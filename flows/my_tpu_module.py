@@ -289,7 +289,6 @@ def train_func_per_worker(config: dict) -> None:
 
 def train_model(
     num_workers: int | None = None,
-    use_tpu: bool = True,
     *,
     model: str = "mlp",
     model_kwargs: dict | None = None,
@@ -335,11 +334,9 @@ def train_model(
     if dcn_data > 1 and (num_workers is None or num_workers <= 0):
         _log(f"hybrid mesh: TPUFLOW_DCN_DATA={dcn_data} (data over "
              "DCN x fsdp over ICI)")
-        scaling = ScalingConfig(
-            dcn_mesh_axes={"data": dcn_data}, use_tpu=use_tpu
-        )
+        scaling = ScalingConfig(dcn_mesh_axes={"data": dcn_data})
     else:
-        scaling = ScalingConfig(num_workers=workers, use_tpu=use_tpu)
+        scaling = ScalingConfig(num_workers=workers)
     trainer = Trainer(
         train_func_per_worker,
         train_loop_config=train_config,
@@ -354,10 +351,10 @@ def train_model(
     return result
 
 
-def train_fashion_mnist(num_workers: int | None = None, use_tpu: bool = True, **kw):
+def train_fashion_mnist(num_workers: int | None = None, **kw):
     """Parity alias (↔ train_fashion_mnist, my_ray_module.py:216)."""
     kw.setdefault("model", "mlp")
-    return train_model(num_workers, use_tpu, **kw)
+    return train_model(num_workers, **kw)
 
 
 class TpuPredictor:

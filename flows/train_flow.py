@@ -35,7 +35,10 @@ from tpuflow.flow import (  # noqa: E402
     tpu,
 )
 
-N_PARALLEL = int(knobs.raw("TPUFLOW_N_PARALLEL", "2"))  # ↔ train_flow.py:17
+# One process owns all the chips of a host, so the gang width is the HOST
+# count (↔ train_flow.py:17's two nodes); a local gang of N > 1 is the CPU
+# simulation of a multi-host world and is refused on an accelerator.
+N_PARALLEL = int(knobs.raw("TPUFLOW_N_PARALLEL", "1"))
 
 
 @schedule(cron="*/5 * * * *")  # ↔ train_flow.py:20
@@ -101,7 +104,6 @@ class TpuTrain(FlowSpec):
         self.dataset_used = self.dataset
         self.result = my_tpu_module.train_model(
             num_workers=None,  # all devices of the gang's world
-            use_tpu=True,
             model=self.model,  # head sized from the dataset registry
             checkpoint_storage_path=current.tpu_storage_path,
             global_batch_size=self.batch_size,

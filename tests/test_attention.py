@@ -333,10 +333,7 @@ def test_ring_attention_ragged_T_falls_back():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
     q, k, v = _qkv(B=1, T=64, H=1, D=8)
-    # jax < 0.5 has no jax.set_mesh; the legacy `with mesh:` context is the
-    # supported spelling there and exercises the same resolution path.
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         out = ring_attention(q, k, v, causal=True)
     ref = xla_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
@@ -436,7 +433,7 @@ def test_gpt2_with_ulysses_attention_trains():
 def test_attention_auto_picks_xla_off_tpu(monkeypatch):
     """impl='auto' must resolve to the XLA path everywhere except a TPU
     backend at long sequence (the measured fwd+bwd crossover,
-    TPU_EVIDENCE.json flash_attention: 0.2x at T=512, 1.73x at T=2048) —
+    a v5e record of 2026-07-31, since deleted: 0.2x at T=512, 1.73x at T=2048) —
     on this CPU platform it must equal xla_attention bit-for-bit at any
     length, including ones the flash kernel couldn't even tile."""
     from tpuflow.ops.attention import attention, xla_attention

@@ -641,7 +641,7 @@ def test_fuzz_random_pytrees_roundtrip_bit_exact(tmp_path, mesh8):
 def test_prewarm_parks_on_starved_box(tmp_path, monkeypatch):
     """With no spare core (TPUFLOW_PREWARM_THREADS=0), background prewarm
     must not spawn work — it parks, runs only under an explicit blocking
-    wait, and is dropped by cancel/clear (BENCH_r03 prewarm_overlap
+    wait, and is dropped by cancel/clear (an early capture's prewarm_overlap
     measured the old always-spawn behavior actively harmful: -16 s)."""
     from tpuflow.ckpt.raw import RecyclePool, RestoreArena
 

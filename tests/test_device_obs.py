@@ -66,7 +66,7 @@ class _FakeCompiled:
     def cost_analysis(self):
         if self._cost_raises:
             raise NotImplementedError("no cost analysis here")
-        return [{"flops": self._flops, "bytes accessed": self._accessed}]
+        return {"flops": self._flops, "bytes accessed": self._accessed}
 
     def memory_analysis(self):
         if self._mem_raises:

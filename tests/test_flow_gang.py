@@ -709,3 +709,60 @@ def test_gang_hybrid_mesh_loss_parity(tmp_path, monkeypatch):
             np.concatenate([a["x"], b["x"]]).reshape(32, -1).sum(axis=1)
         )
         np.testing.assert_allclose(rows_flat, rows_hybrid)
+
+
+def test_local_gang_refused_on_an_accelerator_platform(tmp_path):
+    """One process owns a host's chips. On a platform that is not the CPU
+    a local gang of N > 1 would have every member claim every chip, so the
+    launcher refuses it before anything is launched — and it finds that
+    out from configuration alone: a parent that initialized a backend to
+    ask would itself be holding the chip."""
+    import subprocess
+    import sys
+
+    flow_path = _write_flow(
+        tmp_path,
+        """
+        from jax._src import xla_bridge
+        from tpuflow.flow import runner
+
+        class G(FlowSpec):
+            @step
+            def start(self):
+                self.next(self.work, num_parallel=2)
+
+            @step
+            def work(self):
+                self.next(self.join)
+
+            @step
+            def join(self, inputs):
+                self.next(self.end)
+
+            @step
+            def end(self):
+                pass
+
+        if __name__ == "__main__":
+            try:
+                G.main(["run"])
+            except runner.GangRefused as e:
+                print("REFUSED backend_up=%s" % xla_bridge.backends_are_initialized())
+                print(e)
+        """,
+    )
+    env = {k: v for k, v in os.environ.items() if k != "TPUFLOW_FORCE_CPU"}
+    p = subprocess.run(
+        [sys.executable, flow_path],
+        env={**env, "JAX_PLATFORMS": "tpu"},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert "REFUSED backend_up=False" in p.stdout, p.stdout + p.stderr
+    assert "TPUFLOW_N_PARALLEL=1" in p.stdout
+    assert "retrying" not in p.stdout  # a configuration error, not retried
+    home = os.environ["TPUFLOW_HOME"]
+    logs = [
+        f for _root, _dirs, files in os.walk(home) for f in files
+        if f.startswith("gang_") and f.endswith(".log")
+    ]
+    assert logs == []  # no member was ever started

@@ -149,3 +149,44 @@ def test_expert_weights_shard_over_expert_axis():
         )
     assert has_sharded_leaf(shardings, axis="expert")
     assert "expert" in str(state.params["h0"]["moe"]["w1"].sharding.spec)
+
+
+def test_pin_batch_keeps_activations_split_when_the_vocab_does_not_divide():
+    """A vocabulary the FSDP world does not divide (GPT-2's 50,257 on four
+    chips; 509 on eight devices here) makes FSDP split ``wte``'s hidden
+    axis, and without a pin the embedding lookup hands that layout to the
+    activations: every device then computes the WHOLE batch. With
+    ``pin_batch`` after the embedding each device computes the logits of
+    its own batch rows only."""
+    import re
+
+    from tpuflow.parallel.sharding import pin_batch
+
+    B, T, C = 8, 16, 128
+    mesh = dist.make_mesh({"data": 1, "fsdp": 8})
+    cfg = GPT2Config.small_test(vocab_size=509, n_embd=C, dropout=0.0)
+    model, init_fn = _gpt2_init(cfg, optax.adamw(1e-3))
+    with mesh:
+        state, _ = create_sharded_state(
+            init_fn, mesh, jax.random.PRNGKey(0), fsdp=True,
+            materialize=False,
+        )
+        assert state.params["wte"].sharding.spec == (None, "fsdp")
+        bs = dist.batch_sharding(mesh, 2)
+        batch = {
+            k: jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=bs)
+            for k in ("x", "y")
+        }
+        rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dist.replicated(mesh))
+        hlo = make_train_step().lower(state, batch, rng).compile().as_text()
+        # Leading dim only, and only where a mesh is active and divides.
+        x = jnp.zeros((B, T, C))
+        pinned = jax.jit(pin_batch)(x)
+        assert pinned.sharding.spec[0] == "fsdp"
+        assert jax.jit(pin_batch)(x[:3]).sharding.is_fully_replicated
+    # The logits are the step's largest buffer: every device must hold its
+    # own rows of them and never the whole batch's (without the pin this
+    # program has f32[8,16,509] buffers on every device).
+    rows = {int(b) for b in re.findall(rf"f32\[(\d+),{T},509\]", hlo)}
+    assert rows == {B // 8}, rows
+    assert pin_batch(x) is x  # no mesh: untouched

@@ -1,48 +1,40 @@
 """Compile-cache prewarm (ISSUE 9 startup-latency satellite):
-``tools/prewarm_cache.py`` AOT-lowers the run's signatures into the
-persistent cache ahead of gang launch, and ``dist.seed_compile_cache``
-(called by ``flow/gang_exec`` under ``TPUFLOW_PREWARM_CACHE``) copies
-the prewarmed entries into a member's cache before any jit runs."""
+``tools/prewarm_cache.py`` AOT-lowers the run's signatures straight into
+the ONE persistent cache directory the run reads
+(``dist.maybe_enable_compile_cache``) — JAX keys every entry on the
+directory, so there is no copying between directories."""
 
+import importlib.util
 import os
 import subprocess
 import sys
 
 import pytest
 
-from tpuflow.dist import seed_compile_cache
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_seed_compile_cache_copies_missing_only(tmp_path):
-    src = tmp_path / "prewarmed"
-    dst = tmp_path / "cache"
-    src.mkdir()
-    dst.mkdir()
-    (src / "entry_a").write_bytes(b"compiled-a")
-    (src / "entry_b").write_bytes(b"compiled-b")
-    (src / "subdir").mkdir()  # non-files are skipped, never an error
-    (dst / "entry_b").write_bytes(b"already-here")
-    assert seed_compile_cache(str(src), str(dst)) == 1
-    assert (dst / "entry_a").read_bytes() == b"compiled-a"
-    # Existing entries are NEVER overwritten (content-keyed names: same
-    # name would be same bytes from a real cache; a pre-existing entry
-    # may be in use by a running process).
-    assert (dst / "entry_b").read_bytes() == b"already-here"
-    # Idempotent; missing source is a no-op, not a launch failure.
-    assert seed_compile_cache(str(src), str(dst)) == 0
-    assert seed_compile_cache(str(tmp_path / "nope"), str(dst)) == 0
-    # Destination auto-created.
-    dst2 = tmp_path / "fresh" / "cache"
-    assert seed_compile_cache(str(src), str(dst2)) == 2
+def test_prewarm_writes_only_where_the_run_reads(monkeypatch):
+    """No --cache-dir: the directory comes from the same function the run
+    calls. And where that function keeps the cache off (CPU without
+    --allow-cpu) the tool refuses instead of compiling into nothing."""
+    spec = importlib.util.spec_from_file_location(
+        "prewarm_cache", os.path.join(REPO, "tools", "prewarm_cache.py")
+    )
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    with pytest.raises(SystemExit):
+        tool._parse(["--cache-dir", "/somewhere/else"])
+    monkeypatch.delenv("TPUFLOW_COMPILE_CACHE_CPU", raising=False)
+    with pytest.raises(SystemExit, match="disabled"):
+        tool.prewarm(tool._parse(["--no-train", "--no-serve"]))
 
 
 @pytest.mark.slow
 def test_prewarm_tool_populates_cache_end_to_end(tmp_path):
     """The tool AOT-compiles the train-step + serving signatures (fp AND
     the int8 twin, the paged decode block + page insert, and the
-    speculative verify pair) into a chosen cache dir WITHOUT executing a
+    speculative verify pair) into the placed cache dir WITHOUT executing a
     step — run in a subprocess because force-enabling the persistent
     cache on CPU must not leak into this test process (the XLA:CPU AOT
     reloader is the documented SIGABRT risk maybe_enable_compile_cache
@@ -53,13 +45,14 @@ def test_prewarm_tool_populates_cache_end_to_end(tmp_path):
         [
             sys.executable, os.path.join(REPO, "tools", "prewarm_cache.py"),
             "--preset", "test", "--batch", "2", "--seq-len", "32",
-            "--cache-dir", str(cache), "--buckets", "8", "--slots", "2",
+            "--buckets", "8", "--slots", "2",
             "--decode-block", "2", "--max-new", "8", "--quant",
             "--spec", "2", "--page-size", "8",
             "--allow-cpu",
         ],
         capture_output=True, text=True, timeout=420,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(cache)},
     )
     assert out.returncode == 0, out.stderr[-2000:]
     entries = [p for p in cache.iterdir() if p.is_file()]
@@ -72,6 +65,4 @@ def test_prewarm_tool_populates_cache_end_to_end(tmp_path):
     rec = json.loads(out.stdout.splitlines()[0])
     assert rec["programs_compiled"] == 8
     assert rec["cache_entries"] == len(entries)
-    # A gang member pointed at the prewarmed dir seeds its own cache.
-    member_cache = tmp_path / "member"
-    assert seed_compile_cache(str(cache), str(member_cache)) == len(entries)
+    assert rec["cache_dir"] == str(cache)

@@ -1,8 +1,8 @@
 """Run registry + regression ledger (ISSUE 16), jax-free units: atomic
 append under torn-write injection, the tolerant metric extraction the
-BENCH_r01–r04 backfill depends on (post-PR-15 keys absent → metric
-absent, never KeyError), the one-shot idempotent backfill over the
-repo's real BENCH_r01–r05 captures, trailing median+MAD trend verdicts
+BENCH_r* backfill depends on (post-PR-15 keys absent → metric absent,
+never KeyError), the one-shot idempotent backfill over fixture captures
+of the shapes the driver has written, trailing median+MAD trend verdicts
 (regression vs jitter), and the ``trend``/``compare`` CLI — including a
 poisoned-jax subprocess proving ``obs trend`` never imports jax."""
 
@@ -17,6 +17,38 @@ from tpuflow.obs import registry as reg
 from tpuflow.obs.__main__ import main as obs_main
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_HEADLINE = {"metric": "sharded_ckpt_save_restore_throughput",
+             "unit": "GB/s"}
+
+
+@pytest.fixture
+def bench_dir(tmp_path):
+    """Five driver captures, one of each shape the importer has met: a
+    bare headline (r01-r03, before the compact digest existed), a null
+    ``parsed`` (r04: the record outgrew the captured tail), and a digest
+    with the train summary (r05). Values are made up."""
+    d = tmp_path / "captures"
+    d.mkdir()
+    parsed = {
+        1: {**_HEADLINE, "value": 1.5, "vs_baseline": 0.75},
+        2: {**_HEADLINE, "value": 2.5, "vs_baseline": 1.25},
+        3: {**_HEADLINE, "value": 3.0, "vs_baseline": 1.5},
+        4: None,
+        5: {**_HEADLINE, "value": 3.5, "vs_baseline": 1.75, "summary": {
+            "host_combined_gbps": 3.5, "disk_combined_gbps": 0.5,
+            "train": {"platform": "tpu", "mfu": 0.5,
+                      "tokens_per_s": 1000.0},
+            "spec_decode": {"numerics_ok": False, "speedup": None},
+            "git": "abc1234",
+        }},
+    }
+    for n, p in parsed.items():
+        (d / f"BENCH_r0{n}.json").write_text(json.dumps(
+            {"n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
+             "parsed": p}
+        ))
+    return str(d)
 
 
 def _mk(run_id, metrics, ts=0.0):
@@ -140,24 +172,22 @@ def test_bench_metrics_all_generations():
 
 
 # ------------------------------------------------------------ backfill
-def test_backfill_bench_history_idempotent(tmp_path):
-    """The one-shot importer over the repo's REAL BENCH_r01–r05
-    captures: every round imports (r04's null parsed included), legacy
-    rounds simply carry fewer metrics, and a second run imports
-    nothing."""
+def test_backfill_bench_history_idempotent(tmp_path, bench_dir):
+    """The one-shot importer over driver captures: every round imports
+    (r04's null parsed included), legacy rounds simply carry fewer
+    metrics, and a second run imports nothing."""
     path = str(tmp_path / "reg.jsonl")
-    n = reg.backfill_bench(REPO, path)
-    assert n >= 5  # BENCH_r01..r05 are committed history
-    assert reg.backfill_bench(REPO, path) == 0  # idempotent
+    assert reg.backfill_bench(bench_dir, path) == 5
+    assert reg.backfill_bench(bench_dir, path) == 0  # idempotent
     recs = {r["run_id"]: r for r in reg.read_registry(path)}
     r01 = recs["BENCH_r01"]
-    assert r01["metrics"]["host_combined_gbps"] == pytest.approx(1.7614)
+    assert r01["metrics"]["host_combined_gbps"] == pytest.approx(1.5)
     assert "hbm_peak_frac" not in r01["metrics"]  # absent, not KeyError
     r05 = recs["BENCH_r05"]
-    assert r05["metrics"]["train_mfu"] == pytest.approx(0.4277)
+    assert r05["metrics"]["train_mfu"] == pytest.approx(0.5)
     assert r05["metrics"]["spec_decode_numerics_ok"] == 0.0
     assert r05.get("platform") == "tpu"
-    assert r05.get("git") == "11c8ff0"
+    assert r05.get("git") == "abc1234"
     assert "BENCH_r04" in recs  # null parsed still imports
 
 
@@ -218,9 +248,9 @@ def test_compare_rows_direction_and_absent():
 
 # ------------------------------------------------------------------ CLI
 @pytest.fixture
-def backfilled(tmp_path, monkeypatch):
+def backfilled(tmp_path, monkeypatch, bench_dir):
     path = str(tmp_path / "reg.jsonl")
-    assert reg.backfill_bench(REPO, path) >= 5
+    assert reg.backfill_bench(bench_dir, path) == 5
     monkeypatch.setenv("TPUFLOW_REGISTRY_PATH", path)
     return path
 
@@ -246,12 +276,12 @@ def test_compare_cli_and_prefix_match(backfilled, capsys):
     assert "nope" in capsys.readouterr().err
 
 
-def test_backfill_cli(tmp_path, monkeypatch, capsys):
+def test_backfill_cli(tmp_path, monkeypatch, capsys, bench_dir):
     path = str(tmp_path / "reg.jsonl")
     monkeypatch.setenv("TPUFLOW_REGISTRY_PATH", path)
-    assert obs_main(["registry-backfill", REPO]) == 0
+    assert obs_main(["registry-backfill", bench_dir]) == 0
     assert "imported" in capsys.readouterr().out
-    assert len(reg.read_registry(path)) >= 5
+    assert len(reg.read_registry(path)) == 5
 
 
 def test_trend_cli_empty_registry(tmp_path, monkeypatch, capsys):

@@ -509,3 +509,31 @@ def test_remat_policy_parity_with_flash_kernels():
     named flash output, 'none' holds the custom_vjp residuals
     (outputs + lse) with zero recompute — values identical either way."""
     _remat_parity("flash")
+
+
+def test_unknown_tpu_device_kind_is_an_error_not_a_default(monkeypatch):
+    """The peak tables divide measured rates: a TPU they do not know must
+    raise (a guessed peak makes a wrong utilization), a known one reads
+    its row, and off the TPU there is no number at all."""
+    import tpuflow.train.step as step_mod
+    from tpuflow.obs import goodput as gp
+
+    class Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    def local_device(platform, kind):
+        monkeypatch.setattr(jax, "devices", lambda: [Dev(platform, kind)])
+        monkeypatch.setattr(gp, "_PEAK_CACHE", gp._UNSET)
+
+    local_device("tpu", "TPU v9 hyperchip")
+    with pytest.raises(ValueError, match="v9 hyperchip"):
+        gp._peak_flops_per_device()
+    with pytest.raises(ValueError, match="v9 hyperchip"):
+        step_mod._ici_gbps()
+    local_device("tpu", "TPU v5 lite")
+    assert gp._peak_flops_per_device() == 197e12
+    assert step_mod._ici_gbps() == 400.0
+    local_device("cpu", "cpu")
+    assert gp._peak_flops_per_device() is None
+    assert step_mod._ici_gbps() is None

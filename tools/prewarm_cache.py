@@ -1,33 +1,33 @@
 #!/usr/bin/env python
-"""AOT compile-cache prewarm: pay the jit compiles BEFORE gang launch.
+"""AOT compile-cache prewarm: pay the jit compiles BEFORE the run starts.
 
-BENCH_r05 measured compile 62.9 s and wall-to-first-step 125.1 s — over
-half the startup wall is XLA compiling programs whose shapes were known
-before the gang ever scheduled (ROADMAP item 4 startup latency). This
-tool AOT-lowers (``jit(...).lower(...).compile()``) the signatures a
-run will execute — the train step, the serving engine's decode-block
-program (fp and, with ``--quant``, the int8 twin), every bucket-prefill
-program, and the slot insert — with JAX's persistent compilation cache
-pointed at a durable directory, so the compiled executables land on
-disk without running a single step. ``flow/gang_exec`` then seeds each
-member's cache from that directory ahead of member start
-(``TPUFLOW_PREWARM_CACHE=<dir>``, rsync-style: only missing entries
-copy), so the first real step is a cache LOAD.
+A large share of a cold start is XLA compiling programs whose shapes were
+known before anything was scheduled. This tool AOT-lowers
+(``jit(...).lower(...).compile()``) the signatures a run will execute —
+the train step, the serving engine's decode-block program (fp and, with
+``--quant``, the int8 twin), every bucket-prefill program, and the slot
+insert — with JAX's persistent compilation cache on, so the compiled
+executables land on disk without running a single step.
 
-Cache keys are HLO + compile options: the prewarmed entries hit only
-when the shapes, mesh/sharding, and jax/XLA versions match the run —
-prewarm on the same host image with the run's real ``--preset``/
-``--batch``/``--seq-len``. A mismatch is harmless (the run compiles
-normally); prewarm is an optimization, never a launch gate.
+It writes straight into the ONE cache directory the run will read
+(``dist.maybe_enable_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` where
+set, else ``.compile_cache`` in the checkout). JAX hashes the directory
+into every entry's key, so entries copied in from another directory never
+hit; set the same ``JAX_COMPILATION_CACHE_DIR`` for the prewarm and the
+run. The rest of the key is HLO + compile options: entries hit only when
+the shapes, mesh/sharding, and jax/XLA versions match the run — prewarm
+on the same host image with the run's real ``--preset``/``--batch``/
+``--seq-len``. A mismatch is harmless (the run compiles normally).
+The train signature here is an UNSHARDED state outside any mesh, which is
+not the program ``train_gpt`` builds (born-sharded state, batch sharding,
+``with mesh``): it prewarms ``Trainer``-style single-device steps only.
 
 Usage::
 
     python tools/prewarm_cache.py --preset gpt2 --batch 8 --seq-len 512 \
-        --cache-dir /shared/prewarm [--no-train] [--no-serve] \
+        [--no-train] [--no-serve] \
         [--quant] [--spec K] [--slots 8] [--buckets 16,32,64] \
         [--page-size 16] [--pages N] [--max-new 128]
-
-Then launch the gang with ``TPUFLOW_PREWARM_CACHE=/shared/prewarm``.
 
 CPU note: the persistent cache is OFF on CPU by default (the XLA:CPU
 AOT loader can abort reloading entries across machine-feature changes —
@@ -43,8 +43,7 @@ import os
 import sys
 import time
 
-# Runnable from anywhere (the gang launcher's image bake step, a shared
-# volume init container): put the repo root on sys.path like the other
+# Runnable from anywhere: put the repo root on sys.path like the other
 # standalone tools.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -57,11 +56,6 @@ def _parse(argv):
                    help="train-step global batch rows")
     p.add_argument("--seq-len", type=int, default=64,
                    help="train-step sequence length")
-    p.add_argument("--cache-dir", default=None,
-                   help="cache directory (default: TPUFLOW_COMPILE_CACHE "
-                        "resolution / $TPUFLOW_HOME/compile_cache)")
-    p.add_argument("--run-dir", default=None,
-                   help="run dir for TPUFLOW_COMPILE_CACHE=run keying")
     p.add_argument("--no-train", action="store_true",
                    help="skip the train-step signature")
     p.add_argument("--accum-steps", type=int, default=1,
@@ -106,15 +100,13 @@ def prewarm(args) -> dict:
     # Env staging must precede backend-touching imports/config.
     if args.allow_cpu:
         os.environ["TPUFLOW_COMPILE_CACHE_CPU"] = "1"
-    if args.cache_dir:
-        os.environ["TPUFLOW_COMPILE_CACHE"] = args.cache_dir
 
     import jax
     import jax.numpy as jnp
 
     from tpuflow.dist import maybe_enable_compile_cache
 
-    cache_dir = maybe_enable_compile_cache(args.run_dir)
+    cache_dir = maybe_enable_compile_cache()
     if cache_dir is None:
         raise SystemExit(
             "[prewarm] persistent compile cache is disabled here "
@@ -123,16 +115,9 @@ def prewarm(args) -> dict:
         )
     # Prewarm wants EVERY program persisted, including ones under the
     # default min-compile-time threshold (the whole point is that the
-    # run skips even the small compiles). Old jax without the knobs:
-    # the defaults still persist the expensive programs.
-    for knob, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):
-            pass
+    # run skips even the small compiles).
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
     from tpuflow.models.gpt2 import GPT2, GPT2Config
     from tpuflow.obs import device as device_mod
@@ -296,8 +281,7 @@ def main(argv=None) -> int:
     print(
         f"[prewarm] {rec['programs_compiled']} programs -> "
         f"{rec['cache_entries']} cache entries in {rec['cache_dir']} "
-        f"({rec['wall_s']}s); launch gangs with "
-        f"TPUFLOW_PREWARM_CACHE={rec['cache_dir']}"
+        f"({rec['wall_s']}s)"
     )
     return 0
 

@@ -275,8 +275,8 @@ def _spare_cores() -> int:
     """Cores available for BACKGROUND page-backing beyond the one the
     host compute thread occupies. Background prewarm only wins when its
     page touches run on cores compute isn't using; on a 1-core box it
-    steals the only core and measures actively harmful (BENCH_r03
-    prewarm_overlap: hidden_s -16.2 s, first save collapsed 8x). When
+    steals the only core and measures actively harmful (an early CPU
+    capture's prewarm_overlap: hidden_s -16.2 s, first save collapsed 8x). When
     this returns 0, background prewarms PARK their work: it runs only if
     a caller explicitly waits (prewarm_wait — that caller has nothing
     better to do with the core), else it never runs and the first save /

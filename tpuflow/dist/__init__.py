@@ -16,10 +16,10 @@ from tpuflow.dist.mesh import (
     AXIS_FSDP,
     AXIS_SEQ,
     AXIS_TENSOR,
+    COMPILE_CACHE_DIR,
     barrier,
     batch_sharding,
     data_axis_size,
-    ensure_healthy_platform,
     force_cpu_platform,
     initialize,
     is_initialized,
@@ -27,11 +27,11 @@ from tpuflow.dist.mesh import (
     maybe_enable_compile_cache,
     make_hybrid_mesh,
     make_mesh,
+    platform_is_cpu,
     process_count,
     process_index,
     replicate,
     replicated,
-    seed_compile_cache,
     serialize_steps,
     step_fence,
     shard_batch,
@@ -48,10 +48,10 @@ __all__ = [
     "AXIS_FSDP",
     "AXIS_SEQ",
     "AXIS_TENSOR",
+    "COMPILE_CACHE_DIR",
     "barrier",
     "batch_sharding",
     "data_axis_size",
-    "ensure_healthy_platform",
     "force_cpu_platform",
     "initialize",
     "is_initialized",
@@ -59,11 +59,11 @@ __all__ = [
     "maybe_enable_async_collectives",
     "maybe_enable_compile_cache",
     "make_mesh",
+    "platform_is_cpu",
     "process_count",
     "process_index",
     "replicate",
     "replicated",
-    "seed_compile_cache",
     "serialize_steps",
     "step_fence",
     "shard_batch",

@@ -38,8 +38,13 @@ surviving member is always the coordinator of every generation (member 0
 in practice: coordinator loss falls back to requeue-the-world, see
 ``flow/runner.py``).
 
-Runtime teardown notes (the part jax does not support out of the box,
-validated against jax 0.4.37 / XLA's coordination service):
+Runtime teardown notes (the part jax does not support out of the box).
+The elastic acceptance tests (tests/test_membership.py, slow tier: shrink
+on member loss, shrink then regrow, in-process re-form) exercise the
+leak-and-``os._exit`` design, the cache clearing and the raise-on-dead-peer
+behaviour on the installed jax 0.9.0; the claims about the default
+client's abort and the ``missed_heartbeat_callback`` binding come from an
+older jax and were not probed again:
 
 - The default distributed client **aborts the process** when the
   coordination service reports a peer death (``client.h:80``) and its
@@ -427,21 +432,23 @@ def elastic_initialize(plan: Generation, *, timeout_s: float = 300.0) -> None:
     pid = plan.process_id(me)
     gs = _distributed_state()
     if plan.num_processes > 1:
-        from jax._src.lib import xla_extension
+        from jax._src.lib import _jax
 
+        # Heartbeat timeouts are seconds; ~4 months is "never".
+        never = 10_000_000
         if pid == 0:
-            svc = xla_extension.get_distributed_runtime_service(
+            svc = _jax.get_distributed_runtime_service(
                 "[::]:" + plan.coordinator.rsplit(":", 1)[1],
                 plan.num_processes,
-                heartbeat_interval=10,
-                max_missing_heartbeats=1_000_000,
+                heartbeat_timeout=never,
             )
             _LEAKED.append(svc)
             gs.service = svc
-        cli = xla_extension.get_distributed_runtime_client(
+        cli = _jax.get_distributed_runtime_client(
             plan.coordinator,
             pid,
             init_timeout=int(max(timeout_s, 1.0)),
+            heartbeat_timeout=never,
             shutdown_on_destruction=False,
             use_compression=True,
         )

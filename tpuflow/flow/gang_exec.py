@@ -45,32 +45,10 @@ def _bootstrap_jax() -> None:
 
     maybe_enable_async_collectives()
     # Gang members share the persistent compile cache: after one worker
-    # (or a previous attempt) compiled the step, the rest load it. With
-    # TPUFLOW_COMPILE_CACHE=run the cache keys under the run directory
-    # (the parent of the obs dir every member inherits) — the mode for
-    # k8s gangs whose only shared storage is the run dir, so a requeued
-    # attempt on a fresh pod still reloads the compiled step.
-    from tpuflow.dist import maybe_enable_compile_cache, seed_compile_cache
+    # (or a previous attempt) compiled the step, the rest load it.
+    from tpuflow.dist import maybe_enable_compile_cache
 
-    obs_dir = knobs.raw("TPUFLOW_OBS_DIR")
-    cache_dir = maybe_enable_compile_cache(
-        run_dir=os.path.dirname(obs_dir) if obs_dir else None
-    )
-    # Startup-latency satellite (ISSUE 9): a cache prewarmed AHEAD of
-    # gang launch (tools/prewarm_cache.py, typically on the image or a
-    # shared volume) seeds this member's cache before any jit runs —
-    # the first step / decode block loads a compiled executable instead
-    # of paying the measured 62.9 s compile inside wall-to-first-step.
-    # Rsync-style: only entries absent here are copied, existing ones
-    # never touched, and an unreadable source is a silent no-op.
-    prewarm = knobs.raw("TPUFLOW_PREWARM_CACHE")
-    if prewarm and cache_dir and prewarm != cache_dir:
-        copied = seed_compile_cache(prewarm, cache_dir)
-        if copied:
-            print(
-                f"[tpuflow] seeded {copied} prewarmed compile-cache "
-                f"entries from {prewarm}"
-            )
+    maybe_enable_compile_cache()
 
 
 def _store_artifacts(flow_name: str, run_id: str, step_name: str) -> dict:

@@ -14,7 +14,7 @@ Two modes, one wrapper:
   ``QuantLeaf(q, scale)``), **dequantize inside the compiled program**
   (``QuantizedModel.apply`` rebuilds floats inside the caller's jit
   trace). At rest only int8 bytes exist. CAVEAT, measured on-chip (r4,
-  TPU_EVIDENCE.json decode.int8 = 0.76x vs fp at 124M/b8): XLA fusions
+  a v5e record of 2026-07-31, since deleted: decode.int8 = 0.76x vs fp at 124M/b8): XLA fusions
   do not cross dot boundaries, so the dequantized weights CAN
   materialize as a per-step bf16 buffer — convert+scale+write+read on
   top of the matmul — making weight-only int8 a *memory capacity*
@@ -358,7 +358,7 @@ def quantize_model(
     )
 
 
-# Measured on chip (r4, TPU_EVIDENCE.json decode.int8): weight-only int8
+# Measured on chip (v5e, 2026-07-31; record since deleted): weight-only int8
 # decode at GPT-2-124M/b8 ran 0.76x vs fp — the dequantized weights
 # materialize as a per-step bf16 buffer, so below this resident-set size
 # the halved weight stream never pays for the convert+write+read. The

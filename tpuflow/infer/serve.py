@@ -39,8 +39,7 @@ Shape of the engine:
 - **AOT warm path.** ``warmup()`` routes through
   ``maybe_enable_compile_cache`` and executes the decode program, the
   insert, and every prefill bucket once, so a restarted server pays
-  cache loads instead of the measured 62.9 s compile / 125.1 s
-  wall-to-first-step gap (BENCH_r05). ``compile_stats()`` exposes the
+  cache loads instead of compiles. ``compile_stats()`` exposes the
   jit cache sizes; after warmup they must never grow — pinned by
   tests/test_serve.py.
 
@@ -2191,20 +2190,19 @@ class ServeEngine:
             args.append(jnp.asarray(self._page_table))
         return args
 
-    def warmup(self, run_dir: str | None = None) -> dict[str, int]:
+    def warmup(self) -> dict[str, int]:
         """Compile-or-load every program the engine will ever run: the
         decode block (and the speculative verify block when armed), the
         insert, and one prefill per bucket — through the persistent
         compile cache (``maybe_enable_compile_cache``), so a server
-        restart pays cache loads, not the BENCH_r05 62.9 s compile /
-        125.1 s wall-to-first-step gap. Executes each program once on
+        restart pays cache loads, not compiles. Executes each program once on
         dead-slot state (guaranteed jit-cache hits afterwards; the
         garbage forwards are masked by ``live=False`` everywhere — paged
         writes land in the trash page) and restores a pristine cache.
         Returns ``compile_stats()``."""
         from tpuflow.dist import maybe_enable_compile_cache
 
-        maybe_enable_compile_cache(run_dir)
+        maybe_enable_compile_cache()
         # Per-program compile fences (ISSUE 15): each first execution
         # below IS that program's trace+compile(-or-cache-load) wall, so
         # a couple of monotonic reads per program give the device

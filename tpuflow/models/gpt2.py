@@ -625,6 +625,11 @@ class GPT2(nn.Module):
             pe = wpe[:T]
         dt = cfg.compute_dtype(decode and not prefill)
         x = wte[tokens].astype(dt) + pe.astype(dt)
+        from tpuflow.parallel.sharding import pin_batch
+
+        # Keep the activations split on the batch whatever layout FSDP
+        # gave the embedding tables (see pin_batch).
+        x = pin_batch(x)
         x = nn.Dropout(cfg.dropout, deterministic=not train)(x)
         def remat_wrap(mod):
             import jax as _jax
@@ -639,14 +644,10 @@ class GPT2(nn.Module):
                 # checkpoint_name note in ops/flash_attention.py; the
                 # zero-recompute mode is remat OFF, selector 'none').
                 cp = _jax.checkpoint_policies
-                policy = cp.dots_with_no_batch_dims_saveable
-                try:
-                    policy = cp.save_from_both_policies(
-                        policy,
-                        cp.save_only_these_names("flash_out"),
-                    )
-                except AttributeError:
-                    pass  # old jax without name policies: dots alone
+                policy = cp.save_from_both_policies(
+                    cp.dots_with_no_batch_dims_saveable,
+                    cp.save_only_these_names("flash_out"),
+                )
             elif cfg.remat_policy:
                 try:
                     policy = getattr(

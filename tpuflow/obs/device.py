@@ -72,10 +72,10 @@ def _warn_once(key: str, msg: str) -> None:
 
 # --------------------------------------------- compiled-program analysis
 def cost_analysis_dict(compiled_or_lowered) -> dict[str, float]:
-    """``cost_analysis()`` → ``{flops, bytes_accessed}``, tolerating the
-    per-version return shapes (``Compiled`` returns a list of per-module
-    dicts on some backends, ``Lowered`` a plain dict) and backends that
-    raise — absent keys, never a crash."""
+    """``cost_analysis()`` → ``{flops, bytes_accessed}``. On the installed
+    jax both ``Compiled`` and ``Lowered`` return a plain dict, on the CPU
+    and on the TPU (chip_smoke.py prints the type). A backend that raises
+    yields absent keys, never a crash."""
     out: dict[str, float] = {}
     try:
         ca = compiled_or_lowered.cost_analysis()
@@ -86,15 +86,12 @@ def cost_analysis_dict(compiled_or_lowered) -> dict[str, float]:
             f"({e!r}); recording absent keys",
         )
         return out
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if isinstance(ca, dict):
-        flops = ca.get("flops")
-        if isinstance(flops, (int, float)):
-            out["flops"] = float(flops)
-        accessed = ca.get("bytes accessed")
-        if isinstance(accessed, (int, float)):
-            out["bytes_accessed"] = float(accessed)
+    flops = ca.get("flops")
+    if isinstance(flops, (int, float)):
+        out["flops"] = float(flops)
+    accessed = ca.get("bytes accessed")
+    if isinstance(accessed, (int, float)):
+        out["bytes_accessed"] = float(accessed)
     return out
 
 

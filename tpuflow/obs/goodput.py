@@ -240,9 +240,8 @@ def compute_goodput(events: Iterable[dict]) -> dict[str, Any]:
 
 # --------------------------------------------------------- live ledger
 # bf16 peak FLOP/s per chip for the rolling-MFU gauge, matched (in
-# order) against jax.devices()[0].device_kind — the same table bench.py
-# uses for its one-shot MFU leg, duplicated here because runtime code
-# must not import the benchmark harness.
+# order) against jax.devices()[0].device_kind, which reads like
+# 'TPU v5 lite', not 'v5e' (Google Cloud TPU documentation, per-chip).
 _PEAK_FLOPS = (
     ("v6 lite", 918e12),
     ("v6lite", 918e12),
@@ -258,25 +257,38 @@ _UNSET = object()
 _PEAK_CACHE: Any = _UNSET
 
 
+def table_value(table, device_kind: str, what: str) -> float:
+    """``table``'s entry for a ``device_kind`` string (first substring
+    match wins, so lite entries precede their bare-version keys). A
+    device the table does not know is an error, not a default: a rate
+    divided by a guessed peak is worse than no rate."""
+    kind = device_kind.lower()
+    for key, value in table:
+        if key in kind:
+            return value
+    raise ValueError(
+        f"no {what} known for device_kind {device_kind!r}; add the "
+        "published figure to the table"
+    )
+
+
+def device_table_value(table, what: str) -> float | None:
+    """``table_value`` for the local device, or None off the TPU, where
+    no number is invented."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    return table_value(table, dev.device_kind, what)
+
+
 def _peak_flops_per_device() -> float | None:
     """bf16 peak FLOP/s of the local accelerator, or None off-TPU (the
     rolling MFU is then omitted rather than invented)."""
     global _PEAK_CACHE
-    if _PEAK_CACHE is not _UNSET:
-        return _PEAK_CACHE
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            _PEAK_CACHE = None
-        else:
-            kind = dev.device_kind.lower()
-            _PEAK_CACHE = next(
-                (v for k, v in _PEAK_FLOPS if k in kind), 197e12
-            )
-    except Exception:
-        _PEAK_CACHE = None
+    if _PEAK_CACHE is _UNSET:
+        _PEAK_CACHE = device_table_value(_PEAK_FLOPS, "bf16 peak FLOP/s")
     return _PEAK_CACHE
 
 

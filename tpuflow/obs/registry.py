@@ -8,7 +8,7 @@ digest keys the exit-3/4/5/6 gates read, git commit + dirty flag, and
 platform provenance. The sensors existed (PRs 13–15); this file is the
 memory that lets anything *compare* them: five rounds of BENCH history
 become queryable the moment the one-shot importer backfills
-BENCH_r01–r05.
+BENCH_r*.json captures.
 
 Durability contract:
 

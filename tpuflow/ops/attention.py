@@ -46,11 +46,12 @@ def xla_attention(q, k, v, *, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-# Shipped defaults for the auto-dispatch thresholds, from the on-chip
-# evidence (BENCH_r05): fwd+bwd needs T >= 2048 (at T=512 the flash
-# backward LOST to XLA at 0.2x while fwd won 2.73x — the two paths have
-# genuinely different crossovers, so they carry independent thresholds);
-# fwd-only (decode prefill, scoring without grad) wins from T >= 512.
+# Shipped defaults for the auto-dispatch thresholds, from a v5e record of
+# 2026-07-31 (since deleted; not re-measured on the current kernels):
+# fwd+bwd needs T >= 2048 (at T=512 the flash backward LOST to XLA at 0.2x
+# while fwd won 2.73x — the two paths have genuinely different crossovers,
+# so they carry independent thresholds); fwd-only (dense prefill, scoring
+# without grad) wins from T >= 512.
 _DEFAULT_FLASH_MIN_SEQ = 2048
 _DEFAULT_FLASH_MIN_SEQ_FWD = 512
 _flash_tuning_cache: dict | None = None
@@ -94,7 +95,7 @@ def _flash_min_seq(*, needs_bwd: bool = True) -> int:
     and warns once per process, through the obs stream when one is live.
     An unset fwd-only env var falls back to the fwd+bwd env var scaled
     by nothing — i.e. only its own sources; the two paths never borrow
-    each other's thresholds (BENCH_r05: at T=512 fwd wins 2.73x while
+    each other's thresholds (that record: at T=512 fwd wins 2.73x while
     fwd+bwd loses at 0.2x). The file read is cached per process (this
     runs at trace time).
 
@@ -216,6 +217,13 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "xla",
         impl = resolve_attention_impl(
             "auto", q.shape[1], needs_bwd=needs_bwd
         )
+        if impl == "flash":
+            from tpuflow.ops.flash_attention import flash_tiles
+
+            # 'auto' never picks a kernel that cannot run the shape (a
+            # 600-token prefill does not tile the 256-row blocks).
+            if not flash_tiles(q.shape[1], k.shape[1], q.shape[3]):
+                impl = "xla"
     if impl == "xla":
         return xla_attention(q, k, v, causal=causal)
     if impl == "flash":

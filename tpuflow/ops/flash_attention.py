@@ -22,7 +22,6 @@ first-class long-context support (SURVEY.md §5).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -30,12 +29,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from tpuflow.utils import knobs
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 spells it TPUCompilerParams; alias so call sites stay on
-    # the current name.
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 _NEG_INF = -1e30
+
+
+def _interpret() -> bool:
+    """Pallas interpret mode everywhere but on the TPU backend (the CPU
+    tests run the kernels' exact program through the interpreter)."""
+    return jax.default_backend() != "tpu"
 
 
 def _chunk_positions(t: int, block: int):
@@ -262,7 +262,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 #   of (lse + o): its q-innermost walk re-streams each q row's operands
 #   nk times, so dropping the o stream and the per-visit rowsum removes
 #   one full HBM pass and nk-1 VPU reduces per row — the short-T regime
-#   where BENCH_r05 measured the backward losing 5x to XLA is exactly
+#   where a v5e record of 2026-07-31 had the backward losing 5x to XLA is exactly
 #   where that per-visit residual traffic rivals the useful q/k/v bytes.
 # - SPLIT (TPUFLOW_FLASH_BWD=split, one release as the regression
 #   reference): the previous kernels — D recomputed from (o, do) inside
@@ -654,14 +654,12 @@ def _flash_bwd_split(q, k, v, o, lse, g, causal, block_q, block_k,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, block_q, block_k):
-    interpret = jax.default_backend() != "tpu"
-    return _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_fwd(q, k, v, causal, block_q, block_k, _interpret())
 
 
 def _flash_vjp_fwd(q, k, v, causal, block_q, block_k):
-    interpret = jax.default_backend() != "tpu"
     o, lse = _flash_fwd(
-        q, k, v, causal, block_q, block_k, interpret, with_lse=True
+        q, k, v, causal, block_q, block_k, _interpret(), with_lse=True
     )
     # The residual keeps the kernel's native (BH, Tq, 128) lane-broadcast
     # layout by default (the same choice as the reference TPU flash
@@ -688,7 +686,7 @@ def _flash_vjp_bwd(causal, block_q, block_k, res, g):
             q, k, v,
         )
         return vjp(g)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
     if mode == "split":
         # The pre-ISSUE-10 two-pass kernels, kept one release as the
         # on-chip regression reference (the bench flash leg races them
@@ -712,34 +710,48 @@ def _flash_vjp_bwd(causal, block_q, block_k, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def flash_tiles(
+    tq: int, tk: int, d: int, block_q: int = 256, block_k: int = 256
+) -> bool:
+    """Whether the kernels can run this shape: each sequence length a
+    multiple of its (length-capped) block, head_dim a multiple of 8."""
+    return not (tq % min(block_q, tq) or tk % min(block_k, tk) or d % 8)
+
+
 def flash_attention(
     q, k, v, *, causal: bool = True, block_q: int = 256, block_k: int = 256
 ):
     """Pallas TPU flash attention. q,k,v: (B,T,H,D) → (B,T,H,D).
 
-    Falls back to ``blockwise_attention`` when shapes don't tile (T not
-    divisible by the blocks, or tiny head_dim on CPU interpret mode).
+    A shape that does not tile (``flash_tiles``) raises on the TPU
+    backend: the kernel was asked for by name, and ``impl='auto'`` is the
+    spelling that may pick XLA. Off the TPU — interpret mode, tests —
+    such a shape takes ``blockwise_attention``, the same math.
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
-    if Tq % block_q or Tk % block_k or D % 8:
+    if not flash_tiles(Tq, Tk, D, block_q, block_k):
+        if not _interpret():
+            raise ValueError(
+                f"flash attention cannot run q{tuple(q.shape)} "
+                f"k{tuple(k.shape)}: sequence lengths must be multiples "
+                f"of the {block_q}x{block_k} blocks and head_dim of 8; "
+                "use attn_impl='auto' (which picks XLA for such shapes) "
+                "or 'xla'"
+            )
         return blockwise_attention(q, k, v, causal=causal)
-    out = _flash(q, k, v, causal, block_q, block_k)
-    try:
-        from jax.ad_checkpoint import checkpoint_name
+    out = _flash(
+        q, k, v, causal, min(block_q, Tq), min(block_k, Tk)
+    )
+    from jax.ad_checkpoint import checkpoint_name
 
-        # Named for selective-remat policies (ISSUE 10): the 'dots'
-        # policy saves this output alongside the MXU dot outputs. The
-        # lse softmax residual lives INSIDE the custom_vjp, which jax's
-        # remat treats atomically — a remat'd block re-runs the flash
-        # forward for it regardless of policy (measured: one extra fwd
-        # pallas_call in the remat'd backward jaxpr). Truly saving
-        # "flash outputs + lse" therefore means NOT remat'ing — the
-        # TPUFLOW_REMAT_POLICY=none mode, where the vjp residuals
-        # (q, k, v, o, lse) are held from the forward and the backward
-        # runs zero recompute.
-        return checkpoint_name(out, "flash_out")
-    except ImportError:  # very old jax: the name is advisory anyway
-        return out
+    # Named for selective-remat policies (ISSUE 10): the 'dots' policy
+    # saves this output alongside the MXU dot outputs. The lse softmax
+    # residual lives INSIDE the custom_vjp, which jax's remat treats
+    # atomically — a remat'd block re-runs the flash forward for it
+    # regardless of policy (measured: one extra fwd pallas_call in the
+    # remat'd backward jaxpr). Truly saving "flash outputs + lse"
+    # therefore means NOT remat'ing — the TPUFLOW_REMAT_POLICY=none mode,
+    # where the vjp residuals (q, k, v, o, lse) are held from the forward
+    # and the backward runs zero recompute.
+    return checkpoint_name(out, "flash_out")

@@ -1,7 +1,7 @@
 """Fused native int8 matmul: the decode-path W8A8 contraction.
 
-The measured failure this op exists to close (BENCH_r05, ROADMAP item
-4): weight-only int8 decode ran **0.76x vs fp** at 124M/b8 because the
+The failure this op exists to close (a chip record of 2026-07-31, since
+deleted): weight-only int8 decode ran 0.76x vs fp at 124M/b8 because the
 dequantize-into-matmul interceptor rebuilt bf16 weights per step —
 convert + scale + write + read on top of the very matmul the int8 bytes
 were supposed to shrink. The native path never materializes float
@@ -36,8 +36,8 @@ Dispatch mirrors ``tpuflow.ops.attention``'s flash thresholds:
 interpret-mode off-TPU, for tests); ``auto`` (default) picks pallas on
 TPU when the shape tiles and the weight block is big enough for the
 kernel to matter (``TPUFLOW_INT8_KERNEL_MIN_KN``, default K*N >= 2^18).
-Untileable shapes — e.g. the 50257-column GPT-2 LM head — fall back to
-the XLA path, which is still native int8 end to end.
+Untileable shapes — e.g. the 50257-column GPT-2 LM head — take the XLA
+path under ``auto``, which is still native int8 end to end.
 """
 
 from __future__ import annotations
@@ -45,17 +45,12 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from tpuflow.utils import knobs
-
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 spells it TPUCompilerParams (same alias as flash_attention).
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
 
 # Kernel worth it once the streamed weight block dominates the launch:
 # K*N below this (e.g. tiny test models) stays on the XLA path under
@@ -311,8 +306,10 @@ def int8_matmul(
     materializing a transposed int8 copy). ``w_scale`` holds the
     per-out-channel weight scales, any shape of size N (or a single
     per-tensor scale). ``impl`` overrides the dispatch
-    (``resolve_int8_impl``); a forced ``pallas`` on an untileable shape
-    falls back to the XLA path (numerics identical) with a
+    (``resolve_int8_impl``). A forced ``pallas`` on an untileable shape
+    raises on the TPU backend — the kernel was asked for by name, and
+    ``auto`` is the spelling that may pick XLA; off the TPU (interpret
+    mode, tests) it takes the XLA path (numerics identical) with a
     ``quant.kernel_fallback`` event.
     """
     if wq.dtype != jnp.int8:
@@ -344,8 +341,15 @@ def int8_matmul(
     chosen = impl if impl not in (None, "auto") else resolve_int8_impl(m, k, n)
     if chosen not in ("xla", "pallas"):
         raise ValueError(f"unknown int8 impl {chosen!r}; use xla|pallas")
+    interpret = jax.default_backend() != "tpu"
     if chosen == "pallas" and not kernel_supported(m, k, n):
         shape = (m, k, n)
+        if not interpret:
+            raise ValueError(
+                f"int8 pallas kernel cannot run (m, k, n)={shape}: K and N "
+                "must be multiples of 128. TPUFLOW_INT8_MATMUL=auto picks "
+                "XLA for such shapes (GPT-2's 50257-column head is one)."
+            )
         if shape not in _warned_fallback:
             _warned_fallback.add(shape)
             from tpuflow import obs
@@ -359,7 +363,7 @@ def int8_matmul(
         out = _pallas_int8_matmul(
             x2d, wq, w_scale_row,
             w_contract_last=w_contract_last, out_dtype=out_dtype,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret,
         )
     else:
         out = _xla_int8_matmul(

@@ -37,14 +37,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: experimental home, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, **kw):
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return _shard_map_old(f, **kw)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS_STAGE = "stage"
@@ -126,11 +119,8 @@ def make_pipeline_loss(
             state = jax.lax.ppermute(y, AXIS_STAGE, right)
             return (state, loss_acc, aux_acc), None
 
-        # Accumulators are rank-1 ((1,) not scalar): device-varying rank-0
-        # residuals of the scan can't be concatenated by shard_map's grad
-        # machinery on older jax (_check_names rejects names on a rank-0
-        # aval) — the singleton axis costs nothing and transposes cleanly
-        # everywhere.
+        # Accumulators are rank-1 ((1,) not scalar) so each shard's
+        # contribution reshapes straight into its (1, 1) grid cell below.
         (_, loss_acc, aux_acc), _ = jax.lax.scan(
             tick,
             (
@@ -144,9 +134,7 @@ def make_pipeline_loss(
         # its own layers' aux. Each shard emits its CONTRIBUTION as one
         # cell of an (S, D) grid; the replicated global mean is taken
         # OUTSIDE the shard_map (sum over a sharded array is an ordinary
-        # XLA reduction) — device-varying out_specs transpose cleanly
-        # under grad on every jax version, where an in-body psum to a
-        # replicated P() output trips old shard_map's rep tracking.
+        # XLA reduction), which transposes cleanly under grad.
         return (loss_acc + aux_acc).reshape(1, 1)
 
     def loss_fn(stacked_blocks, other, tokens, targets):

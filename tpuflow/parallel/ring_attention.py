@@ -30,13 +30,7 @@ _NEG_INF = -1e30
 def _ring_shard_fn(q, k, v, *, causal: bool, axis_name: str):
     """Per-shard body (inside shard_map). q,k,v: (B, T_local, H, D)."""
     B, Tl, H, D = q.shape
-    size = (
-        jax.lax.axis_size(axis_name)
-        if hasattr(jax.lax, "axis_size")
-        # jax < 0.5 idiom: psum of the unit constant folds to the static
-        # axis size at trace time.
-        else jax.lax.psum(1, axis_name)
-    )
+    size = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
     q32 = q.astype(jnp.float32)
@@ -76,33 +70,10 @@ def _ring_shard_fn(q, k, v, *, causal: bool, axis_name: str):
 
 
 def _current_mesh():
-    # Supported context first: jax.set_mesh(mesh) / jax.sharding.get_mesh().
-    # Under an active jit trace get_mesh() refuses to run; the abstract mesh
-    # carries the axis structure and shard_map accepts it (devices are bound
-    # at lowering from the set_mesh context).
-    if hasattr(jax.sharding, "get_mesh"):
-        try:
-            mesh = jax.sharding.get_mesh()
-            if not getattr(mesh, "empty", True):
-                return mesh
-        except ValueError:
-            mesh = jax.sharding.get_abstract_mesh()
-            if not getattr(mesh, "empty", True):
-                return mesh
-    # Legacy `with mesh:` context: thread_resources via its public
-    # deprecation-path alias (not jax._src). Tolerate removal in a future
-    # JAX: the helpful error below still fires.
-    try:
-        import warnings
+    from tpuflow.parallel.sharding import active_mesh
 
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters.pxla import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        mesh = None
-    if mesh is None or mesh.empty:
+    mesh = active_mesh()
+    if mesh is None:
         raise RuntimeError(
             "ring_attention needs an active mesh: run under `with mesh:` or "
             "`jax.set_mesh(mesh)` (Trainer.fit does this automatically), or "
@@ -151,22 +122,10 @@ def seq_shard_map(body, mesh, axis_name, *, batch: int):
     if batch_axes and batch % batch_size != 0:
         batch_axes = ()  # e.g. model.init traces with batch 1: replicate it
     spec = P(batch_axes if batch_axes else None, axis_name, None, None)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            check_vma=False,
-        )
-    # jax < 0.5: shard_map lives under experimental and the variance check
-    # flag is spelled check_rep.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )

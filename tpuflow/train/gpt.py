@@ -269,23 +269,14 @@ def train_gpt(
     restore's page backing overlaps the setup work here.
     """
     cfg.validate()
-    # Startup latency: point XLA's persistent compilation cache at a
-    # durable directory (default $TPUFLOW_HOME/compile_cache; set
-    # TPUFLOW_COMPILE_CACHE=run to key it under this run's directory —
-    # the right mode when only the run dir is on shared storage, e.g. a
-    # requeued k8s gang whose pod-local home is ephemeral). Retried /
-    # requeued / resumed attempts then load the compiled step instead of
-    # re-paying the 20-40 s TPU compile (BENCH_r05: compile_s 62.9,
-    # wall_to_first_step 125.1 s).
+    # Retried, requeued and resumed attempts load the compiled step from
+    # the persistent cache instead of compiling it again.
     from tpuflow import dist as _dist
 
-    _dist.maybe_enable_compile_cache(
-        run_dir=os.path.dirname(os.path.abspath(ckpt_dir))
-    )
-    # Async-collective scheduling flags for the comm-overlap path
-    # (ISSUE 10) — a best-effort staging for in-process runs: only
-    # effective when no jax backend is up yet (gang members stage them
-    # in gang_exec before ANY backend touch; libtpu reads the env once).
+    _dist.maybe_enable_compile_cache()
+    # For library callers that reach here with no backend up yet; the
+    # flow CLI and gang members staged these before their first device
+    # touch, and a late call logs that the flags were not applied.
     _dist.maybe_enable_async_collectives()
     # Live metrics endpoint (ISSUE 6, opt-in TPUFLOW_OBS_HTTP_PORT): gang
     # member 0 — or an in-process run, which is its own member 0 — serves
@@ -390,6 +381,14 @@ def _run_fsdp_generation(
         mgr = CheckpointManager(
             ckpt_dir, max_to_keep=2, save_dtype=cfg.ckpt_dtype or None
         )
+        from tpuflow import _native
+
+        # Which shard writer this run got: the native one is built from
+        # source on first use and quietly gives way to NumPy when it can't.
+        log(
+            f"[gpt] checkpoint format {mgr.format}, shard writer "
+            f"{'native' if _native.lib() is not None else 'numpy'}"
+        )
         # In-run resume (retry / preemption requeue): a previous attempt of
         # THIS run left committed checkpoints in ckpt_dir — continue from
         # the newest instead of restarting at step 0 (the manager already
@@ -412,7 +411,8 @@ def _run_fsdp_generation(
             # On resume the state is built ABSTRACTLY (shape eval only):
             # materializing 355M random params + zeroed moments just to
             # overwrite every leaf with the restore doubled resume wall
-            # time (MEDIUM_RUNS.md r3: fresh 103 s vs resume 206 s).
+            # time (measured at 355M on CPU devices: 103 s fresh, 206 s
+            # resuming).
             materialize=resume_checkpoint is None and resume_step is None,
         )
         resuming = resume_checkpoint is not None or resume_step is not None
@@ -730,6 +730,7 @@ def _run_fsdp_generation(
                                 )
                         if profile is not None:
                             profile.maybe_start(opt_step + 1)
+                        t_dispatch = time.monotonic()
                         state, metrics = train_step(state, batch, rng)
                         losses.append(metrics["loss"])
                         tokens = int(np.prod(batch["y"].shape))
@@ -740,6 +741,11 @@ def _run_fsdp_generation(
                             # rate accordingly. The cold step settles
                             # inline (never enters the window).
                             jax.block_until_ready(metrics["loss"])
+                            log(
+                                "[gpt] first step (trace, compile or cache "
+                                "load, run) in "
+                                f"{time.monotonic() - t_dispatch:.1f}s"
+                            )
                             t_epoch = time.monotonic()
                             ts_epoch = time.time()
                             compile_s = clock.compile_done(

@@ -634,16 +634,9 @@ _ICI_GBPS = (
 
 
 def _ici_gbps() -> float | None:
-    try:
-        import jax
+    from tpuflow.obs import goodput as _gp
 
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            return None
-        kind = dev.device_kind.lower()
-        return next((v for k, v in _ICI_GBPS if k in kind), 400.0)
-    except Exception:
-        return None
+    return _gp.device_table_value(_ICI_GBPS, "ICI bandwidth")
 
 
 def comm_attribution(

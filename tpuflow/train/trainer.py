@@ -46,6 +46,8 @@ logger = logging.getLogger("tpuflow.train")
 @dataclasses.dataclass
 class ScalingConfig:
     """↔ ray ScalingConfig(num_workers, use_gpu) (my_ray_module.py:240-243).
+    There is no ``use_tpu``: devices are the ones JAX selects, and CPU is
+    chosen with ``dist.force_cpu_platform`` / ``TPUFLOW_FORCE_CPU=1``.
 
     ``num_workers``: data-parallel shard count; ``None``/-1 → every device.
     ``mesh_axes``: optional full mesh spec (e.g. {'data': 4, 'tensor': 2}) for
@@ -58,7 +60,6 @@ class ScalingConfig:
     """
 
     num_workers: int | None = None
-    use_tpu: bool = True  # kept for config parity; devices come from jax
     mesh_axes: dict[str, int] | None = None
     dcn_mesh_axes: dict[str, int] | None = None
     rendezvous_timeout_s: float = 300.0  # ↔ all_nodes_started_timeout
@@ -461,11 +462,9 @@ class Trainer:
 
     def fit(self) -> Result:
         global _ACTIVE_CONTEXT
-        # Persistent XLA compile cache (default-on; TPUFLOW_COMPILE_CACHE
-        # =run keys it under this run's storage path): retried/requeued
-        # attempts reload the compiled step instead of re-paying the
-        # first-compile wall time. See dist.maybe_enable_compile_cache.
-        dist.maybe_enable_compile_cache(run_dir=self.run_config.storage_path)
+        # Retried/requeued attempts reload the compiled step from the
+        # persistent cache instead of re-paying the first-compile wall.
+        dist.maybe_enable_compile_cache()
         # Live goodput + metrics endpoint (ISSUE 6): restart the ledger
         # for this fit, and serve /metrics + /status when opted in via
         # TPUFLOW_OBS_HTTP_PORT (member 0 only; one env lookup when off).

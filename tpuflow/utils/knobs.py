@@ -99,14 +99,16 @@ REGISTRY: dict[str, Knob] = dict(
     (
         # ----------------------------------------------------------- flow
         _k("TPUFLOW_HOME", "path", "~/.tpuflow",
-           "root for run/artifact storage, deployments, and the default "
-           "compile cache", "flow", _A_SETUP),
+           "root for run/artifact storage and deployments", "flow",
+           _A_SETUP),
         _k("TPUFLOW_NAMESPACE", "str", None,
            "namespace runs are produced under (default `user:<login>`)",
            "flow", _A_SETUP),
-        _k("TPUFLOW_N_PARALLEL", "int", 2,
-           "gang width the example flows launch (processes forming one "
-           "jax.distributed world)", "flow", _A_SETUP),
+        _k("TPUFLOW_N_PARALLEL", "int", 1,
+           "gang width the example flows launch: one process per HOST "
+           "(it owns all local chips); N > 1 on one host is the CPU "
+           "simulation and is refused on an accelerator", "flow",
+           _A_SETUP),
         _k("TPUFLOW_TOPOLOGY", "str", "v5e-8",
            "TPU topology the @kubernetes example flows request",
            "flow", _A_DEPLOY),
@@ -144,11 +146,9 @@ REGISTRY: dict[str, Knob] = dict(
            "hold a relaunch until every survivor's post-shrink heartbeat "
            "reappears (or this many seconds pass)", "flow", _A_ELASTIC),
         _k("TPUFLOW_FORCE_CPU", "bool", False,
-           "1 = pin gang subprocesses / platform probe to XLA:CPU "
-           "virtual devices", "flow", _A_FLOW),
-        _k("TPUFLOW_PREWARM_CACHE", "path", None,
-           "prewarmed compile-cache dir gang members seed their cache "
-           "from (rsync-style, missing entries only)", "flow", _A_STEP),
+           "1 = run flows and gang subprocesses on XLA:CPU virtual "
+           "devices (the explicit CPU choice; nothing falls back to it)",
+           "flow", _A_FLOW),
         # Launcher/member plumbing — stamped by the supervisor, read by
         # members; never set by operators.
         _k("TPUFLOW_ATTEMPT", "int", 0,
@@ -184,11 +184,10 @@ REGISTRY: dict[str, Knob] = dict(
            "pip requirements line the k8s manifest installs in member "
            "pods", "flow", _A_DEPLOY, internal=True),
         # ----------------------------------------------------------- dist
-        _k("TPUFLOW_COMPILE_CACHE", "str", None,
-           "persistent XLA compile cache: a directory, `run` "
-           "(<run_dir>/compile_cache), or 0/off to disable (default: "
-           "$TPUFLOW_HOME/compile_cache on accelerators)", "dist",
-           _A_STEP, default_doc="$TPUFLOW_HOME/compile_cache"),
+        _k("TPUFLOW_COMPILE_CACHE", "bool", True,
+           "0 = disable the persistent XLA compile cache (on by default "
+           "on accelerators, at JAX_COMPILATION_CACHE_DIR where set, else "
+           ".compile_cache in the checkout)", "dist", _A_STEP),
         _k("TPUFLOW_COMPILE_CACHE_CPU", "bool", False,
            "1 = force-enable the persistent compile cache on CPU "
            "(default off: the XLA:CPU AOT reloader can SIGABRT across "
@@ -200,13 +199,6 @@ REGISTRY: dict[str, Knob] = dict(
         _k("TPUFLOW_DCN_DATA", "int", 0,
            "N = put the worker mesh's data axis on the DCN (multi-slice) "
            "axis at width N", "dist", _A_FSDP),
-        _k("TPUFLOW_PLATFORM_BACKEND", "str", None,
-           "platform the probe/conftest pinned for this process (cpu | "
-           "tpu); consumed pre-init by mesh/bench", "dist", _A_FLOW,
-           internal=True),
-        _k("TPUFLOW_PLATFORM_PROBED", "str", None,
-           "cached platform-probe verdict (default | cpu) so respawns "
-           "skip the subprocess probe", "dist", _A_FLOW, internal=True),
         # ---------------------------------------------------------- train
         _k("TPUFLOW_DISPATCH_DEPTH", "int", 2,
            "steps in flight before the hot loop settles the oldest "
@@ -218,11 +210,8 @@ REGISTRY: dict[str, Knob] = dict(
            choices=("full", "dots", "none"),
            default_doc="model preset's policy"),
         _k("TPUFLOW_TRAIN_MODE", "str", None,
-           "`tpu` routes bench train legs through the real gang path",
-           "train", _A_BENCH),
-        _k("TPUFLOW_TRAIN_SMOKE", "bool", True,
-           "0 = skip the on-TPU pre-bench train smoke", "bench",
-           _A_BENCH),
+           "`tpu` = the bench train child must run on the `tpu` backend "
+           "(it fails on any other); unset = the CPU", "train", _A_BENCH),
         # ----------------------------------------------------------- data
         _k("TPUFLOW_DATA_DIR", "path", None,
            "dataset root (IDX/corpus files); unset → synthetic "
@@ -650,8 +639,6 @@ REGISTRY: dict[str, Knob] = dict(
         # ---------------------------------------------------------- bench
         _k("TPUFLOW_BENCH_TRAIN", "bool", True,
            "0 = skip bench train legs", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_TRAIN_TIMEOUT", "float", 480.0,
-           "bench train-leg subprocess timeout (s)", "bench", _A_BENCH),
         _k("TPUFLOW_BENCH_SERVE", "bool", True,
            "0 = skip the serving bench leg", "bench", _A_BENCH),
         _k("TPUFLOW_BENCH_ROUTER", "bool", True,
@@ -662,7 +649,7 @@ REGISTRY: dict[str, Knob] = dict(
         _k("TPUFLOW_BENCH_DISAGG", "bool", True,
            "0 = skip the serving.disagg bench leg (TTFT cold vs "
            "tier-hit vs cross-engine ship on one hot prompt set; "
-           "records ttft_tier_hit_vs_cold — fresh on-chip gate < 1.0 "
+           "records ttft_tier_hit_vs_cold — on-chip gate < 1.0 "
            "— per-tier hit rates, and ship exactness)", "bench",
            _A_BENCH),
         _k("TPUFLOW_BENCH_INT8", "bool", True,
@@ -683,17 +670,6 @@ REGISTRY: dict[str, Knob] = dict(
            "device-IO bench payload (GiB)", "bench", _A_BENCH),
         _k("TPUFLOW_BENCH_DIR", "path", None,
            "bench scratch/output directory", "bench", _A_BENCH),
-        # ------------------------------------------------------------ e2e
-        _k("TPUFLOW_E2E_ALLOW_CPU", "bool", False,
-           "1 = let tools/e2e_tpu.py run on CPU", "e2e", _A_BENCH),
-        _k("TPUFLOW_E2E_GPT_PRESET", "str", "gpt2",
-           "e2e GPT preset", "e2e", _A_BENCH),
-        _k("TPUFLOW_E2E_GPT_SEQ", "int", 512,
-           "e2e GPT sequence length", "e2e", _A_BENCH),
-        _k("TPUFLOW_E2E_GPT_DATA_AXIS", "int", 1,
-           "e2e GPT data-axis width", "e2e", _A_BENCH),
-        _k("TPUFLOW_E2E_GPT_FSDP_AXIS", "int", 1,
-           "e2e GPT fsdp-axis width", "e2e", _A_BENCH),
         # ---------------------------------------------------------- flows
         _k("TPUFLOW_STORAGE", "path", "/tmp/tpuflow_run",
            "checkpoint storage path the example custom-Trainer flow "
@@ -721,7 +697,6 @@ _SUBSYSTEM_TITLES = (
     ("alerts", "Run registry & alerting"),
     ("testing", "Fault injection & testing"),
     ("bench", "Benchmark"),
-    ("e2e", "On-chip e2e"),
 )
 
 MARKDOWN_BEGIN = (
