@@ -349,7 +349,7 @@ def test_obs_catalog_lint():
         ("gauge", "serve.tokens_per_s"),
         ("counter", "serve.tokens"),
         ("counter", "serve.requests"),
-        ("event", "serve.admit"),
+        ("span", "serve.admit"),
         ("event", "serve.complete"),
         ("span", "serve.warmup"),
         ("span", "serve.prefill"),
@@ -430,7 +430,6 @@ def test_obs_catalog_lint():
         ("counter", "trace.dropped"),
         # Native int8 decode (ISSUE 9) with the right kinds (also
         # REQUIRED_EMITTERS below — same standalone/pytest cross-check).
-        ("span", "serve.quant_decode"),
         ("counter", "serve.quant_requests"),
         ("event", "quant.decision"),
         ("event", "quant.kernel_fallback"),

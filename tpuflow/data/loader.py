@@ -235,20 +235,27 @@ def prefetch_to_device(loader, mesh, *, depth: int | None = None, keys=None,
         # Kept deliberately bare — one generator frame over the loader —
         # so disabling prefetch never costs more than the work it defers.
         def _inline():
-            obs_on = obs.enabled()
-            for batch in loader:
-                if obs_on:
-                    import time
+            import time
 
-                    t0 = time.monotonic()
+            obs_on = obs.enabled()
+
+            def _placed(batch):
                 if keys is not None:
                     batch = {k: batch[k] for k in keys}
-                placed = place(batch)
-                if obs_on:
-                    wait = time.monotonic() - t0
-                    obs.histogram("data.batch_wait_s", wait)
-                    obs.gauge("data.host_wait_s", wait)
-                    obs.counter("data.prefetch_miss")
+                return place(batch)
+
+            for batch in loader:
+                if not obs_on:
+                    yield _placed(batch)
+                    continue
+                # Inline, the consumer's wait is the placement itself.
+                t0 = time.monotonic()
+                with obs.span("data.wait", hit=False):
+                    placed = _placed(batch)
+                wait = time.monotonic() - t0
+                obs.histogram("data.batch_wait_s", wait)
+                obs.gauge("data.host_wait_s", wait)
+                obs.counter("data.prefetch_miss")
                 yield placed
 
         return _inline()
@@ -298,7 +305,8 @@ def _prefetch_threaded(loader, place, depth: int, keys):
 
                 hit = not q.empty()
                 t0 = time.monotonic()
-                item = q.get()
+                with obs.span("data.wait", hit=hit):
+                    item = q.get()
                 wait = time.monotonic() - t0
                 obs.histogram("data.batch_wait_s", wait)
                 # The overlap proof: ~0 on every hit means the input

@@ -23,6 +23,7 @@ from __future__ import annotations
 import logging
 import math
 import os
+import threading
 from typing import Mapping, Sequence
 
 import jax
@@ -70,6 +71,53 @@ COMPILE_CACHE_DIR = os.path.join(
 )
 
 
+# JAX's own monitoring events around one backend compile: the duration
+# event wraps compile-or-load; inside it the cache says hit or miss.
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_cache_outcome = threading.local()  # the compiling thread's last hit/miss
+_compile_listener_on = False
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _cache_outcome.hit = True
+    elif event == _CACHE_MISS:
+        _cache_outcome.hit = False
+
+
+def _on_compile_duration(event: str, duration_secs: float, **kw) -> None:
+    """One ``compile`` span per backend compile-or-load, with whether the
+    persistent cache served it (None: the cache was not asked) and the
+    program's name as JAX gives it."""
+    if event != _BACKEND_COMPILE:
+        return
+    hit = getattr(_cache_outcome, "hit", None)
+    _cache_outcome.hit = None
+    from tpuflow import obs
+    from tpuflow.obs.recorder import ended_span
+
+    rec = obs.recorder()
+    if rec is None:
+        return
+    rec.record(
+        "span", "compile", **ended_span(duration_secs), cache_hit=hit,
+        program=kw.get("fun_name"),
+    )
+
+
+def _register_compile_listener() -> None:
+    """Once per process: compiles (and loads from the persistent cache)
+    become spans of the recorder whenever it is on."""
+    global _compile_listener_on
+    if _compile_listener_on:
+        return
+    _compile_listener_on = True
+    jax.monitoring.register_event_listener(_on_cache_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+
+
 def maybe_enable_compile_cache() -> str | None:
     """Turn on JAX's persistent compilation cache; return its directory.
 
@@ -92,6 +140,7 @@ def maybe_enable_compile_cache() -> str | None:
     reloaded from cache). CPU compiles are seconds, so the cache buys
     nothing there; ``TPUFLOW_COMPILE_CACHE_CPU=1`` force-enables it.
     """
+    _register_compile_listener()
     if not knobs.get_bool("TPUFLOW_COMPILE_CACHE"):
         return None
     if platform_is_cpu() and knobs.raw("TPUFLOW_COMPILE_CACHE_CPU") != "1":

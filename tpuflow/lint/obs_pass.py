@@ -94,7 +94,7 @@ REQUIRED_EMITTERS: tuple[tuple[str, str], ...] = (
     ("gauge", "serve.tokens_per_s"),
     ("counter", "serve.tokens"),
     ("counter", "serve.requests"),
-    ("event", "serve.admit"),
+    ("span", "serve.admit"),
     ("event", "serve.complete"),
     ("span", "serve.warmup"),
     ("span", "serve.prefill"),
@@ -103,10 +103,21 @@ REQUIRED_EMITTERS: tuple[tuple[str, str], ...] = (
     ("gauge", "serve.prefix_hits"),
     ("gauge", "serve.spec_accept_rate"),
     ("event", "serve.page_evict"),
-    ("span", "serve.quant_decode"),
     ("counter", "serve.quant_requests"),
     # Serving observatory (ISSUE 13): lifecycle traces, engine-time
     # ledger fractions, and declared-SLO accounting.
+    # Spans on the profiler's clock (ISSUE 26): the engine step's
+    # phases, the per-request interval, and where set-up goes.
+    ("span", "serve.step"),
+    ("span", "serve.insert"),
+    ("span", "serve.decode.dispatch"),
+    ("span", "serve.decode.fence"),
+    ("span", "serve.decode.merge"),
+    ("span", "serve.harvest"),
+    ("event", "serve.first_token"),
+    ("span", "data.wait"),
+    ("span", "compile"),
+    ("span", "state.init"),
     ("event", "serve.trace"),
     ("event", "serve.slo_violation"),
     ("counter", "serve.slo_violations"),

@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from tpuflow.ops import attention
@@ -202,19 +203,18 @@ def _masked_attention(q, k, v, valid, precision=None):
     that no real query ever attends to, so it stays isolated.
     ``precision`` pins the einsum matmul precision (the decode path
     passes Precision.HIGHEST for width-independent MXU rounding)."""
-    import jax
-
     D = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
-    s = jnp.einsum(
-        "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32),
-        precision=precision,
-    ) * scale
-    s = jnp.where(valid, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum(
-        "bhqk,bkhd->bqhd", p, v.astype(jnp.float32), precision=precision
-    ).astype(q.dtype)
+    with jax.named_scope("attn_core"):
+        scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+        s = jnp.einsum(
+            "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32),
+            precision=precision,
+        ) * scale
+        s = jnp.where(valid, s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum(
+            "bhqk,bkhd->bqhd", p, v.astype(jnp.float32), precision=precision
+        ).astype(q.dtype)
 
 
 def _left_pad_attention(q, k, v, pad_lens):
@@ -371,10 +371,12 @@ class Block(nn.Module):
             )
             return body.reshape(n_pages, ps, H, D)
 
-        ck.value = scatter(ck.value, k)
-        cv.value = scatter(cv.value, v)
-        k_all = ck.value[page_table].reshape(B, cfg.n_ctx, H, D)
-        v_all = cv.value[page_table].reshape(B, cfg.n_ctx, H, D)
+        with jax.named_scope("kv_write"):
+            ck.value = scatter(ck.value, k)
+            cv.value = scatter(cv.value, v)
+        with jax.named_scope("kv_read"):
+            k_all = ck.value[page_table].reshape(B, cfg.n_ctx, H, D)
+            v_all = cv.value[page_table].reshape(B, cfg.n_ctx, H, D)
         k_pos = jnp.arange(cfg.n_ctx)
         valid = k_pos[None, None, None, :] <= pos[:, None, :, None]
         if pad_lens is not None:
@@ -414,8 +416,6 @@ class Block(nn.Module):
         is exact for every (start, pad) combination (``lax.cond`` picks the
         branch at runtime, so both compile into the one program).
         """
-        import jax
-
         cfg = self.config
         B, T, H, D = q.shape
         if slot_index is not None and page_table is not None:
@@ -452,12 +452,13 @@ class Block(nn.Module):
                     cache_row, new_row, (s, 0, 0)
                 )
 
-            ck.value = jax.vmap(row_write)(
-                ck.value, k.astype(cdt), slot_index
-            )
-            cv.value = jax.vmap(row_write)(
-                cv.value, v.astype(cdt), slot_index
-            )
+            with jax.named_scope("kv_write"):
+                ck.value = jax.vmap(row_write)(
+                    ck.value, k.astype(cdt), slot_index
+                )
+                cv.value = jax.vmap(row_write)(
+                    cv.value, v.astype(cdt), slot_index
+                )
             q_pos = slot_index[:, None] + jnp.arange(T)[None, :]  # (B, T)
             k_pos = jnp.arange(cfg.n_ctx)
             valid = (
@@ -472,12 +473,13 @@ class Block(nn.Module):
                 q, ck.value, cv.value, valid, precision=precision
             )
         start = idx.value
-        ck.value = jax.lax.dynamic_update_slice(
-            ck.value, k.astype(cdt), (0, start, 0, 0)
-        )
-        cv.value = jax.lax.dynamic_update_slice(
-            cv.value, v.astype(cdt), (0, start, 0, 0)
-        )
+        with jax.named_scope("kv_write"):
+            ck.value = jax.lax.dynamic_update_slice(
+                ck.value, k.astype(cdt), (0, start, 0, 0)
+            )
+            cv.value = jax.lax.dynamic_update_slice(
+                cv.value, v.astype(cdt), (0, start, 0, 0)
+            )
         idx.value = start + T
 
         def cache_attention():
@@ -585,8 +587,6 @@ class GPT2(nn.Module):
             # Autoregressive mode: positions continue from the model-level
             # cache index (the blocks keep their own KV indices in the same
             # 'cache' collection; see Block._cached_attention).
-            import jax
-
             pos = self.variable(
                 "cache", "pos_index", lambda: jnp.zeros((), jnp.int32)
             )
@@ -632,8 +632,6 @@ class GPT2(nn.Module):
         x = pin_batch(x)
         x = nn.Dropout(cfg.dropout, deterministic=not train)(x)
         def remat_wrap(mod):
-            import jax as _jax
-
             policy = None
             if cfg.remat_policy == "dots":
                 # The ISSUE 10 selector's middle ground: save every MXU
@@ -643,7 +641,7 @@ class GPT2(nn.Module):
                 # kernel re-run jax's remat can't elide — see the
                 # checkpoint_name note in ops/flash_attention.py; the
                 # zero-recompute mode is remat OFF, selector 'none').
-                cp = _jax.checkpoint_policies
+                cp = jax.checkpoint_policies
                 policy = cp.save_from_both_policies(
                     cp.dots_with_no_batch_dims_saveable,
                     cp.save_only_these_names("flash_out"),
@@ -651,7 +649,7 @@ class GPT2(nn.Module):
             elif cfg.remat_policy:
                 try:
                     policy = getattr(
-                        _jax.checkpoint_policies, cfg.remat_policy
+                        jax.checkpoint_policies, cfg.remat_policy
                     )
                 except AttributeError:
                     raise ValueError(
@@ -703,10 +701,11 @@ class GPT2(nn.Module):
             from tpuflow.ops.int8_matmul import int8_matmul
 
             head = self.get_variable("quant", "wte_q")
-            return int8_matmul(
-                x, head.q, head.scale, w_contract_last=True,
-                out_dtype=jnp.float32,
-            )
+            with jax.named_scope("lm_head"):
+                return int8_matmul(
+                    x, head.q, head.scale, w_contract_last=True,
+                    out_dtype=jnp.float32,
+                )
         # Weight-tied LM head; logits come straight out of the MXU's f32
         # accumulator (preferred_element_type) — never rounded through
         # bf16. The old einsum→bf16→f32 path collapsed near-tie logits
@@ -715,14 +714,15 @@ class GPT2(nn.Module):
         # the r4 on-chip speculative numerics_ok=false; decode_dtype
         # handles the layer-stack part). f32 logits also feed a stable
         # softmax/CE in training.
-        return jnp.einsum(
-            "btc,vc->btv",
-            x,
-            wte.astype(dt),
-            preferred_element_type=jnp.float32,
-            # Decode non-prefill: HIGHEST precision so the logits'
-            # rounding is width-independent on the MXU too (the f32
-            # accumulator alone does not fix the bf16 multiply passes
-            # DEFAULT precision lowers f32 operands to).
-            precision=cfg.matmul_precision(decode and not prefill),
-        )
+        with jax.named_scope("lm_head"):
+            return jnp.einsum(
+                "btc,vc->btv",
+                x,
+                wte.astype(dt),
+                preferred_element_type=jnp.float32,
+                # Decode non-prefill: HIGHEST precision so the logits'
+                # rounding is width-independent on the MXU too (the f32
+                # accumulator alone does not fix the bf16 multiply passes
+                # DEFAULT precision lowers f32 operands to).
+                precision=cfg.matmul_precision(decode and not prefill),
+            )

@@ -165,6 +165,27 @@ CATALOG: dict[str, tuple[str, str]] = {
         "(~0 on every prefetch hit = the input pipeline ran entirely "
         "behind device compute)",
     ),
+    "data.wait": (
+        "span",
+        "the consuming loop blocked for one batch (hit = the batch was "
+        "already queued); the span form of data.host_wait_s, on the "
+        "profiler's host plane in a traced run",
+    ),
+    # ------------------------------------------------------------- set-up
+    # Where launch-to-first-step goes (ISSUE 26): one span per backend
+    # compile-or-load from JAX's own monitoring events, and the state's
+    # initializer.
+    "compile": (
+        "span",
+        "one backend compile of a jitted program, or its load from the "
+        "persistent cache (cache_hit true/false, null where the cache "
+        "was not asked; program = the name JAX gives it)",
+    ),
+    "state.init": (
+        "span",
+        "create_sharded_state: trace, compile-or-load and dispatch of "
+        "the born-sharded initializer (execution not awaited)",
+    ),
     # --------------------------------------------------------------- infer
     "infer.predict": ("span", "BatchPredictor forward over one batch"),
     "infer.generate": ("span", "one generate() call; tokens + tokens/s"),
@@ -189,13 +210,54 @@ CATALOG: dict[str, tuple[str, str]] = {
     ),
     "serve.decode": (
         "span",
-        "one decode block of the persistent slot-based program "
-        "(decode_block tokens per live slot, one host sync)",
+        "one decode (spec: verify) block of the persistent slot-based "
+        "program over one group's slots (quant = the int8 twin; "
+        "decode_block tokens per live slot, one host sync)",
+    ),
+    "serve.decode.dispatch": (
+        "span",
+        "child of serve.decode: host drafts and the enqueue of the "
+        "block's program",
+    ),
+    "serve.decode.fence": (
+        "span",
+        "child of serve.decode: the host copy of the block's tokens, "
+        "the one place the engine waits for the device",
+    ),
+    "serve.decode.merge": (
+        "span",
+        "child of serve.decode: the block's carries merged back into "
+        "the host's slot state through the group mask",
+    ),
+    "serve.harvest": (
+        "span",
+        "a block's tokens handed to their requests, per-token latencies "
+        "noted, ended requests' slots and pages freed",
+    ),
+    "serve.step": (
+        "span",
+        "one scheduler iteration, the root of the engine's spans: "
+        "admissions, then one decode block per live group",
     ),
     "serve.admit": (
+        "span",
+        "one admission attempt of the head of the queue (request, "
+        "admitted): page allocation and prefix lookup, with "
+        "serve.prefill and serve.insert inside it. admitted=true adds "
+        "slot, bucket, queue_wait_s, pages, shared_pages; an attempt "
+        "the page pool refused (admitted=false, the request stays "
+        "queued) carries only request and admitted",
+    ),
+    "serve.insert": (
+        "span",
+        "a prefill row (or restored pages) written into the cache for "
+        "one request",
+    ),
+    "serve.first_token": (
         "event",
-        "a queued request entered a free slot (request, slot, bucket, "
-        "queue_wait_s)",
+        "a request's first token exists (request; mono = the engine's "
+        "own stamp): with serve.complete it bounds the interval in "
+        "which the request decodes",
     ),
     "serve.complete": (
         "event",
@@ -343,15 +405,9 @@ CATALOG: dict[str, tuple[str, str]] = {
         "OUT of the dispatching group's program — what the "
         "(fp,int8)x(spec,plain) partition costs on mixed traffic",
     ),
-    # Per-request int8 serving (ISSUE 9): the quantized twin of the
-    # persistent decode program, plus the completion trail that lets an
-    # operator split throughput by numeric path.
-    "serve.quant_decode": (
-        "span",
-        "one decode block of the INT8 (fused-native W8A8) persistent "
-        "program over the quantize=True slots — runs beside serve.decode "
-        "when fp and int8 requests share the engine",
-    ),
+    # Per-request int8 serving (ISSUE 9): the completion trail that lets
+    # an operator split throughput by numeric path (the int8 twin of the
+    # decode program is a serve.decode span with quant=true).
     "serve.quant_requests": (
         "counter",
         "completed requests that decoded through the int8 path (subset "

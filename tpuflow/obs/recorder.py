@@ -21,10 +21,23 @@ Event schema (one JSON object per line)::
 
     {"kind": "span|counter|gauge|histogram|event",
      "name": "<catalog name>", "ts": <wall-clock start, s>,
+     "mono": <time.monotonic() at the same instant, s>,
      "proc": <gang process index>, "pid": <os pid>,
      "launch": <launch attempt, gang members only (TPUFLOW_ATTEMPT)>,
      "dur_s": <monotonic duration, spans only>,
+     "span": <span id, unique in the process, spans only>,
+     "parent": <id of the span open on the same thread when this one
+                opened, or null; spans only>,
      "value": <counter/gauge/histogram payload>, ...attrs}
+
+An enabled span also enters a ``jax.profiler.TraceAnnotation`` of its
+name for its lifetime, in a process that has imported jax (the recorder
+itself never imports it): under a profiler session the span lands on
+the ``/host:`` plane of the same ``.xplane.pb`` as the device
+operations, so an idle gap of the device can be put down to what the
+program was doing. ``mono`` puts spans and point events (a request's
+first token, its completion) on one clock; ``request=`` is the attribute
+that ties a span to one serving request.
 
 The ``launch`` stamp (a dedicated key — several flow events already
 carry their own ``attempt`` attribute, which must not be confused with
@@ -40,8 +53,10 @@ from __future__ import annotations
 
 import atexit
 import collections
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any
@@ -71,8 +86,52 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+# Span identity: ids are unique in the process (``next`` on a count is
+# atomic under the GIL); the spans open on a thread form that thread's
+# stack, so a span's parent is whatever its own thread had open.
+_SPAN_IDS = itertools.count(1)
+_OPEN = threading.local()
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _open_spans() -> list[int]:
+    try:
+        return _OPEN.stack
+    except AttributeError:
+        _OPEN.stack = []
+        return _OPEN.stack
+
+
+def _annotation(name: str):
+    """A profiler annotation for an enabled span, or None in a process
+    that has not imported jax: the recorder never imports it itself."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _TRACE_ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+        if _TRACE_ANNOTATION is None:
+            return None
+    return _TRACE_ANNOTATION(name)
+
+
+def ended_span(dur_s: float) -> dict:
+    """The clock and identity fields of a span that someone else timed
+    and that has just ended on this thread (JAX's compile listener):
+    spread into ``record("span", name, **ended_span(d), ...)``."""
+    stack = _open_spans()
+    return {
+        "ts": time.time() - dur_s,
+        "mono": time.monotonic() - dur_s,
+        "dur_s": dur_s,
+        "span": next(_SPAN_IDS),
+        "parent": stack[-1] if stack else None,
+    }
+
+
 class _Span:
-    __slots__ = ("_rec", "_name", "_attrs", "_t0", "_ts")
+    __slots__ = (
+        "_rec", "_name", "_attrs", "_t0", "_ts", "_id", "_parent", "_ann",
+    )
 
     def __init__(self, rec: "Recorder", name: str, attrs: dict):
         self._rec = rec
@@ -85,16 +144,31 @@ class _Span:
         return self
 
     def __enter__(self):
+        stack = _open_spans()
+        self._parent = stack[-1] if stack else None
+        self._id = next(_SPAN_IDS)
+        stack.append(self._id)
+        self._ann = _annotation(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._ts = time.time()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, *exc):
         dur = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, *exc)
+        stack = _open_spans()
+        if stack and stack[-1] == self._id:
+            stack.pop()
+        elif self._id in stack:  # closed out of order (a generator's span)
+            stack.remove(self._id)
         if exc_type is not None:
             self._attrs.setdefault("error", exc_type.__name__)
         self._rec.record(
-            "span", self._name, ts=self._ts, dur_s=dur, **self._attrs
+            "span", self._name, ts=self._ts, mono=self._t0, dur_s=dur,
+            span=self._id, parent=self._parent, **self._attrs
         )
         return False
 
@@ -171,11 +245,13 @@ class Recorder:
         self._thread.start()
 
     # ------------------------------------------------------------- record
-    def record(self, kind: str, name: str, *, ts: float | None = None, **attrs) -> None:
+    def record(self, kind: str, name: str, *, ts: float | None = None,
+               mono: float | None = None, **attrs) -> None:
         ev = {
             "kind": kind,
             "name": name,
             "ts": time.time() if ts is None else ts,
+            "mono": time.monotonic() if mono is None else mono,
             "proc": self.proc,
             "pid": os.getpid(),
             **attrs,

@@ -229,6 +229,8 @@ def create_sharded_state(
     355M-param init materializes ~4 GiB of random weights + zeroed adamw
     moments that the restore immediately discards).
     """
+    from tpuflow import obs
+
     abstract = jax.eval_shape(init_fn, *init_args)
     shardings = make_shardings(
         abstract, mesh, fsdp=fsdp, tensor_rules=tensor_rules
@@ -240,5 +242,8 @@ def create_sharded_state(
             shardings,
         )
         return abstract, shardings
-    state = jax.jit(init_fn, out_shardings=shardings)(*init_args)
+    # Trace, compile-or-load (a `compile` span of its own inside this
+    # one) and dispatch of the initializer; its execution is not awaited.
+    with obs.span("state.init"):
+        state = jax.jit(init_fn, out_shardings=shardings)(*init_args)
     return state, shardings

@@ -448,7 +448,8 @@ def make_train_step(
             )
             from tpuflow.models.losses import sum_sown_losses
 
-            loss = loss_fn(logits, mb["y"]) + sum_sown_losses(updates)
+            with jax.named_scope("loss"):
+                loss = loss_fn(logits, mb["y"]) + sum_sown_losses(updates)
             return loss, (logits, updates)
 
         grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
@@ -519,10 +520,11 @@ def make_train_step(
         # Explicit tx.update (what TrainState.apply_gradients wraps): the
         # produced ``updates`` tree feeds the health telemetry below
         # without a second optimizer pass or a params diff.
-        updates, new_opt_state = state.tx.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = state.tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1, params=new_params, opt_state=new_opt_state
         )
@@ -545,13 +547,13 @@ def make_train_step(
         # numerics telemetry (update/param norms, fused NaN/Inf flag) —
         # tiny fused reductions, noise next to the backward pass; the
         # HealthMonitor and the health.* gauges read these post-fence.
+        # Under the update's scope: XLA fuses the update into these
+        # reductions over the same arrays, and a fusion carries one name.
         from tpuflow.train.optim import health_stats
 
-        metrics = {
-            "loss": loss,
-            "accuracy": acc,
-            **health_stats(loss, grads, updates, new_params),
-        }
+        with jax.named_scope("optimizer"):
+            health = health_stats(loss, grads, updates, new_params)
+        metrics = {"loss": loss, "accuracy": acc, **health}
         return new_state, metrics
 
     return jax.jit(train_step, donate_argnums=(0,) if donate else ())
