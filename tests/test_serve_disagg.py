@@ -39,9 +39,8 @@ from tpuflow.infer.serve import ServeEngine, resolve_serve_role
 from tpuflow.models.gpt2 import GPT2, GPT2Config
 
 
-@pytest.fixture(scope="module")
-def model_params():
-    cfg = GPT2Config.small_test(n_ctx=64, dropout=0.0)
+def _model_params(**kw):
+    cfg = GPT2Config.small_test(n_ctx=64, dropout=0.0, **kw)
     model = GPT2(cfg)
     params = model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
@@ -50,15 +49,16 @@ def model_params():
 
 
 @pytest.fixture(scope="module")
+def model_params():
+    return _model_params()
+
+
+@pytest.fixture(scope="module")
 def store_dir(tmp_path_factory):
     return str(tmp_path_factory.mktemp("kvstore"))
 
 
-@pytest.fixture(scope="module")
-def ship_pair(model_params, store_dir):
-    """One warmed prefill-role + one warmed decode-role engine sharing
-    a KV store — the disaggregated topology, in-process. Shared by the
-    fast ship tests; compile baselines are pinned per test."""
+def _ship_pair(model_params, store_dir):
     model, params = model_params
     pf = ServeEngine(
         model, params, max_slots=2, buckets=[8, 16], decode_block=4,
@@ -71,6 +71,36 @@ def ship_pair(model_params, store_dir):
     )
     dc.warmup()
     return pf, dc
+
+
+@pytest.fixture(scope="module")
+def ship_pair(model_params, store_dir):
+    """One warmed prefill-role + one warmed decode-role engine sharing
+    a KV store — the disaggregated topology, in-process. Shared by the
+    fast ship tests; compile baselines are pinned per test."""
+    return _ship_pair(model_params, store_dir)
+
+
+@pytest.fixture(scope="module")
+def scan_shipping(tmp_path_factory):
+    """The same pair under ``scan_layers``: pages are exported from and
+    imported into the layer-stacked pool (ISSUE 27)."""
+    model_params = _model_params(scan_layers=True)
+    return model_params, _ship_pair(
+        model_params, str(tmp_path_factory.mktemp("kvstore_scan"))
+    )
+
+
+@pytest.fixture(params=["blocks", "scan"])
+def shipping(request):
+    """``((model, params), (prefill engine, decode engine))`` for each
+    paged cache layout."""
+    if request.param == "scan":
+        return request.getfixturevalue("scan_shipping")
+    return (
+        request.getfixturevalue("model_params"),
+        request.getfixturevalue("ship_pair"),
+    )
 
 
 def _solo(model, params, prompt, n_new):
@@ -109,14 +139,11 @@ def test_resolve_serve_role(monkeypatch, capsys):
 
 
 # ----------------------------------------------------------- fast: ship
-def test_ship_roundtrip_bit_equal_zero_decode_prefill(
-    model_params, ship_pair
-):
+def test_ship_roundtrip_bit_equal_zero_decode_prefill(shipping):
     """The tentpole roundtrip: prefill engine ships, decode engine
     imports, tokens are bit-equal to solo generate(), the decode engine
     never ran a prefill, and neither engine compiled anything new."""
-    model, params = model_params
-    pf, dc = ship_pair
+    (model, params), (pf, dc) = shipping
     pf_base, dc_base = pf.compile_stats(), dc.compile_stats()
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, 512, size=9).astype(np.int32)

@@ -980,30 +980,31 @@ class ServeEngine:
         guards each page: shared prefix pages and unneeded tail entries
         are masked OFF — their writes route to the trash page — so a
         refcounted page is never rewritten by a matching admission.
-        All three controls are DATA (no recompile per admission)."""
-        ps = self.page_size
-        pages_per_slot = self.pages_per_slot
+        All three controls are DATA (no recompile per admission).
+
+        The pool is updated in place by index, as the decode program
+        does it (``Block._paged_attention``): a K or V leaf
+        ``(..., n_pages, page_size, H, D)`` — one block's pool, or the
+        layer-stacked one — is flattened over its leading axes to
+        ``(layers * n_pages, page_size, H, D)`` and takes ONE scatter of
+        the row's ``layers * pages_per_slot`` pages at
+        ``layer * n_pages + page``. Nothing is read back from the pool
+        and no other page is touched."""
         idx = jnp.where(write_mask, table_row, 0)
 
         def put(pool, row):
             if pool.ndim < 4 or row.ndim < 4:
                 return pool  # scalar index leaves pass through
-
-            def one(pl, rw):
-                shifted = jnp.roll(rw[0], -pad, axis=0)  # (n_ctx, H, D)
-                pages = shifted.reshape(
-                    pages_per_slot, ps, *shifted.shape[1:]
-                ).astype(pl.dtype)
-                return pl.at[idx].set(
-                    jnp.where(
-                        write_mask[:, None, None, None], pages, pl[idx]
-                    )
-                )
-
-            lead = pool.ndim - 4
-            p2 = pool.reshape((-1,) + pool.shape[lead:])
-            r2 = row.reshape((-1,) + row.shape[row.ndim - 4:])
-            return jax.vmap(one)(p2, r2).reshape(pool.shape)
+            n_pages, tail = pool.shape[-4], pool.shape[-3:]
+            rows = row.reshape((-1,) + row.shape[-3:])  # (layers, n_ctx, H, D)
+            pages = jnp.roll(rows, -pad, axis=1).reshape(
+                (-1,) + tail
+            ).astype(pool.dtype)  # (layers * pages_per_slot, ps, H, D)
+            first_page = jnp.arange(rows.shape[0]) * n_pages
+            at = (first_page[:, None] + idx[None, :]).reshape(-1)
+            return pool.reshape((-1,) + tail).at[at].set(pages).reshape(
+                pool.shape
+            )
 
         return jax.tree_util.tree_map(put, cache, row_cache)
 

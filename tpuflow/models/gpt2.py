@@ -245,7 +245,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool, decode: bool = False, pad_lens=None,
-                 prefill: bool = False, slot_index=None, page_table=None):
+                 prefill: bool = False, slot_index=None, page_table=None,
+                 layer=None):
         cfg = self.config
         B, T, C = x.shape
         head_dim = cfg.n_embd // cfg.n_head
@@ -270,7 +271,7 @@ class Block(nn.Module):
         v = v.reshape(B, T, cfg.n_head, head_dim)
         if decode:
             a = self._cached_attention(
-                q, k, v, pad_lens, prec, slot_index, page_table
+                q, k, v, pad_lens, prec, slot_index, page_table, layer
             )
         elif pad_lens is not None:
             # Ragged (LEFT-padded) batch without a cache — the scoring path:
@@ -310,30 +311,43 @@ class Block(nn.Module):
         return x + h
 
     def _paged_attention(self, q, k, v, pad_lens, precision, slot_index,
-                         page_table):
+                         page_table, layer=None):
         """Paged (block-pooled) KV-cache attention — the serving engine's
         slot mode over a page pool (ISSUE 11).
 
-        The cache is ONE (kv_pages, kv_page_size, H, D) pool shared by
-        every slot; ``page_table`` (B, n_ctx/page_size) int32 maps each
-        row's logical cache columns onto pool pages, and is threaded
-        through the decode program as DATA — admissions, evictions and
-        prefix-page sharing never change a shape, so the engine's
-        never-recompile contract extends to page management.
+        The cache is ONE (kv_pages, kv_page_size, H, D) pool per layer,
+        shared by every slot; ``page_table`` (B, n_ctx/page_size) int32
+        maps each row's logical cache columns onto pool pages, and is
+        threaded through the decode program as DATA — admissions,
+        evictions and prefix-page sharing never change a shape, so the
+        engine's never-recompile contract extends to page management.
+
+        Threading: the pool is one buffer that is only ever indexed into,
+        never sliced, restacked or copied. Without a layer scan each
+        block owns its (kv_pages, page_size, H, D) leaf. Under
+        ``scan_layers`` the leaf is the whole (n_layer, kv_pages,
+        page_size, H, D) stack, CARRIED through the layer loop
+        (``GPT2.__call__``: ``variable_carry``), and ``layer`` — this
+        iteration's index, the loop's scanned input — offsets every
+        index into the stack flattened over (layer, page): a step
+        writes B*T rows of (H, D) per layer for K and for V (16 x 4 KB
+        at the serving cell's size), whatever the pool holds.
 
         Writes: row b's T new k/v land at logical columns
         ``slot_index[b] + t``, each routed to
-        ``table[b, col // ps] * ps + col % ps`` of the flattened pool.
+        ``(layer * kv_pages + table[b, col // ps]) * ps + col % ps`` of
+        the flattened pool, in one scatter.
         Out-of-range columns (>= n_ctx: a dying row's overshoot) and
-        dead slots (tables zeroed by the engine) route to page 0 — the
-        reserved TRASH page nothing ever reads — so a page freed and
-        re-allocated to a new request can never be corrupted by its old
-        slot's frozen garbage write (the paged analogue of the slot
-        engine's overwritten-at-own-column argument).
+        dead slots (tables zeroed by the engine) route to the layer's
+        page 0 — the reserved TRASH page nothing ever reads — so a page
+        freed and re-allocated to a new request can never be corrupted
+        by its old slot's frozen garbage write (the paged analogue of
+        the slot engine's overwritten-at-own-column argument).
 
         Reads: each row gathers its logical (n_ctx, H, D) view through
-        its table and runs the SAME masked attention as the contiguous
-        slot path — columns ``[pad_lens[b], slot_index[b] + t]`` only.
+        its table (pages ``layer * kv_pages + table[b]``, one gather)
+        and runs the SAME masked attention as the contiguous slot path
+        — columns ``[pad_lens[b], slot_index[b] + t]`` only.
         Masked columns may be backed by the trash page or a stale page:
         their scores are the -1e30 constant either way, so the gathered
         garbage never reaches a real query (and the gathered bytes equal
@@ -346,37 +360,47 @@ class Block(nn.Module):
         n_pages = cfg.kv_pages
         pages_per_row = cfg.n_ctx // ps
         cdt = cfg.kv_cache_dtype()
+        stack = () if layer is None else (cfg.n_layer,)
+        first_page = 0 if layer is None else layer * n_pages
         ck = self.variable(
-            "cache", "cached_key", jnp.zeros, (n_pages, ps, H, D), cdt
+            "cache", "cached_key", jnp.zeros, stack + (n_pages, ps, H, D),
+            cdt,
         )
         cv = self.variable(
-            "cache", "cached_value", jnp.zeros, (n_pages, ps, H, D), cdt
+            "cache", "cached_value", jnp.zeros, stack + (n_pages, ps, H, D),
+            cdt,
         )
         # Created (never read/advanced) so the paged cache pytree keeps
         # the structure of a row cache — the engine's page-insert
         # tree_maps the two together.
         self.variable(
-            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
+            "cache", "cache_index", lambda: jnp.zeros(stack, jnp.int32)
         )
         pos = slot_index[:, None] + jnp.arange(T)[None, :]  # (B, T) logical
         page = jnp.take_along_axis(
             page_table, jnp.clip(pos // ps, 0, pages_per_row - 1), axis=1
         )
-        flat = jnp.where(pos < cfg.n_ctx, page * ps + pos % ps, 0)
+        flat = first_page * ps + jnp.where(
+            pos < cfg.n_ctx, page * ps + pos % ps, 0
+        )
 
         def scatter(pool, new):
-            body = pool.reshape(n_pages * ps, H, D)
+            body = pool.reshape(-1, H, D)
             body = body.at[flat.reshape(-1)].set(
                 new.astype(cdt).reshape(B * T, H, D)
             )
-            return body.reshape(n_pages, ps, H, D)
+            return body.reshape(pool.shape)
+
+        def gather(pool):
+            pages = pool.reshape(-1, ps, H, D)[first_page + page_table]
+            return pages.reshape(B, cfg.n_ctx, H, D)
 
         with jax.named_scope("kv_write"):
             ck.value = scatter(ck.value, k)
             cv.value = scatter(cv.value, v)
         with jax.named_scope("kv_read"):
-            k_all = ck.value[page_table].reshape(B, cfg.n_ctx, H, D)
-            v_all = cv.value[page_table].reshape(B, cfg.n_ctx, H, D)
+            k_all = gather(ck.value)
+            v_all = gather(cv.value)
         k_pos = jnp.arange(cfg.n_ctx)
         valid = k_pos[None, None, None, :] <= pos[:, None, :, None]
         if pad_lens is not None:
@@ -386,7 +410,7 @@ class Block(nn.Module):
         return _masked_attention(q, k_all, v_all, valid, precision=precision)
 
     def _cached_attention(self, q, k, v, pad_lens=None, precision=None,
-                          slot_index=None, page_table=None):
+                          slot_index=None, page_table=None, layer=None):
         """Fixed-size KV-cache attention (decode mode).
 
         Writes the new k/v at ``cache_index`` and attends q over the whole
@@ -426,7 +450,7 @@ class Block(nn.Module):
                     "engine clones its decode model with them)"
                 )
             return self._paged_attention(
-                q, k, v, pad_lens, precision, slot_index, page_table
+                q, k, v, pad_lens, precision, slot_index, page_table, layer
             )
         cdt = cfg.kv_cache_dtype()
         ck = self.variable(
@@ -519,16 +543,20 @@ class Block(nn.Module):
 
 
 class _ScanBlock(nn.Module):
-    """Scan-body adapter: (carry, broadcast train/decode) → (carry, no ys)."""
+    """Scan-body adapter: (carry, broadcast train/decode) → (carry, no ys).
+    ``layer`` is the one scanned input: the iteration's index, given only
+    where the paged pool is carried through the loop."""
 
     config: GPT2Config
 
     @nn.compact
     def __call__(self, x, train: bool, decode: bool = False, pad_lens=None,
-                 prefill: bool = False, slot_index=None, page_table=None):
+                 prefill: bool = False, slot_index=None, page_table=None,
+                 layer=None):
         return (
             Block(self.config, name="block")(
-                x, train, decode, pad_lens, prefill, slot_index, page_table
+                x, train, decode, pad_lens, prefill, slot_index, page_table,
+                layer,
             ),
             None,
         )
@@ -657,9 +685,9 @@ class GPT2(nn.Module):
                         "names are the jax.checkpoint_policies attributes"
                     ) from None
             # Args (with the module at 0): x=1, train=2, decode=3,
-            # pad_lens=4, prefill=5, slot_index=6, page_table=7.
+            # pad_lens=4, prefill=5, slot_index=6, page_table=7, layer=8.
             # train/decode/prefill are Python bools that steer tracing —
-            # static. pad_lens, slot_index, and page_table are DATA
+            # static. pad_lens, slot_index, page_table and layer are DATA
             # arrays (tracers during ragged/slot/paged decode): marking
             # pad_lens static, as (2, 3, 4) once did, crashed every
             # remat=True decode-mode call with TracerBoolConversionError.
@@ -667,18 +695,38 @@ class GPT2(nn.Module):
 
         if cfg.scan_layers:
             body = remat_wrap(_ScanBlock) if cfg.remat else _ScanBlock
+            call = (x, train, decode, pad_lens, prefill, slot_index,
+                    page_table)
+            # The paged pool rides the layer loop as its CARRY, whole (a
+            # (n_layer, kv_pages, page_size, H, D) leaf for K and for V),
+            # and each iteration indexes into it with its own number, the
+            # loop's one scanned input (Block._paged_attention). Scanned
+            # in and out by layer instead, every iteration would slice
+            # its pool out of the stack and write it back into another
+            # stack. Row caches are scanned by layer, and so is a paged
+            # cache in the one call that creates it: each layer makes
+            # its leaf and the scan stacks them, as it does params.
+            carried = page_table is not None and self.has_variable(
+                "cache", "h"
+            )
+            # 'losses' must be declared or nn.scan silently DROPS the
+            # per-layer sown values (the MoE load-balance aux loss).
+            variable_axes = {"params": 0, "losses": 0}
+            in_axes = nn.broadcast
+            if carried:
+                in_axes = (nn.broadcast,) * (len(call) - 1) + (0,)
+                call += (jnp.arange(cfg.n_layer),)
+            else:
+                variable_axes["cache"] = 0
             blocks = nn.scan(
                 body,
-                # 'losses' must be declared or nn.scan silently DROPS the
-                # per-layer sown values (the MoE load-balance aux loss).
-                variable_axes={"params": 0, "losses": 0, "cache": 0},
+                variable_axes=variable_axes,
+                variable_carry="cache" if carried else False,
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.n_layer,
-                in_axes=nn.broadcast,
+                in_axes=in_axes,
             )
-            x, _ = blocks(cfg, name="h")(
-                x, train, decode, pad_lens, prefill, slot_index, page_table
-            )
+            x, _ = blocks(cfg, name="h")(*call)
         else:
             block_cls = remat_wrap(Block) if cfg.remat else Block
             for i in range(cfg.n_layer):
