@@ -1,8 +1,11 @@
 """BENCHMARK.json and the files it names.
 
-The harness holds no cell's, configuration's or metric's name in code: a
-cell names its configuration and traffic, and each is a file found by that
-name; a per-layer metric is a reader file found by the metric's name.
+The harness holds no cell's, configuration's, metric's or model family's
+name in code: a cell names its configuration and traffic, and each is a
+file found by that name; a configuration's file names its family, and
+`benchmark/families/<family>.py` is the only file that knows the
+architecture; a per-layer metric is a reader file found by the metric's
+name.
 """
 
 from __future__ import annotations
@@ -11,6 +14,7 @@ import importlib.util
 import json
 import os
 import re
+import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
@@ -68,6 +72,7 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
         "config_name": cell["config"],
         "traffic_name": cell["traffic"],
         "config": config,
+        "family": load_family(config.get("family"), root),
         "traffic": traffic,
         "limits": load_json("limits", workload, root)["limits"],
         "end_to_end": end_to_end,
@@ -89,6 +94,59 @@ def load_reader(metric: str, root: str = ROOT):
     return mod.read
 
 
+class Family:
+    """`benchmark/families/<name>.py`, found by the name a configuration's
+    file gives and loaded when a loop or a reader first asks it for
+    something (so after the chip is reached). What it has to expose is
+    listed in PERF.md section 3; asked for a function it lacks, it raises
+    `ManifestError` naming the family and the function."""
+
+    def __init__(self, name: str, path: str):
+        self.name, self.path = name, path
+        self._module = None
+
+    def __getattr__(self, attr: str):
+        if attr.startswith("__"):  # copy, pickle and the like look these up
+            raise AttributeError(attr)
+        if self._module is None:
+            spec = importlib.util.spec_from_file_location(
+                "family_" + re.sub(r"[^A-Za-z0-9_]", "_", self.name), self.path
+            )
+            module = importlib.util.module_from_spec(spec)
+            sys.modules[spec.name] = module  # dataclasses look their module up there
+            spec.loader.exec_module(module)
+            self._module = module
+        try:
+            return getattr(self._module, attr)
+        except AttributeError:
+            raise ManifestError(
+                f"family {self.name!r} ({self.path}) has no {attr!r}"
+            ) from None
+
+
+def load_family(name: str, root: str = ROOT) -> Family:
+    """`benchmark/families/<name>.py`, as `load_reader` finds a reader."""
+    if not isinstance(name, str) or not NAME_RE.match(name):
+        raise ManifestError(f"bad family name {name!r}")
+    path = os.path.join(root, "benchmark", "families", name + ".py")
+    if not os.path.isfile(path):
+        raise ManifestError(f"no family {name!r}: {path} is missing")
+    return Family(name, path)
+
+
+def _config_problems(entry: dict, root: str) -> list[str]:
+    """What the family says of a configuration's file."""
+    path = os.path.join(root, entry["file"])
+    if not os.path.isfile(path):
+        return [f"no file {entry['file']}"]
+    with open(path) as f:
+        cfg = json.load(f)
+    try:
+        return list(load_family(cfg.get("family"), root).check_config(cfg))
+    except ManifestError as e:
+        return [str(e)]
+
+
 def validate(man: dict, root: str = ROOT) -> list[str]:
     """Problems with the manifest's names, units and files (empty = none)."""
     bad: list[str] = []
@@ -101,10 +159,9 @@ def validate(man: dict, root: str = ROOT) -> list[str]:
     cells = {w["name"] for w in man["workloads"]}
     for c in man["configs"]:
         name_ok(c["name"], "config")
-        if not os.path.isfile(os.path.join(root, c["file"])):
-            bad.append(f"config {c['name']}: no file {c['file']}")
         for k in c["reduced"]:
             name_ok(k, f"config {c['name']} reduced")
+        bad += [f"config {c['name']}: {p}" for p in _config_problems(c, root)]
     for w in man["workloads"]:
         name_ok(w["name"], "workload")
         name_ok(w["traffic"], "traffic")

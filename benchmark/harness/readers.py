@@ -7,8 +7,6 @@ from __future__ import annotations
 
 import statistics
 
-from benchmark.harness import flops
-
 
 def median_step_ms(run):
     steps = run["host"].get("step_s") or []
@@ -23,14 +21,16 @@ def idle_share(run):
 
 
 def train_mfu(run, steps: float):
-    """6·N·tokens/s of the traced sub-window over the chips' bf16 peak."""
+    """The family's operations per token times tokens/s of the traced
+    sub-window, over the chips' bf16 peak."""
     traced = run["host"].get("traced") or {}
     if not traced.get("s") or not steps:
         return None
-    model = run["cell"]["config"]["model"]
+    cell = run["cell"]
+    per_token = cell["family"].train_flops_per_token(cell["config"]["model"])
     tokens_per_s = steps * run["host"]["tokens_per_step"] / traced["s"]
     peak = run["peaks"]["bf16_flops_per_s"] * run["device"]["count"]
-    return 100.0 * flops.train_flops_per_token(model) * tokens_per_s / peak
+    return 100.0 * per_token * tokens_per_s / peak
 
 
 def program_events(run, kind: str, name: str) -> list[dict]:

@@ -1,14 +1,13 @@
-"""The plain reference: GPT-2 in straightforward `jax.numpy`, float32 at
-`highest` matmul precision, with its loss, gradients and AdamW. No kernels,
-no cache, no batching tricks; it imports nothing of the program.
+"""What every family's plain reference shares: the roundings that turn a
+reference into its control, the key a seed makes, AdamW's arithmetic, and
+the two drivers that run a family's reference at a cell's own size (the
+first training steps in blocks of rows; served tokens, teacher-forced).
 
-Memory: the training reference follows the program's first steps at the
-cell's own batch. It runs row block by row block and layer by layer, adding
-each layer's gradient into one accumulator in place, so that parameters,
-both Adam moments and one gradient tree are all that lives on the chip
-beside one block's activations.
+The architecture itself (the block, its gradient, the weights, the counts)
+lives in `benchmark/families/<family>.py`, found by the name a
+configuration's file gives. Nothing here imports anything of the program.
 
-`quant` turns the same code into the control: every matrix product's
+`quant` turns a reference into the control: every matrix product's
 operands are rounded to float8 on the way in (e4m3, per-tensor scale) and
 their gradients on the way back (e5m2).
 """
@@ -21,8 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-
-from benchmark.harness import weights as W
 
 HIGHEST = lax.Precision.HIGHEST
 F8_MAX = 448.0
@@ -72,118 +69,15 @@ bf16_round.defvjp(lambda a: (_bf16(a), None), lambda _, g: (_bf16(g),))
 QUANT = {None: _identity, "fp8": fp8_round, "bf16": bf16_round}
 
 
-def _ln(x, p, eps):
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(0.7978845608028654 * (x + 0.044715 * x**3)))
-
-
-def layer(x, lp, n_head: int, eps: float, q):
-    """One pre-LN block on (R, T, C)."""
-    r, t, c = x.shape
-    d = c // n_head
-    h = _ln(x, lp["ln_1"], eps)
-    qkv = jnp.matmul(q(h), q(lp["c_attn"]["kernel"]), precision=HIGHEST)
-    qkv = qkv + lp["c_attn"]["bias"]
-    qh, kh, vh = (a.reshape(r, t, n_head, d) for a in jnp.split(qkv, 3, axis=-1))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q(qh), q(kh), precision=HIGHEST)
-    s = s / jnp.sqrt(jnp.float32(d))
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    s = jnp.where(causal[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    a = jnp.einsum("bhqk,bkhd->bqhd", q(p), q(vh), precision=HIGHEST)
-    a = a.reshape(r, t, c)
-    x = x + jnp.matmul(q(a), q(lp["c_proj"]["kernel"]), precision=HIGHEST) + lp["c_proj"]["bias"]
-    h = _ln(x, lp["ln_2"], eps)
-    h = jnp.matmul(q(h), q(lp["mlp_fc"]["kernel"]), precision=HIGHEST) + lp["mlp_fc"]["bias"]
-    h = _gelu(h)
-    h = jnp.matmul(q(h), q(lp["mlp_proj"]["kernel"]), precision=HIGHEST) + lp["mlp_proj"]["bias"]
-    return x + h
-
-
-def _embed(params, tokens):
-    t = tokens.shape[1]
-    return params["wte"][tokens] + params["wpe"][:t][None]
-
-
-def _head_logits(x, ln_f, wte, eps, q):
-    x = _ln(x, ln_f, eps)
-    return jnp.matmul(q(x), q(wte).T, precision=HIGHEST)
-
-
-def forward_logits(params, tokens, m: dict, quant=None):
-    """(R, T) token ids -> (R, T, V) logits."""
-    q = QUANT[quant]
-    eps = m.get("ln_eps", 1e-5)
-
-    def body(x, lp):
-        return layer(x, lp, m["n_head"], eps, q), None
-
-    x, _ = lax.scan(body, _embed(params, tokens), params["h"]["block"])
-    return _head_logits(x, params["ln_f"], params["wte"], eps, q)
-
-
-def _take_layer(tree, l):
-    return jax.tree_util.tree_map(
-        lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False), tree
+def seed_key(seed: int):
+    """A PRNG key from any whole number (seeds pass 2**31)."""
+    seed = int(seed)
+    return jax.random.fold_in(
+        jax.random.PRNGKey(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF
     )
 
 
-def _block_grad(params, g_acc, x_tok, y_tok, scale, *, m, quant):
-    """Loss (times `scale`) of one block of rows, and its gradient added
-    into `g_acc` layer by layer."""
-    q = QUANT[quant]
-    eps = m.get("ln_eps", 1e-5)
-    n_head, n_layer = m["n_head"], m["n_layer"]
-    layers = params["h"]["block"]
-    fn = functools.partial(layer, n_head=n_head, eps=eps, q=q)
-
-    def fwd(x, lp):
-        return fn(x, lp), x
-
-    x_last, xs = lax.scan(fwd, _embed(params, x_tok), layers)
-
-    def head(x, ln_f, wte):
-        logits = _head_logits(x, ln_f, wte, eps, q)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, y_tok[..., None], axis=-1)[..., 0]
-        return jnp.sum(nll) * scale
-
-    loss, (dx, d_lnf, d_wte) = jax.value_and_grad(head, argnums=(0, 1, 2))(
-        x_last, params["ln_f"], params["wte"]
-    )
-
-    def bwd(i, carry):
-        dx, g = carry
-        l = n_layer - 1 - i
-        x_in = lax.dynamic_index_in_dim(xs, l, 0, keepdims=False)
-        _, vjp = jax.vjp(fn, x_in, _take_layer(layers, l))
-        dx_in, d_lp = vjp(dx)
-        g = jax.tree_util.tree_map(
-            lambda acc, d: lax.dynamic_update_index_in_dim(
-                acc, lax.dynamic_index_in_dim(acc, l, 0, keepdims=False) + d, l, 0
-            ),
-            g, d_lp,
-        )
-        return dx_in, g
-
-    dx0, g_layers = lax.fori_loop(0, n_layer, bwd, (dx, g_acc["h"]["block"]))
-    t = x_tok.shape[1]
-    g_wte = (g_acc["wte"] + d_wte).at[x_tok.reshape(-1)].add(
-        dx0.reshape(-1, dx0.shape[-1])
-    )
-    g_wpe = g_acc["wpe"].at[:t].add(jnp.sum(dx0, axis=0))
-    g_lnf = jax.tree_util.tree_map(jnp.add, g_acc["ln_f"], d_lnf)
-    return loss, {
-        "wte": g_wte, "wpe": g_wpe, "h": {"block": g_layers}, "ln_f": g_lnf,
-    }
-
-
-def _adamw(p, mu, nu, g, t, *, lr, wd, b1=0.9, b2=0.999, eps=1e-8):
+def adamw(p, mu, nu, g, t, *, lr, wd, b1=0.9, b2=0.999, eps=1e-8):
     """optax.adamw's arithmetic: Adam's scaled moments, decoupled weight
     decay added to the update, then times -lr."""
     def one(p, mu, nu, g):
@@ -199,31 +93,24 @@ def _adamw(p, mu, nu, g, t, *, lr, wd, b1=0.9, b2=0.999, eps=1e-8):
     return pick(0), pick(1), pick(2)
 
 
-def delta_norms(params, m: dict, seed: int) -> dict[str, float]:
-    """Per leaf, the norm of (params - the seed's initial leaf)."""
-    return W.leaf_norms(params, m, minus_key=W.seed_key(seed))
+def follow_steps(params, opt: dict, batches, block_grad, leaf_norms, *,
+                 rows_per_block: int = 1, fault: str | None = None):
+    """Follow `len(batches)` AdamW steps from `params`, a step's gradient
+    summed over blocks of rows so that one block's activations are all that
+    lives beside parameters, both moments and one gradient tree.
 
-
-def train_reference(m: dict, opt: dict, seed: int, batches, *, quant=None,
-                    rows_per_block: int = 1, fault: str | None = None) -> dict:
-    """Follow `len(batches)` AdamW steps from the seed's weights.
-
-    `batches` is a list of (x, y) int arrays (B, T). Returns each step's
-    loss, the per-leaf norm of the first gradient and the per-leaf norm of
-    the parameters' change after the last step. `fault="half_batch"` leaves
-    out the second half of every batch and takes the mean over the rest.
+    `batches` is a list of (x, y) int arrays (B, T). The family gives
+    `block_grad(params, g_acc, x, y, scale)`, which returns the loss (times
+    `scale`) of one block and its gradient added into `g_acc` (jitted,
+    `g_acc` donated), and `leaf_norms(tree)`. Returns each step's loss and
+    the per-leaf norm of the first gradient, and the parameters after the
+    last step. `fault="half_batch"` leaves out the second half of every
+    batch and takes the mean over the rest.
     """
     lr, wd = float(opt["learning_rate"]), float(opt.get("weight_decay", 0.0))
-    key = W.seed_key(seed)
-    params = jax.jit(lambda k: W.make_params(m, k))(key)
     zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)  # noqa: E731
     mu, nu = zeros(), zeros()
-    block = jax.jit(
-        functools.partial(_block_grad, m=m, quant=quant), donate_argnums=(1,)
-    )
-    update = jax.jit(
-        functools.partial(_adamw, lr=lr, wd=wd), donate_argnums=(0, 1, 2)
-    )
+    update = jax.jit(functools.partial(adamw, lr=lr, wd=wd), donate_argnums=(0, 1, 2))
     losses, grad_norms = [], None
     for step, (x, y) in enumerate(batches, start=1):
         x, y = np.asarray(x), np.asarray(y)
@@ -234,48 +121,45 @@ def train_reference(m: dict, opt: dict, seed: int, batches, *, quant=None,
         g = zeros()
         loss = 0.0
         for r0 in range(0, rows, rows_per_block):
-            part, g = block(
+            part, g = block_grad(
                 params, g, jnp.asarray(x[r0:r0 + rows_per_block]),
                 jnp.asarray(y[r0:r0 + rows_per_block]), scale,
             )
             loss += float(part)
         losses.append(loss)
         if grad_norms is None:
-            grad_norms = W.leaf_norms(g, m)
+            grad_norms = leaf_norms(g)
         params, mu, nu = update(params, mu, nu, g, jnp.float32(step))
-    dparam = delta_norms(params, m, seed)
-    del params, mu, nu
-    return {"losses": losses, "grad_norms": grad_norms, "dparam_norms": dparam}
+    return {"losses": losses, "grad_norms": grad_norms}, params
 
 
-def serve_gaps(m: dict, seed: int, samples, *, quant=None) -> dict:
+def teacher_forced_gaps(params, logits_fn, samples, positions: int, *, quant=None) -> dict:
     """Teacher-forced check of served tokens.
 
-    `samples` is a list of (prompt ids, served ids). For every served token
-    the reference's logits at that position give the gap by which the
-    served token lies below the reference's best. With `quant`, the gap of
-    the token the lower precision puts first is read beside it (the
-    control)."""
-    n_ctx = m["n_ctx"]
-    params = jax.jit(lambda k: W.make_params(m, k))(W.seed_key(seed))
+    `samples` is a list of (prompt ids, served ids); the family gives
+    `logits_fn(params, tokens, quant)`, (1, T) ids -> (1, T, V) logits. For
+    every served token the reference's logits at that position give the gap
+    by which the served token lies below the reference's best. With
+    `quant`, the gap of the token the lower precision puts first is read
+    beside it (the control)."""
 
     @jax.jit
     def gaps(params, tokens):
-        logits = forward_logits(params, tokens[None], m)[0]
+        logits = logits_fn(params, tokens[None], None)[0]
         best = jnp.max(logits, axis=-1)
         nxt = jnp.concatenate([tokens[1:], tokens[:1]])
         served = jnp.take_along_axis(logits, nxt[:, None], axis=-1)[:, 0]
         if quant is None:
             return best - served, best - served
-        low = jnp.argmax(forward_logits(params, tokens[None], m, quant)[0], axis=-1)
+        low = jnp.argmax(logits_fn(params, tokens[None], quant)[0], axis=-1)
         return best - served, best - jnp.take_along_axis(logits, low[:, None], axis=-1)[:, 0]
 
     widest = widest_low = 0.0
     n_tokens = 0
     for prompt, served in samples:
         prompt, served = np.asarray(prompt, np.int32), np.asarray(served, np.int32)
-        seq = np.concatenate([prompt, served])[:n_ctx]
-        padded = np.zeros((n_ctx,), np.int32)
+        seq = np.concatenate([prompt, served])[:positions]
+        padded = np.zeros((positions,), np.int32)
         padded[: seq.size] = seq
         g_served, g_low = gaps(params, jnp.asarray(padded))
         # logits at position i predict token i+1: the served tokens sit at
@@ -285,5 +169,4 @@ def serve_gaps(m: dict, seed: int, samples, *, quant=None) -> dict:
             widest = max(widest, float(jnp.max(g_served[lo:hi])))
             widest_low = max(widest_low, float(jnp.max(g_low[lo:hi])))
             n_tokens += hi - lo
-    del params
     return {"widest_gap": widest, "widest_gap_low": widest_low, "tokens": n_tokens}

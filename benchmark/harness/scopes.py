@@ -35,16 +35,11 @@ MIN_GAP_S = trace_mod.MIN_GAP_S
 PROGRAM_SPAN_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z_][a-z0-9_]*)+$")
 TOKEN_RE = re.compile(r"[A-Za-z_][\w.\-]*")
 
-# The scopes inside a block and after it: what `decode_carry_share.serve`
-# leaves out, and the labels of the tables. Flax names the Dense and
-# LayerNorm modules; the program names the rest (PERF.md section 3).
-BLOCK_SCOPES = (
-    "attn_core", "kv_read", "kv_write", "c_attn", "c_proj", "mlp_fc", "mlp_proj",
-    "ln_1", "ln_2", "ln_f", "lm_head", "sample",
-)
+# The train step's and the engine's own scopes. Those inside a model (a
+# block's parts, flax's modules around them) are the family's:
+# `BLOCK_SCOPES` and `MODULE_SCOPES` of `benchmark/families/<family>.py`.
 STEP_SCOPES = ("loss", "optimizer")
 ENGINE_SCOPES = ("serve.decode", "serve.prefill", "serve.insert", "serve.verify")
-MODULE_SCOPES = ("block", "GPT2")  # flax's own, around everything in the model
 REMAT = "rematted_computation"
 ADMISSION_SPANS = ("serve.admit", "serve.prefill", "serve.insert")
 
@@ -156,17 +151,18 @@ def inherit_program_scopes(paths: dict) -> dict[str, frozenset[str]]:
 
 # ----------------------------------------------------- the device by scope
 def tokens(scope_path: str) -> frozenset[str]:
-    """`jit(step)/transpose(jvp(GPT2))/while/body/checkpoint/h/block/attn_core/mul:`
-    -> {jit, step, transpose, jvp, GPT2, while, ..., attn_core, mul}."""
+    """`jit(step)/transpose(jvp(Model))/while/body/checkpoint/h/block/attn_core/mul:`
+    -> {jit, step, transpose, jvp, Model, while, ..., attn_core, mul}."""
     return frozenset(TOKEN_RE.findall(scope_path))
 
 
-def label(toks: frozenset[str]) -> str:
+def label(toks: frozenset[str], family) -> str:
     """The first of the named scopes, in this order, that an operation
-    lies under: a block's parts, then the step's, then flax's modules,
-    then the engine's programs. Tokens are a set, so nesting is not read;
-    the order puts the scopes that lie inside before those around them."""
-    for group in (BLOCK_SCOPES, STEP_SCOPES, MODULE_SCOPES, ENGINE_SCOPES):
+    lies under: a block's parts (the family's), then the step's, then
+    flax's modules (the family's), then the engine's programs. Tokens are a
+    set, so nesting is not read; the order puts the scopes that lie inside
+    before those around them."""
+    for group in (family.BLOCK_SCOPES, STEP_SCOPES, family.MODULE_SCOPES, ENGINE_SCOPES):
         for scope in group:
             if scope in toks:
                 return scope
@@ -237,7 +233,7 @@ def reduce_planes(planes: list[dict], tokens_of: dict[str, frozenset[str]]) -> d
 
 
 @functools.lru_cache(maxsize=2)
-def _reduce_file(path: str, _mtime: float) -> dict:
+def _reduce_file(path: str, _mtime: float, family) -> dict:
     import jax.profiler as jp
 
     data = jp.ProfileData.from_file(path)
@@ -262,14 +258,14 @@ def _reduce_file(path: str, _mtime: float) -> dict:
             "lines": [{"name": k, "events": v} for k, v in lines.items()],
         })
     red = reduce_planes(planes, operation_scopes(path))
-    _log_tables(red)
+    _log_tables(red, family)
     return red
 
 
-def reduce_file(path: str) -> dict:
+def reduce_file(path: str, family) -> dict:
     """Parsed once per file however many readers ask; the first time, the
-    tables go to the log."""
-    return _reduce_file(path, os.path.getmtime(path))
+    tables go to the log, labelled by the family's scopes."""
+    return _reduce_file(path, os.path.getmtime(path), family)
 
 
 def find_trace(run: dict) -> str | None:
@@ -290,7 +286,7 @@ def device(run: dict) -> dict | None:
     path = find_trace(run)
     if path is None:
         return None
-    red = reduce_file(path)
+    red = reduce_file(path, run["cell"]["family"])
     return red if red["busy_s"] and any(red["by_tokens"]) else None
 
 
@@ -318,24 +314,24 @@ def share_under(run: dict, any_of, none_of=(), all_of=()) -> float | None:
     return None if seconds is None else 100.0 * seconds / red["busy_s"]
 
 
-def by_label(red: dict) -> list[list]:
+def by_label(red: dict, family) -> list[list]:
     out: dict[str, float] = {}
     for toks, t in red["by_tokens"].items():
-        key = label(toks) + (" (recomputed)" if REMAT in toks else "")
+        key = label(toks, family) + (" (recomputed)" if REMAT in toks else "")
         out[key] = out.get(key, 0.0) + t
     return sorted(([k, v] for k, v in out.items()), key=lambda kv: -kv[1])
 
 
-def _log_tables(red: dict) -> None:
+def _log_tables(red: dict, family) -> None:
     from benchmark.harness.runner import log
 
     busy = red["busy_s"]
     if not busy:
         return
-    rows = [f"{k} {100 * v / busy:.2f}%" for k, v in by_label(red)[:10]]
+    rows = [f"{k} {100 * v / busy:.2f}%" for k, v in by_label(red, family)[:10]]
     log(f"device time by scope, of {busy:.4f} s busy: " + "; ".join(rows))
     roots = {r: seconds_under(red, (r,)) or 0.0 for r in ENGINE_SCOPES}
-    named = busy - sum(t for toks, t in red["by_tokens"].items() if label(toks) == "unscoped")
+    named = busy - sum(t for toks, t in red["by_tokens"].items() if label(toks, family) == "unscoped")
     log(f"under a named scope {100 * named / busy:.2f}% of busy; under the engine's programs "
         + ", ".join(f"{k} {100 * v / busy:.2f}%" for k, v in roots.items() if v))
     idle = sorted(red["idle_by_span"].items(), key=lambda kv: -kv[1])
@@ -345,16 +341,6 @@ def _log_tables(red: dict) -> None:
         f"innermost program span over each gap: "
         + "; ".join(f"{k} {v:.4f}" for k, v in idle[:10])
         + (f"; under a span deeper than serve.step {100 * deep / total:.1f}%" if total else ""))
-
-
-def attention_flops(model: dict, batch: int, seq: int, steps: float) -> float:
-    """The least causal attention has to do in `steps` training steps: per
-    layer the score and the value products, 4·B·H·T²·D forward, half of it
-    under the causal mask, three times that with the backward pass. What
-    remat computes again is not counted."""
-    head_dim = model["n_embd"] // model["n_head"]
-    per_layer = 3 * 0.5 * 4.0 * batch * model["n_head"] * seq * seq * head_dim
-    return steps * model["n_layer"] * per_layer
 
 
 # ------------------------------------------------- the program's own events

@@ -53,10 +53,11 @@ def _zipf_counts(n: int, k: int, s: float) -> np.ndarray:
 
 
 def serve_schedule(traffic: dict, seed: int, seconds: float, vocab: int,
-                   n_ctx: int) -> dict:
+                   positions: int) -> dict:
     """An open-loop schedule over the pre-roll and the window.
 
-    Returns `due` (seconds from the schedule's start, ascending), `prompts`
+    `vocab` and `positions` are the family's: the ids a prompt may draw
+    and the longest sequence a request may reach. Returns `due` (seconds from the schedule's start, ascending), `prompts`
     (token ids), `max_new` and `counted` (due inside the window)."""
     rate = float(traffic["rate_per_s"])
     preroll = float(traffic["preroll_s"])
@@ -86,7 +87,7 @@ def serve_schedule(traffic: dict, seed: int, seconds: float, vocab: int,
     for i in range(n):
         user = rng.integers(1, vocab, size=int(user_len[i]), dtype=np.int64)
         prompt = np.concatenate([system[sys_ids[i]], user]).astype(np.int32)
-        new = int(min(out_len[i], n_ctx - prompt.size))
+        new = int(min(out_len[i], positions - prompt.size))
         prompts.append(prompt)
         max_new.append(new)
     counted = (due >= preroll) & (due < span)

@@ -1,5 +1,5 @@
-"""The least causal attention has to do in the traced steps (per layer
-3 x 1/2 x 4·B·H·T²·D; what remat computes again is not counted), over the
+"""The least attention has to do in the traced steps (the family's count;
+what remat computes again is not counted), over the
 device time under `attn_core`, over the chips' bf16 peak."""
 from benchmark.harness import scopes
 
@@ -12,9 +12,10 @@ def read(run):
     seconds = scopes.seconds_under(red, ("attn_core",))
     if not seconds:
         return None
-    tr = run["cell"]["traffic"]
-    flops = scopes.attention_flops(
-        run["cell"]["config"]["model"], int(tr["batch_size"]), int(tr["seq_len"]), steps
+    cell = run["cell"]
+    tr = cell["traffic"]
+    flops = cell["family"].attention_flops(
+        cell["config"]["model"], int(tr["batch_size"]), int(tr["seq_len"]), steps
     )
     peak = run["peaks"]["bf16_flops_per_s"] * run["device"]["count"]
     return 100.0 * flops / seconds / peak

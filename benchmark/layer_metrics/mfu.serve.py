@@ -1,8 +1,8 @@
-"""2·N·(prompt tokens computed + tokens generated) per second of the
-window over the bf16 peak. Prompt tokens that the prefix cache served (its
+"""The family's forward operations per token (2·N) times (prompt tokens
+computed + tokens generated) per second of the window over the bf16 peak. Prompt tokens that the prefix cache served (its
 pages hit over the window, times the page size) are not computed and not
 counted. Decode is bound by memory, so this reads low."""
-from benchmark.harness import flops, readers
+from benchmark.harness import readers
 
 
 def read(run):
@@ -11,6 +11,7 @@ def read(run):
     tokens = max(h.get("prompt_tokens", 0) - cached, 0) + h.get("output_tokens", 0)
     if not tokens or not h.get("window_s"):
         return None
-    model = run["cell"]["config"]["model"]
+    cell = run["cell"]
+    per_token = cell["family"].forward_flops_per_token(cell["config"]["model"])
     peak = run["peaks"]["bf16_flops_per_s"] * run["device"]["count"]
-    return 100.0 * flops.forward_flops_per_token(model) * tokens / h["window_s"] / peak
+    return 100.0 * per_token * tokens / h["window_s"] / peak

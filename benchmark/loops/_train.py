@@ -1,6 +1,7 @@
 """What the two training kinds share: the program's own calls, in the order
 `train/gpt.py:_run_fsdp_generation` makes them, fed by the benchmark's
-weights and corpus, and the first three steps read for `correct`."""
+weights and corpus, and the first three steps read for `correct`. The
+module, the weights and the reference are the cell's family's."""
 
 from __future__ import annotations
 
@@ -9,22 +10,11 @@ import time
 
 import numpy as np
 
-from benchmark.harness import check, reference, traffic
-from benchmark.harness import weights as W
+from benchmark.harness import check, traffic
+from benchmark.harness.reference import seed_key
 from benchmark.harness.runner import log
 
 CHECK_STEPS = 3
-
-
-def model_config(model: dict):
-    import jax.numpy as jnp
-
-    from tpuflow.models.gpt2 import GPT2Config
-
-    fields = dict(model)
-    if "dtype" in fields:
-        fields["dtype"] = jnp.dtype(fields["dtype"])
-    return GPT2Config(**fields)
 
 
 class TrainRig:
@@ -38,31 +28,31 @@ class TrainRig:
         from tpuflow import dist
         from tpuflow.data.datasets import Split
         from tpuflow.data.loader import ShardedLoader, prefetch_to_device
-        from tpuflow.models.gpt2 import GPT2
         from tpuflow.parallel import create_sharded_state
         from tpuflow.train import TrainState, make_optimizer, make_train_step
         from tpuflow.train.step import dispatch_depth
 
         cfg, tr = cell["config"], cell["traffic"]
+        self.family = fam = cell["family"]
         self.m = m = cfg["model"]
         self.opt = cfg["optimizer"]
         self.seed = seed
         self.batch, self.seq = int(tr["batch_size"]), int(tr["seq_len"])
         self.depth = dispatch_depth()
-        model = GPT2(model_config(m))
+        model = fam.module(m)
         tx = make_optimizer(**self.opt)
         self.mesh = dist.make_mesh({"data": 1, "fsdp": len(jax.devices())})
 
         def init_fn(key):
             return TrainState.create(
-                apply_fn=model.apply, params=W.make_params(m, key), tx=tx
+                apply_fn=model.apply, params=fam.make_params(m, key), tx=tx
             )
 
         declared = jax.eval_shape(
             lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32))["params"],
             jax.random.PRNGKey(0),
         )
-        made = jax.eval_shape(lambda k: W.make_params(m, k), jax.random.PRNGKey(0))
+        made = jax.eval_shape(lambda k: fam.make_params(m, k), jax.random.PRNGKey(0))
         if jax.tree_util.tree_map(lambda a: a.shape, declared) != jax.tree_util.tree_map(
             lambda a: a.shape, made
         ):
@@ -70,11 +60,11 @@ class TrainRig:
         t0 = time.monotonic()
         with self.mesh:
             self.state, self.shardings = create_sharded_state(
-                init_fn, self.mesh, W.seed_key(seed), fsdp=True
+                init_fn, self.mesh, seed_key(seed), fsdp=True
             )
             jax.block_until_ready(self.state.params)
         log(f"state on the device in {time.monotonic() - t0:.1f}s")
-        self.corpus = traffic.lm_corpus(seed, int(tr["corpus_rows"]), self.seq, m["vocab_size"])
+        self.corpus = traffic.lm_corpus(seed, int(tr["corpus_rows"]), self.seq, fam.vocabulary(m))
         self.loader = ShardedLoader(
             Split(self.corpus[:, :-1], self.corpus[:, 1:]),
             batch_size=self.batch, shuffle=True, seed=seed,
@@ -127,8 +117,8 @@ class TrainRig:
             losses.append(float(self.dispatch()))
             if step == 1:
                 mu = next(s for s in self.state.opt_state if hasattr(s, "mu")).mu
-                grad_norms = W.leaf_norms(mu, self.m, scale=1.0 / (1.0 - 0.9))
-        dparam = reference.delta_norms(self.state.params, self.m, self.seed)
+                grad_norms = self.family.leaf_norms(mu, self.m, scale=1.0 / (1.0 - 0.9))
+        dparam = self.family.delta_norms(self.state.params, self.m, self.seed)
         return {"losses": losses, "grad_norms": grad_norms, "dparam_norms": dparam}
 
     def batches_for_reference(self) -> list[tuple[np.ndarray, np.ndarray]]:
@@ -154,7 +144,7 @@ def judge_train(rig: TrainRig, prog: dict, cell: dict, extra: dict | None = None
     freed by now) and hold every number to its limit."""
     limits = cell["limits"]
     t0 = time.monotonic()
-    ref = reference.train_reference(
+    ref = rig.family.train_reference(
         rig.m, rig.opt, rig.seed, rig.batches_for_reference(),
         rows_per_block=int(cell["traffic"].get("reference_rows_per_block", 1)),
     )

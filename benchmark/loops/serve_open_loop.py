@@ -10,10 +10,9 @@ import time
 
 import numpy as np
 
-from benchmark.harness import check, reference, traffic, windows
-from benchmark.harness import weights as W
+from benchmark.harness import check, traffic, windows
+from benchmark.harness.reference import seed_key
 from benchmark.harness.runner import device_line, log
-from benchmark.loops._train import model_config
 
 WORST_S = 1e6  # a request that never finished counts as the worst latency
 WATCH_INTERVAL_S = 0.005  # how often the watching thread looks at the handles
@@ -23,16 +22,15 @@ def run(cell: dict, *, seed: int, seconds: float, tracer, t_start: float) -> dic
     import jax
 
     from tpuflow.infer.serve import ServeEngine
-    from tpuflow.models.gpt2 import GPT2
 
-    cfg, tr = cell["config"], cell["traffic"]
+    cfg, tr, fam = cell["config"], cell["traffic"], cell["family"]
     m = cfg["model"]
-    model = GPT2(model_config(m))
-    params = jax.jit(lambda k: W.make_params(m, k))(W.seed_key(seed))
+    model = fam.module(m)
+    params = jax.jit(lambda k: fam.make_params(m, k))(seed_key(seed))
     engine = ServeEngine(model, params, buckets=list(tr["buckets"]), **cfg["serve"])
     stats = engine.warmup()
     log(f"engine warm: {stats}")
-    sched = traffic.serve_schedule(tr, seed, seconds, m["vocab_size"], m["n_ctx"])
+    sched = traffic.serve_schedule(tr, seed, seconds, fam.vocabulary(m), fam.positions(m))
     due = [float(d) for d in sched["due"]]
     n = len(due)
     preroll, span = sched["preroll_s"], sched["span_s"]
@@ -166,7 +164,7 @@ def run(cell: dict, *, seed: int, seconds: float, tracer, t_start: float) -> dic
         f"the prefix cache {hits} of {lookups}")
     del engine, params, handles
     t0 = clock()
-    gaps = reference.serve_gaps(m, seed, samples)
+    gaps = fam.serve_gaps(m, seed, samples)
     log(f"reference over {len(samples)} requests, {gaps['tokens']} served tokens, "
         f"in {clock() - t0:.1f}s")
     numbers = {

@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from benchmark.harness import flops, manifest, peaks
+from benchmark.harness import manifest, peaks
 
 
 def test_manifest_is_valid_and_files_exist():
@@ -64,10 +64,27 @@ def test_config_files_hold_what_is_run_and_name_every_changed_key():
             cfg = json.load(f)
         assert cfg["source"] == c["source"]
         assert cfg["reduced"] == c["reduced"]
-        for key in ("n_embd", "n_layer", "n_head", "vocab_size"):
-            assert cfg["model"][key] == cfg[key]  # widths and depth as published
-        assert cfg["model"]["n_ctx"] == cfg["n_positions"]
-        assert flops.n_params(cfg["model"]) == cfg["parameters"]
+        # widths, depth and the parameter count: the family's own check
+        assert manifest.load_family(cfg["family"]).check_config(cfg) == []
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in manifest.load_manifest()["configs"]])
+def test_a_configuration_made_wrong_is_reported_by_its_family(config, tmp_path):
+    man = manifest.load_manifest()
+    entry = next(c for c in man["configs"] if c["name"] == config)
+    with open(os.path.join(manifest.ROOT, entry["file"])) as f:
+        cfg = json.load(f)
+    fam = manifest.load_family(cfg["family"])
+    assert fam.n_params(cfg["model"]) == cfg["parameters"]
+    assert fam.check_config({**cfg, "parameters": cfg["parameters"] + 1})
+    width = next(k for k, v in cfg["model"].items() if isinstance(v, int) and cfg.get(k) == v)
+    assert fam.check_config({**cfg, "model": {**cfg["model"], width: cfg[width] * 2}})
+    # and `validate` says which configuration: a file that names no family
+    os.makedirs(tmp_path / os.path.dirname(entry["file"]))
+    with open(tmp_path / entry["file"], "w") as f:
+        json.dump({k: v for k, v in cfg.items() if k != "family"}, f)
+    problems = manifest._config_problems(entry, str(tmp_path))
+    assert problems == ["bad family name None"]
 
 
 def test_missing_device_kind_raises():
@@ -77,6 +94,7 @@ def test_missing_device_kind_raises():
 
 
 def test_flops_and_bytes_from_shapes():
+    flops = manifest.load_family("gpt2")
     m = {"vocab_size": 50257, "n_ctx": 1024, "n_embd": 1024, "n_layer": 24, "n_head": 16}
     n = flops.n_params(m)
     assert n == 354_823_168
@@ -87,9 +105,10 @@ def test_flops_and_bytes_from_shapes():
 
 
 def test_mfu_serve_leaves_out_prompt_tokens_that_the_prefix_cache_served():
+    flops = manifest.load_family("gpt2")
     m = {"vocab_size": 50257, "n_ctx": 1024, "n_embd": 1024, "n_layer": 24, "n_head": 16}
     run = {
-        "cell": {"config": {"model": m}}, "device": {"count": 1},
+        "cell": {"config": {"model": m}, "family": flops}, "device": {"count": 1},
         "peaks": {"bf16_flops_per_s": 197e12},
         "host": {"prompt_tokens": 1000, "output_tokens": 200, "window_s": 2.0, "page_size": 16,
                  "counters": {"open": {"prefix_hits": 10}, "close": {"prefix_hits": 40}}},

@@ -13,6 +13,7 @@ import pytest
 from benchmark.harness import manifest, scopes
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
+GPT2 = manifest.load_family("gpt2")  # the fixture and the made-up paths are this family's
 NEW_METRICS = (
     "attn_core_share.train", "attn_core_roofline.train", "remat_share.train",
     "lm_head_loss_share.train", "decode_carry_share.serve", "kv_read_share.serve",
@@ -24,18 +25,22 @@ def toks(path):
     return scopes.tokens(path)
 
 
+def label(tokens):
+    return scopes.label(tokens, GPT2)
+
+
 # ------------------------------------------------------------- plain data
 def test_tokens_and_labels():
     t = toks("jit(train_step)/transpose(jvp(GPT2))/while/body/closed_call/checkpoint/"
              "rematted_computation/h/block/attn_core/bhqk,bkhd->bqhd/dot_general:")
     assert {"jit", "train_step", "transpose", "jvp", "GPT2", "attn_core",
             "rematted_computation", "dot_general"} <= t
-    assert scopes.label(t) == "attn_core"
-    assert scopes.label(toks("jit(<unknown>)/serve.decode/while/body/GPT2/h/block/add")) == "block"
-    assert scopes.label(toks("jit(<unknown>)/serve.decode/while")) == "serve.decode"
-    assert scopes.label(toks("jit(train_step)/jvp(loss)/reduce_max")) == "loss"
-    assert scopes.label(toks("jit(train_step)/reduce_sum")) == "unscoped"
-    assert scopes.label(frozenset()) == "unscoped"
+    assert label(t) == "attn_core"
+    assert label(toks("jit(<unknown>)/serve.decode/while/body/GPT2/h/block/add")) == "block"
+    assert label(toks("jit(<unknown>)/serve.decode/while")) == "serve.decode"
+    assert label(toks("jit(train_step)/jvp(loss)/reduce_max")) == "loss"
+    assert label(toks("jit(train_step)/reduce_sum")) == "unscoped"
+    assert label(frozenset()) == "unscoped"
 
 
 def test_pathless_operations_take_what_their_program_shares():
@@ -50,7 +55,7 @@ def test_pathless_operations_take_what_their_program_shares():
     }
     out = scopes.inherit_program_scopes(paths)
     assert out["1|copy"] == {"jit", "unknown", "serve.decode", "GPT2"}
-    assert scopes.label(out["1|copy"]) == "GPT2"
+    assert label(out["1|copy"]) == "GPT2"
     # not `block`, which its argument's name holds: the roots, and a mark
     assert out["1|pool"] == out["1|copy"] | {"argument"}
     assert "c_attn" in out["2|copy"] and "serve.decode" not in out["2|copy"]
@@ -111,9 +116,9 @@ def test_self_time_under_nested_whiles_and_shares():
     assert under(("serve.decode",)) == pytest.approx(10.0 + 1.0)
     # what the decode program spends under none of the block's scopes: the
     # carried copies, the pool's slices, the whiles' own time (none here)
-    assert under(("serve.decode",), none_of=scopes.BLOCK_SCOPES) == pytest.approx(1.0 + 3.0 + 1.0)
+    assert under(("serve.decode",), none_of=GPT2.BLOCK_SCOPES) == pytest.approx(1.0 + 3.0 + 1.0)
     assert under(("serve.verify",)) is None  # no such scope in this program
-    labels = dict((k, v) for k, v in scopes.by_label(red))
+    labels = dict((k, v) for k, v in scopes.by_label(red, GPT2))
     assert labels["serve.decode"] == pytest.approx(2.0)
     assert labels["GPT2"] == pytest.approx(3.0)
     assert "unscoped" not in labels
@@ -153,7 +158,7 @@ def test_span_self_time_and_the_wait_under_other_requests():
 def test_attention_flops():
     m = {"n_embd": 1280, "n_head": 20, "n_layer": 36}
     per_layer = 3 * 0.5 * 4 * 8 * 20 * 1024 * 1024 * 64
-    assert scopes.attention_flops(m, 8, 1024, 12) == 12 * 36 * per_layer
+    assert GPT2.attention_flops(m, 8, 1024, 12) == 12 * 36 * per_layer
 
 
 # -------------------------------------------------- the recorded fixture
@@ -164,7 +169,7 @@ def recorded(tmp_path_factory):
         with open(path, "wb") as dst:
             dst.write(src.read())
     with open(os.path.join(DATA, "scopes.events.json")) as f:
-        return scopes.reduce_file(path), json.load(f)
+        return scopes.reduce_file(path, GPT2), json.load(f)
 
 
 def test_recorded_trace_device_time_by_scope(recorded):
@@ -190,7 +195,7 @@ def test_recorded_trace_device_time_by_scope(recorded):
     assert under(("kv_read",), all_of=("serve.decode",)) > 0
     assert under(("kv_write",), all_of=("serve.decode",)) > 0
     assert under(("sample",)) > 0  # the prefill's; in decode the argmax fuses into the head
-    carry = under(("serve.decode",), none_of=scopes.BLOCK_SCOPES)
+    carry = under(("serve.decode",), none_of=GPT2.BLOCK_SCOPES)
     assert 0.1 * decode < carry < decode
     pathless = [k for k in red["by_tokens"] if "serve.decode" in k
                 and k <= {"jit", "unknown", "serve.decode", "while", "argument"}]
@@ -234,7 +239,8 @@ def test_recorded_events_request_intervals(recorded):
 def test_a_new_reader_finds_nothing_without_a_trace(metric):
     read = manifest.load_reader(metric)
     untraced = {"traced": None, "host": {"window_s": 51.0}, "attempted": 10,
-                "cell": {"name": "no-such-cell", "traffic": {}, "config": {"model": {}}}}
+                "cell": {"name": "no-such-cell", "traffic": {}, "config": {"model": {}},
+                         "family": GPT2}}
     assert read(untraced) is None
     # a traced run of a program from before the spans: events without ids
     # or the monotonic clock, and no trace file to be found

@@ -26,10 +26,11 @@ sys.path.insert(0, ROOT)
 def train_readings(cell: dict, seed: int) -> dict:
     import numpy as np
 
-    from benchmark.harness import check, reference, traffic
+    from benchmark.harness import check, traffic
 
+    fam = cell["family"]
     m, opt, tr = cell["config"]["model"], cell["config"]["optimizer"], cell["traffic"]
-    corpus = traffic.lm_corpus(seed, int(tr["corpus_rows"]), int(tr["seq_len"]), m["vocab_size"])
+    corpus = traffic.lm_corpus(seed, int(tr["corpus_rows"]), int(tr["seq_len"]), fam.vocabulary(m))
     order = np.random.default_rng((seed, 0)).permutation(len(corpus))
     b = int(tr["batch_size"])
     batches = [
@@ -39,30 +40,31 @@ def train_readings(cell: dict, seed: int) -> dict:
     rows = int(tr.get("reference_rows_per_block", 1))
     out = {"seed": seed}
     t0 = time.monotonic()
-    ref = reference.train_reference(m, opt, seed, batches, rows_per_block=rows)
+    ref = fam.train_reference(m, opt, seed, batches, rows_per_block=rows)
     out["reference_s"] = time.monotonic() - t0
     for name, kw in (("control_fp8", {"quant": "fp8"}), ("fault_half_batch", {"fault": "half_batch"})):
-        other = reference.train_reference(m, opt, seed, batches, rows_per_block=rows, **kw)
+        other = fam.train_reference(m, opt, seed, batches, rows_per_block=rows, **kw)
         out[name] = check.compare_train(other, ref)
     return out
 
 
 def serve_readings(cell: dict, seed: int, seconds: float) -> dict:
-    from benchmark.harness import reference, runner
+    from benchmark.harness import runner
 
     got = {}
-    plain = reference.serve_gaps
+    fam = cell["family"]
+    plain = fam.serve_gaps
 
     def both(m, seed, samples, **_):
         got.update(plain(m, seed, samples, quant="fp8"))
         return got
 
-    reference.serve_gaps = both
+    fam.serve_gaps = both  # on the cell's own family object, for this run
     try:
         res = runner.run_cell(cell, seed=seed, seconds=seconds, trace=False,
                               t_start=time.monotonic(), rehearse=True)
     finally:
-        reference.serve_gaps = plain
+        del fam.serve_gaps
     return {"seed": seed, "program_widest_gap": got["widest_gap"],
             "control_fp8_widest_gap": got["widest_gap_low"], "tokens": got["tokens"],
             "failed": res["failed"], "attempted": res["attempted"]}

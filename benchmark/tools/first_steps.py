@@ -30,28 +30,28 @@ def leaf_gaps(prog: dict, ref: dict) -> dict:
 
 
 def readings(cell: dict, seed: int, witnesses: list[str]) -> dict:
-    from benchmark.harness import check, reference
+    from benchmark.harness import check
     from benchmark.loops._train import TrainRig
 
     rig = TrainRig(cell, seed)
     prog = rig.first_steps()
     batches = rig.batches_for_reference()
     rig.free()
-    m, opt = rig.m, rig.opt
+    m, opt, fam = rig.m, rig.opt, rig.family
     del rig
     rows = int(cell["traffic"].get("reference_rows_per_block", 1))
     t0 = time.monotonic()
-    ref = reference.train_reference(m, opt, seed, batches, rows_per_block=rows)
+    ref = fam.train_reference(m, opt, seed, batches, rows_per_block=rows)
     out = {"seed": seed, "reference_s": time.monotonic() - t0,
            "reference_losses": ref["losses"],
            "reference_grad_norms": ref["grad_norms"],
            "reference_dparam_norms": ref["dparam_norms"]}
     runs = {"program": prog}
     if "bf16" in witnesses:
-        runs["bf16"] = reference.train_reference(
+        runs["bf16"] = fam.train_reference(
             m, opt, seed, batches, rows_per_block=rows, quant="bf16")
     if "reorder" in witnesses:
-        runs["reorder"] = reference.train_reference(
+        runs["reorder"] = fam.train_reference(
             m, opt, seed, batches, rows_per_block=2 * rows)
     for name, run in runs.items():
         out[name] = check.compare_train(run, ref)

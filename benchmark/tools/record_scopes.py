@@ -1,9 +1,10 @@
 """Record the small scoped trace the tests reduce
 (`benchmark/tests/data/scopes.xplane.pb.gz`, with the program's events of
 the same run beside it): two train steps and a few engine steps of a
-two-layer GPT-2 with the program's named scopes and its spans on the host
-plane; and print where this JAX keeps a device operation's scope path. Run
-on the chip: `chiprun -- python benchmark/tools/record_scopes.py`.
+two-layer model (the `test_config()` of the family named below) with the
+program's named scopes and its spans on the host plane; and print where
+this JAX keeps a device operation's scope path. Run on the chip:
+`chiprun -- python benchmark/tools/record_scopes.py`.
 
 The profiler's file is 1.8 MB at this size, two thirds of it the programs'
 HLO (`/host:metadata`) and most of the rest stack traces in the
@@ -22,9 +23,7 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-MODEL = {"vocab_size": 512, "n_ctx": 128, "n_embd": 64, "n_layer": 2, "n_head": 4,
-         "dropout": 0.0, "attn_impl": "auto", "dtype": "bfloat16", "remat": True,
-         "scan_layers": True}
+FAMILY = "gpt2"  # the fixture the tests reduce is this family's
 
 
 def _varint(x: int) -> bytes:
@@ -117,10 +116,9 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.loops._train import model_config
+    from benchmark.harness import manifest
     from tpuflow import obs
     from tpuflow.infer.serve import ServeEngine
-    from tpuflow.models.gpt2 import GPT2
     from tpuflow.train import TrainState, make_optimizer, make_train_step
 
     out = os.path.join(ROOT, "chiprun_out", "scopes_trace")
@@ -132,7 +130,8 @@ def main() -> None:
     # (the first fixture loaded an older step and lacked a scope).
     jax.config.update("jax_enable_compilation_cache", False)
     obs.configure(obs_dir)
-    model = GPT2(model_config(MODEL))
+    family = manifest.load_family(FAMILY, ROOT)
+    model = family.module(family.test_config()["model"])
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
     state = TrainState.create(
         apply_fn=model.apply, params=params, tx=make_optimizer(learning_rate=3e-4)
@@ -181,7 +180,7 @@ def main() -> None:
             break
     from benchmark.harness import scopes
 
-    whole, small = scopes.reduce_file(path), scopes.reduce_file(kept)
+    whole, small = scopes.reduce_file(path, family), scopes.reduce_file(kept, family)
     assert whole["by_tokens"] == small["by_tokens"], "stripping changed the reduction"
     assert whole["idle_by_span"] == small["idle_by_span"]
     scoped = {k: sorted(v) for k, v in scopes.operation_scopes(kept).items() if "attn_core" in v}

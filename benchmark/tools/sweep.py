@@ -7,7 +7,6 @@ generator ran.
 """
 
 import argparse
-import copy
 import json
 import os
 import subprocess
@@ -18,14 +17,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 
-def one(workload: str, rate: float, seconds: float, seed: int) -> dict:
-    from benchmark.harness import manifest, runner, windows
+def one(cell: dict, rate: float, seconds: float, seed: int) -> dict:
+    """The cell's mix at another rate, once."""
+    from benchmark.harness import runner, windows
     from benchmark.loops import serve_open_loop
-    from tpuflow import dist
 
-    dist.maybe_enable_compile_cache()
-    cell = copy.deepcopy(manifest.load_cell(workload, ROOT))
-    cell["traffic"]["rate_per_s"] = rate
+    cell = {**cell, "traffic": {**cell["traffic"], "rate_per_s": rate}}
     run = serve_open_loop.run(
         cell, seed=seed, seconds=seconds, tracer=runner.Tracer(False, "sweep"),
         t_start=time.monotonic(),
@@ -54,7 +51,12 @@ def main() -> None:
     ap.add_argument("--child", action="store_true")
     args = ap.parse_args()
     if args.child:
-        print("SWEEP " + json.dumps(one(args.workload, args.rates[0], args.seconds, args.seed)),
+        from benchmark.harness import manifest
+        from tpuflow import dist
+
+        dist.maybe_enable_compile_cache()
+        cell = manifest.load_cell(args.workload, ROOT)
+        print("SWEEP " + json.dumps(one(cell, args.rates[0], args.seconds, args.seed)),
               flush=True)
         return
     out = os.path.join(ROOT, "chiprun_out", f"sweep-{args.workload}.jsonl")
