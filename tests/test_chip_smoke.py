@@ -104,3 +104,40 @@ def test_cpu_rehearsal_end_to_end(tmp_path):
     assert report["resume"]["checkpoint_steps"] == [9, 12]
     assert report["serve"]["answered"] == report["serve"]["requests"] == 6
     assert report["kernels"]["interpret"] is True
+
+
+def test_kernel_stage_checks_the_shape_auto_trains_with():
+    """The kernels stage compiles the attention kernels at the row the
+    train stage runs (``--attn-impl auto``, 1,024 positions, heads of
+    64), where ``auto`` takes them on a TPU since ISSUE 31; the stage
+    fails a chip run in which ``auto`` chose otherwise."""
+    from tpuflow.ops import flash_attention as fa
+    from tpuflow.ops.attention import resolve_attention_impl
+
+    f = chip_smoke.CHIP["flash"]
+    assert f["T"] == chip_smoke.CHIP["seq_len"] == 1024 and f["D"] == 64
+    assert resolve_attention_impl(
+        "auto", f["T"], needs_bwd=True, backend="tpu") == "flash"
+    assert fa.flash_tiles(f["T"], f["T"], f["H"], f["D"])
+    assert fa._block_sizes(f["T"], f["T"]) == (1024, 1024, 256, 256)
+    assert fa._head_group(f["H"], f["D"]) == 2
+
+
+def test_kernel_stage_rehearsal(tmp_path):
+    """The kernels child alone, as the rehearsal runs it: the forward,
+    the fused backward and the split reference pair against XLA in the
+    interpreter, and what ``auto`` picks off the chip."""
+    ctx = {"rehearse": True, "size": chip_smoke.REHEARSAL}
+    p = subprocess.run(
+        [sys.executable, SCRIPT, "--stage", "kernels", "--ctx", json.dumps(ctx)],
+        capture_output=True, text=True, timeout=300, cwd=str(tmp_path),
+    )
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    line = [ln for ln in p.stdout.splitlines()
+            if ln.startswith(chip_smoke.RESULT_TAG)][-1]
+    out = json.loads(line[len(chip_smoke.RESULT_TAG):])
+    flash = out["flash"]
+    assert flash["blocks"] == [64, 64, 64, 64]
+    assert flash["heads_per_program"] == 2
+    assert max(flash["fwd"], flash["bwd_fused"], flash["bwd_split"]) <= 1e-4
+    assert out["auto"]["attention_train_T64"] == "xla"

@@ -46,14 +46,25 @@ def xla_attention(q, k, v, *, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-# Shipped defaults for the auto-dispatch thresholds, from a v5e record of
-# 2026-07-31 (since deleted; not re-measured on the current kernels):
-# fwd+bwd needs T >= 2048 (at T=512 the flash backward LOST to XLA at 0.2x
-# while fwd won 2.73x — the two paths have genuinely different crossovers,
-# so they carry independent thresholds); fwd-only (dense prefill, scoring
-# without grad) wins from T >= 512.
-_DEFAULT_FLASH_MIN_SEQ = 2048
-_DEFAULT_FLASH_MIN_SEQ_FWD = 512
+# Shipped defaults for the auto-dispatch thresholds, from chip calls 93 and
+# 95 of PR 31 (PERF.md §6; TPU v5e, bf16, causal, heads of 64, the kernels
+# against `xla_attention` in one process). The crossover is not a length:
+# XLA's attention is fast while the (B, H, T, T) scores stay on the chip
+# and slow once they go through HBM, and the kernels' time follows B·H·T.
+# Up to 21M score elements XLA is level or ahead (forward, device ms a
+# call, XLA against the kernels: B·H = 20 at T=1024 0.071 against 0.071,
+# B·H = 80 at T=512 0.072 against 0.115, at T=256 0.017 against 0.054;
+# forward + backward at B·H = 80, T=256 0.041 against 0.110); from 42M the
+# kernels are ahead (forward / forward + backward at 8,192 tokens of 20
+# heads: T=256 0.61 / 1.49 against 0.51 / 1.22, T=512 1.09 / 3.44 against
+# 0.55 / 1.30, T=1024 2.05 / 6.64 against 0.63 / 1.59, T=2048 4.09 / 12.6
+# against 1.04 / 2.67; B·H = 48 at T=1024 0.59 against 0.16 forward). A
+# threshold on T cannot say that, so both defaults are the shortest row at
+# which no measured shape lost: 1,024 (level at one row of 12 to 20 heads,
+# 3.2 to 4.2 times ahead from four). They stay two names because the
+# tuning file and the two environment variables set them apart.
+_DEFAULT_FLASH_MIN_SEQ = 1024
+_DEFAULT_FLASH_MIN_SEQ_FWD = 1024
 _flash_tuning_cache: dict | None = None
 _warned_malformed_env = False
 _warned_malformed_tuning = False
@@ -93,11 +104,9 @@ def _flash_min_seq(*, needs_bwd: bool = True) -> int:
     falls through to the tuning-file lookup (the host's measured
     crossover — strictly better information than the shipped constant)
     and warns once per process, through the obs stream when one is live.
-    An unset fwd-only env var falls back to the fwd+bwd env var scaled
-    by nothing — i.e. only its own sources; the two paths never borrow
-    each other's thresholds (that record: at T=512 fwd wins 2.73x while
-    fwd+bwd loses at 0.2x). The file read is cached per process (this
-    runs at trace time).
+    An unset fwd-only env var falls back to only its own sources; the
+    two paths never borrow each other's thresholds. The file read is
+    cached per process (this runs at trace time).
 
     The training path additionally consults the fitted BWD-ONLY
     crossover (ISSUE 10 satellite; bench's T512/T2048 ``jax.vjp`` timing
@@ -197,18 +206,39 @@ def resolve_attention_impl(
     return "xla"
 
 
+def _flash_runs(q_shape, tk: int, causal: bool) -> bool:
+    """Whether the flash kernels can run this call where it is traced:
+    'auto' never picks a kernel that cannot. Two things stop them. The
+    shape (``flash_tiles``): a 600-token prefill does not tile the 256-row
+    score tiles, gpt2-xl's 25 heads of 64 do not pair into 128-lane tiles.
+    And the placement: a Mosaic kernel is opaque to the partitioner, and
+    jax refuses to lower one inside a program partitioned over several
+    devices ("wrap the call in a shard_map"), so under a mesh of more
+    than one device 'auto' stays with XLA, as it did on four chips before
+    ISSUE 31 (PERF.md §7: the four-chip issue's first change)."""
+    from tpuflow.ops.flash_attention import flash_tiles
+    from tpuflow.parallel.sharding import active_mesh
+
+    mesh = active_mesh()
+    if mesh is not None and mesh.size > 1:
+        return False
+    _, tq, h, d = q_shape
+    return flash_tiles(tq, tk, h, d, causal=causal)
+
+
 def attention(q, k, v, *, causal: bool = True, impl: str = "xla",
               needs_bwd: bool = True):
     """Dispatch to the selected implementation (see module docstring).
 
-    ``impl='auto'`` picks by measured crossover: flash only on TPU at
-    T >= the resolved threshold — the fwd+bwd threshold when
-    ``needs_bwd`` (TPUFLOW_FLASH_MIN_SEQ / tuning-file ``flash_min_seq``
-    / 2048: on-chip evidence had fwd+bwd winning at T=2048 by 1.73x and
-    LOSING at T=512 by 0.2x), else the fwd-only threshold
-    (TPUFLOW_FLASH_MIN_SEQ_FWD / ``flash_min_seq_fwd`` / 512, where fwd
-    alone already won 2.73x) — and XLA everywhere else; CPU always takes
-    XLA (flash there is interpret-mode, for tests only).
+    ``impl='auto'`` picks by measured crossover: flash only on TPU, only
+    for a call the kernels can run (``_flash_runs``: a shape they tile,
+    on one device), at T >= the resolved threshold — the
+    fwd+bwd threshold when ``needs_bwd`` (TPUFLOW_FLASH_MIN_SEQ /
+    tuning-file ``flash_min_seq`` / 1024), else the fwd-only threshold
+    (TPUFLOW_FLASH_MIN_SEQ_FWD / ``flash_min_seq_fwd`` / 1024); the
+    defaults are the chip calls of PR 31, above — and XLA everywhere
+    else; CPU always takes XLA (flash there is interpret-mode, for tests
+    only).
     """
     if impl == "auto":
         # NB: resolved at trace time — under jit the choice is baked into
@@ -217,13 +247,8 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "xla",
         impl = resolve_attention_impl(
             "auto", q.shape[1], needs_bwd=needs_bwd
         )
-        if impl == "flash":
-            from tpuflow.ops.flash_attention import flash_tiles
-
-            # 'auto' never picks a kernel that cannot run the shape (a
-            # 600-token prefill does not tile the 256-row blocks).
-            if not flash_tiles(q.shape[1], k.shape[1], q.shape[3]):
-                impl = "xla"
+        if impl == "flash" and not _flash_runs(q.shape, k.shape[1], causal):
+            impl = "xla"
     if impl == "xla":
         fn = xla_attention
     elif impl == "flash":

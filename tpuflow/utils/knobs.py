@@ -332,19 +332,16 @@ REGISTRY: dict[str, Knob] = dict(
            "min seq length where flash attention beats XLA for "
            "forward+backward programs (auto-tuned; malformed → tuning "
            "file with a once-per-process warning)", "ops", _A_STEP,
-           default_doc="2048 / tuned"),
+           default_doc="1024 / tuned"),
         _k("TPUFLOW_FLASH_MIN_SEQ_FWD", "int", None,
            "min seq length where flash attention beats XLA for "
            "forward-only (decode prefill) programs", "ops", _A_STEP,
-           default_doc="512 / tuned"),
+           default_doc="1024 / tuned"),
         _k("TPUFLOW_FLASH_BWD", "enum", "fused",
-           "flash backward implementation (split = the pre-fusion pair, "
-           "regression reference)", "ops", _A_STEP,
+           "flash backward implementation (fused = one kernel for dq, dk "
+           "and dv; split = the two-kernel pair, regression reference)",
+           "ops", _A_STEP,
            choices=("fused", "split", "blockwise")),
-        _k("TPUFLOW_FLASH_LSE", "enum", None,
-           "`compact` restores the small (non-lane-padded) backward "
-           "residual for memory-bound remat-off configs", "ops", _A_STEP,
-           choices=("compact",), default_doc="lane-padded"),
         _k("TPUFLOW_INT8_MATMUL", "enum", "auto",
            "int8 matmul impl: force xla/pallas, or auto-dispatch by "
            "shape", "quant", _A_QUANT,
