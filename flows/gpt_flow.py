@@ -61,8 +61,8 @@ class TpuGptTrain(FlowSpec):
     attn_impl = Parameter(
         "attn_impl",
         default="auto",
-        help="auto|xla|flash|ring|ulysses (auto = flash on TPU at "
-        "T>=TPUFLOW_FLASH_MIN_SEQ, else xla)",
+        help="auto|xla|flash|ring|ulysses (auto = flash on one TPU chip "
+        "from 1,024 positions at shapes the kernels tile, else xla)",
     )
     dataset = Parameter(
         "dataset", default="lm_synth", help="lm_synth | lm_text (byte-level)"

@@ -205,115 +205,38 @@ def test_flash_pallas_backward_multiblock(small_tiles):
             )
 
 
-def test_flash_pallas_backward_matches_blockwise_fallback(
-    monkeypatch, small_tiles
-):
-    """The kernel backward and the blockwise-recompute fallback agree."""
-    q, k, v = _qkv(B=1, T=32, H=2, D=16)
-
+def _grads(fn, q, k, v, causal=True):
     def loss(q, k, v):
-        return flash_attention(q, k, v).sum()
-
-    g_kernel = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("TPUFLOW_FLASH_BWD", "blockwise")
-    g_fallback = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_kernel, g_fallback):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
-
-
-def _flash_grads(q, k, v, mode, causal, monkeypatch):
-    """Grads through flash_attention with TPUFLOW_FLASH_BWD=mode. Fresh
-    trace per call — the knob resolves at trace time."""
-    if mode is None:
-        monkeypatch.delenv("TPUFLOW_FLASH_BWD", raising=False)
-    else:
-        monkeypatch.setenv("TPUFLOW_FLASH_BWD", mode)
-    jax.clear_caches()
-
-    def loss(q, k, v):
-        return (flash_attention(q, k, v, causal=causal) * 0.1).sum()
+        return (fn(q, k, v, causal=causal) * 0.1).sum()
 
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.slow
-def test_flash_bwd_fused_matches_split(monkeypatch, small_tiles):
-    """The fused backward (one kernel, transposed tiles, D from one XLA
-    reduction: ISSUE 31) against the split pair it is raced with on the
-    chip, in interpret mode, several q/k blocks — and the default against
-    the blockwise-recompute VJP. The two kernels sum in different orders,
-    so they agree to float rounding, not to the bit as ISSUE 10's pair
-    did. (The non-causal configs and per-config blockwise agreement ride
-    the slow full-grid twin below — the 820 s guard.)"""
-    # 3 q/k blocks (uneven vs the 16-block), small B/H to keep the
-    # interpret-mode grad compiles inside the tier-1 wall.
-    q, k, v = _qkv(B=1, T=48, H=2, D=16, seed=1)
-    g_fused = _flash_grads(q, k, v, None, True, monkeypatch)
-    g_split = _flash_grads(q, k, v, "split", True, monkeypatch)
-    g_block = _flash_grads(q, k, v, "blockwise", True, monkeypatch)
-    for a, b, c, name in zip(g_fused, g_split, g_block, "qkv"):
+def test_flash_pallas_backward_matches_blockwise_fallback(small_tiles):
+    """The kernel backward agrees with ``jax.grad`` of the pure-JAX
+    ``blockwise_attention`` (the off-chip path of odd shapes)."""
+    q, k, v = _qkv(B=1, T=32, H=2, D=16)
+    g_kernel = _grads(flash_attention, q, k, v)
+    g_blockwise = _grads(blockwise_attention, q, k, v)
+    for a, b in zip(g_kernel, g_blockwise):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "T", [pytest.param(31, marks=pytest.mark.slow), 32, 33]
+)
+def test_flash_bwd_parity_at_block_boundary_edges(T, small_tiles):
+    """Odd-T edges around the tile boundary (tile 16; T = 31/32/33): the
+    tiling T takes the kernels, the ±1 neighbors take the documented
+    blockwise fallback — either way the gradients agree with the XLA
+    reference."""
+    q, k, v = _qkv(B=1, T=T, H=2, D=16, seed=T)
+    g_flash = _grads(flash_attention, q, k, v)
+    g_ref = _grads(xla_attention, q, k, v)
+    for a, b in zip(g_flash, g_ref):
         np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=1e-5, err_msg=f"d{name}"
+            np.asarray(a), np.asarray(b), atol=2e-4, err_msg=f"T={T}"
         )
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(c), atol=1e-4, err_msg=f"d{name}"
-        )
-
-
-@pytest.mark.slow
-def test_flash_bwd_fused_matches_split_full_grid(monkeypatch, small_tiles):
-    """Causal and not, with per-config blockwise agreement (slow tier),
-    plus the below-boundary fallback edge T=31 the fast twin drops."""
-    q31 = _qkv(B=1, T=31, H=2, D=16, seed=31)
-    g31_fused = _flash_grads(*q31, None, True, monkeypatch)
-    g31_ref = jax.grad(
-        lambda q, k, v: (xla_attention(q, k, v) * 0.1).sum(),
-        argnums=(0, 1, 2),
-    )(*q31)
-    for a, b in zip(g31_fused, g31_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
-    for causal in (True, False):
-        q, k, v = _qkv(B=2, T=64, H=2, D=32, seed=1)
-        g_fused = _flash_grads(q, k, v, None, causal, monkeypatch)
-        g_split = _flash_grads(q, k, v, "split", causal, monkeypatch)
-        g_block = _flash_grads(q, k, v, "blockwise", causal, monkeypatch)
-        for a, b, c, name in zip(g_fused, g_split, g_block, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=1e-5,
-                err_msg=f"d{name} causal={causal}",
-            )
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(c), atol=1e-4,
-                err_msg=f"d{name} causal={causal}",
-            )
-
-
-def test_flash_bwd_parity_at_block_boundary_edges(monkeypatch, small_tiles):
-    """Odd-T edges around the tile boundary (tile 16; T = 31/32/33):
-    the tiling T takes the kernels, the ±1 neighbors take the documented
-    blockwise fallback — every mode's gradients must agree with the XLA
-    reference, and fused with split where the kernels actually run (at
-    the fallback T both env modes trace the SAME blockwise program, so
-    only one is compiled; the below-boundary edge T=31 rides the slow
-    twin)."""
-    for T in (32, 33):
-        q, k, v = _qkv(B=1, T=T, H=2, D=16, seed=T)
-        g_ref = jax.grad(
-            lambda q, k, v: (xla_attention(q, k, v) * 0.1).sum(),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        g_fused = _flash_grads(q, k, v, None, True, monkeypatch)
-        if T % 16 == 0:
-            g_split = _flash_grads(q, k, v, "split", True, monkeypatch)
-            for a, b in zip(g_fused, g_split):
-                np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b), atol=1e-5
-                )
-        for a, b in zip(g_fused, g_ref):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=2e-4,
-                err_msg=f"T={T}",
-            )
 
 
 def _cell_shape_parity(B, H, causal):
@@ -497,10 +420,10 @@ def test_gpt2_with_ulysses_attention_trains():
     assert np.isfinite(float(metrics["loss"]))
 
 
-def test_attention_auto_picks_xla_off_tpu(monkeypatch):
+def test_attention_auto_picks_xla_off_tpu():
     """impl='auto' must resolve to the XLA path everywhere except a TPU
-    backend from the measured fwd+bwd threshold (1,024 positions: the
-    chip calls of PR 31) — on this CPU platform it must equal
+    backend from the measured threshold (1,024 positions: the chip
+    calls of PR 31) — on this CPU platform it must equal
     xla_attention bit-for-bit at any length, including ones the flash
     kernel couldn't even tile."""
     from tpuflow.ops.attention import attention, xla_attention
@@ -513,15 +436,6 @@ def test_attention_auto_picks_xla_off_tpu(monkeypatch):
         np.asarray(attention(q, k, v, causal=True, impl="auto")),
         np.asarray(xla_attention(q, k, v, causal=True)),
     )
-    # The threshold is resolved at trace time (baked into compiled
-    # programs); these unjitted calls re-read it, and even a tiny min_seq
-    # changes nothing off-TPU.
-    monkeypatch.setenv("TPUFLOW_FLASH_MIN_SEQ", "1")
-    np.testing.assert_array_equal(
-        np.asarray(attention(q, k, v, causal=True, impl="auto")),
-        np.asarray(xla_attention(q, k, v, causal=True)),
-    )
-
 
 @pytest.mark.parametrize(
     "q_shape,tk,causal,mesh_axes,want",
@@ -530,8 +444,8 @@ def test_attention_auto_picks_xla_off_tpu(monkeypatch):
         ((8, 1024, 12, 64), 1024, True, {"data": 1, "fsdp": 1}, True),
         ((1, 600, 12, 64), 600, True, None, False),  # does not tile
         ((1, 1024, 25, 64), 1024, True, None, False),  # gpt2-xl's heads
-        ((8, 256, 12, 64), 1024, True, None, False),  # causal, Tq != Tk
-        ((8, 256, 12, 64), 1024, False, None, True),
+        ((8, 1024, 12, 64), 2048, True, None, False),  # causal, Tq != Tk
+        ((8, 1024, 12, 64), 2048, False, None, True),
         # A Mosaic kernel cannot be partitioned: under a mesh of several
         # devices (chip_smoke.py on four chips) 'auto' stays with XLA.
         ((8, 1024, 12, 64), 1024, True, {"data": 2, "fsdp": 4}, False),
@@ -542,9 +456,9 @@ def test_auto_takes_the_kernels_only_where_they_run(
     q_shape, tk, causal, mesh_axes, want
 ):
     import contextlib
-    import importlib
 
-    att = importlib.import_module("tpuflow.ops.attention")
+    from tpuflow.ops.attention import resolve_attention_impl
+
     if mesh_axes is None:
         ctx = contextlib.nullcontext()
     else:
@@ -554,20 +468,25 @@ def test_auto_takes_the_kernels_only_where_they_run(
             tuple(mesh_axes),
         )
     with ctx:
-        assert att._flash_runs(q_shape, tk, causal) is want
+        assert resolve_attention_impl(
+            "auto", q_shape, tk, causal=causal, backend="tpu"
+        ) == ("flash" if want else "xla")
 
 
 def test_auto_under_a_mesh_compiles_xla_attention(monkeypatch):
-    """Where the threshold says flash (forced here as on a TPU) and the
-    call is traced under a mesh of several devices, the jitted program
-    holds no kernel and equals XLA's to the bit; outside the mesh the
-    same call takes the kernel."""
+    """Where backend and length say flash (forced here as on a TPU) and
+    the call is traced under a mesh of several devices, the jitted
+    program holds no kernel and equals XLA's to the bit; outside the mesh
+    the same call takes the kernel."""
     import importlib
 
     att = importlib.import_module("tpuflow.ops.attention")
+    resolve = att.resolve_attention_impl
     monkeypatch.setattr(
-        att, "resolve_attention_impl", lambda impl, *a, **kw: "flash"
+        att, "resolve_attention_impl",
+        lambda *a, **kw: resolve(*a, **{**kw, "backend": "tpu"}),
     )
+    monkeypatch.setattr(att, "_FLASH_MIN_SEQ", 32)
     q, k, v = _qkv(B=8, T=32, H=2, D=16)
     fn = jax.jit(lambda q, k, v: attention(q, k, v, impl="auto"))
     mesh = dist.make_mesh({"data": 2, "fsdp": 4})
@@ -580,146 +499,50 @@ def test_auto_under_a_mesh_compiles_xla_attention(monkeypatch):
     assert "pallas_call" in str(jax.make_jaxpr(fn)(q, k, v))
 
 
-@pytest.fixture
-def untuned(monkeypatch):
-    """No threshold from the environment or a tuning file: the shipped
-    defaults decide."""
-    import importlib
-
-    monkeypatch.delenv("TPUFLOW_FLASH_MIN_SEQ", raising=False)
-    monkeypatch.delenv("TPUFLOW_FLASH_MIN_SEQ_FWD", raising=False)
-    # Point the tuning file somewhere empty so host state can't leak in.
-    monkeypatch.setenv("TPUFLOW_HOME", "/nonexistent_tpuflow_home")
-    att = importlib.import_module("tpuflow.ops.attention")
-    monkeypatch.setattr(att, "_flash_tuning_cache", None)
-    yield att
-    att._flash_tuning_cache = None
+# A training batch and a one-row dense prefill at each length.
+_TRAIN = (8, 20, 64)
+_PREFILL = (1, 16, 64)
 
 
 @pytest.mark.parametrize(
-    "seq,needs_bwd,backend,env,want",
+    "seq,bhd,backend,want",
     [
         # THE pin of ISSUE 31: the training cell's row takes the kernel.
-        (1024, True, "tpu", {}, "flash"),
-        (1024, False, "tpu", {}, "flash"),
+        (1024, _TRAIN, "tpu", "flash"),
+        (1024, _PREFILL, "tpu", "flash"),
         # Below 1,024 positions the winner depends on batch x heads (XLA
         # by up to 3x at 80 rows of 256 or 512, the kernels by 1.2x and
         # 2.6x at 8,192 tokens: chip calls 93 and 95), so XLA keeps them.
-        (512, True, "tpu", {}, "xla"),
-        (512, False, "tpu", {}, "xla"),
-        (256, True, "tpu", {}, "xla"),
-        (256, False, "tpu", {}, "xla"),
-        (2048, True, "tpu", {}, "flash"),
-        (2048, False, "tpu", {}, "flash"),
-        (128, True, "tpu", {}, "xla"),
-        (128, False, "tpu", {}, "xla"),
-        # Off-TPU is always XLA regardless of path or length.
-        (8192, True, "cpu", {}, "xla"),
-        (8192, False, "cpu", {}, "xla"),
-        # Per-path env overrides: each knob moves only its own path.
-        (2048, True, "tpu", {"TPUFLOW_FLASH_MIN_SEQ": "4096"}, "xla"),
-        (2048, False, "tpu", {"TPUFLOW_FLASH_MIN_SEQ": "4096"}, "flash"),
-        (128, False, "tpu", {"TPUFLOW_FLASH_MIN_SEQ_FWD": "64"}, "flash"),
-        (128, True, "tpu", {"TPUFLOW_FLASH_MIN_SEQ_FWD": "64"}, "xla"),
+        (512, _TRAIN, "tpu", "xla"),
+        (512, _PREFILL, "tpu", "xla"),
+        (256, _TRAIN, "tpu", "xla"),
+        (256, _PREFILL, "tpu", "xla"),
+        (2048, _TRAIN, "tpu", "flash"),
+        (2048, _PREFILL, "tpu", "flash"),
+        (128, _TRAIN, "tpu", "xla"),
+        (128, _PREFILL, "tpu", "xla"),
+        # Off-TPU is always XLA regardless of shape or length.
+        (8192, _TRAIN, "cpu", "xla"),
+        (8192, _PREFILL, "cpu", "xla"),
     ],
 )
-def test_flash_dispatch_independent_fwd_and_fwdbwd_thresholds(
-    seq, needs_bwd, backend, env, want, untuned, monkeypatch
-):
-    """'auto' carries one threshold for differentiated calls and one for
-    forward-only calls. Pins the shipped defaults (1,024 for both: the
-    chip calls of PR 31, PERF.md §6), the per-path env overrides and that
-    neither path borrows the other's."""
+def test_flash_dispatch_threshold(seq, bhd, backend, want):
+    """'auto' carries one threshold, for differentiated and forward-only
+    calls alike: 1,024 positions (the chip calls of PR 31, PERF.md §6)."""
     from tpuflow.ops.attention import resolve_attention_impl
 
-    for name, value in env.items():
-        monkeypatch.setenv(name, value)
+    b, h, d = bhd
     assert resolve_attention_impl(
-        "auto", seq, needs_bwd=needs_bwd, backend=backend) == want
+        "auto", (b, seq, h, d), seq, backend=backend) == want
 
 
-def test_flash_dispatch_passes_named_impls_through(untuned):
+def test_flash_dispatch_passes_named_impls_through():
+    import importlib
+
     from tpuflow.ops.attention import resolve_attention_impl
 
-    assert untuned._DEFAULT_FLASH_MIN_SEQ == 1024
-    assert untuned._DEFAULT_FLASH_MIN_SEQ_FWD == 1024
+    assert importlib.import_module(
+        "tpuflow.ops.attention")._FLASH_MIN_SEQ == 1024
     for impl in ("xla", "flash", "ring", "ulysses"):
         assert resolve_attention_impl(
-            impl, 8, needs_bwd=True, backend="cpu") == impl
-
-
-def test_flash_tuning_file_per_path_keys(tmp_path, monkeypatch):
-    """bench.py persists {flash_min_seq, flash_min_seq_fwd}; the
-    dispatcher reads each key for its own path only."""
-    import json
-
-    from tpuflow.ops.attention import resolve_attention_impl
-
-    monkeypatch.delenv("TPUFLOW_FLASH_MIN_SEQ", raising=False)
-    monkeypatch.delenv("TPUFLOW_FLASH_MIN_SEQ_FWD", raising=False)
-    monkeypatch.setenv("TPUFLOW_HOME", str(tmp_path))
-    with open(tmp_path / "flash_tuning.json", "w") as f:
-        json.dump({"flash_min_seq": 1024, "flash_min_seq_fwd": 256}, f)
-    import importlib
-
-    att = importlib.import_module("tpuflow.ops.attention")
-    monkeypatch.setattr(att, "_flash_tuning_cache", None)
-    assert resolve_attention_impl(
-        "auto", 1024, needs_bwd=True, backend="tpu") == "flash"
-    assert resolve_attention_impl(
-        "auto", 512, needs_bwd=True, backend="tpu") == "xla"
-    assert resolve_attention_impl(
-        "auto", 256, needs_bwd=False, backend="tpu") == "flash"
-    monkeypatch.setattr(att, "_flash_tuning_cache", None)
-
-
-def test_flash_tuning_bwd_only_crossover_governs_training_path(
-    tmp_path, monkeypatch
-):
-    """ISSUE 10 satellite: the fitted bwd-ONLY crossover
-    (``flash_min_seq_bwd``, from bench's T512/T2048 vjp timing split)
-    raises the effective fwd+bwd threshold — below the measured
-    backward-kernel loss region, auto dispatch picks XLA even when the
-    fwd+bwd composition entry would have allowed flash. The fwd-only
-    path never consults it; malformed entries degrade to the shipped
-    default with a once-per-process warning."""
-    import importlib
-    import json
-
-    from tpuflow.ops.attention import resolve_attention_impl
-
-    monkeypatch.delenv("TPUFLOW_FLASH_MIN_SEQ", raising=False)
-    monkeypatch.delenv("TPUFLOW_FLASH_MIN_SEQ_FWD", raising=False)
-    monkeypatch.setenv("TPUFLOW_HOME", str(tmp_path))
-    att = importlib.import_module("tpuflow.ops.attention")
-
-    def retune(entries):
-        with open(tmp_path / "flash_tuning.json", "w") as f:
-            json.dump(entries, f)
-        monkeypatch.setattr(att, "_flash_tuning_cache", None)
-
-    # The bwd crossover is the binding constraint: max(512, 2048).
-    retune({"flash_min_seq": 512, "flash_min_seq_bwd": 2048,
-            "flash_min_seq_fwd": 256})
-    assert resolve_attention_impl(
-        "auto", 1024, needs_bwd=True, backend="tpu") == "xla"
-    assert resolve_attention_impl(
-        "auto", 2048, needs_bwd=True, backend="tpu") == "flash"
-    # The fwd-only path is governed by its own key alone.
-    assert resolve_attention_impl(
-        "auto", 256, needs_bwd=False, backend="tpu") == "flash"
-    # bwd entry alone still gates the training path.
-    retune({"flash_min_seq_bwd": 1024})
-    assert resolve_attention_impl(
-        "auto", 512, needs_bwd=True, backend="tpu") == "xla"
-    assert resolve_attention_impl(
-        "auto", 1024, needs_bwd=True, backend="tpu") == "flash"
-    # Malformed entries are ignored (warn once) → shipped default 1,024.
-    retune({"flash_min_seq": "garbage", "flash_min_seq_bwd": -3})
-    monkeypatch.setattr(att, "_warned_malformed_tuning", False)
-    with pytest.warns(UserWarning, match="flash tuning entry"):
-        assert resolve_attention_impl(
-            "auto", 512, needs_bwd=True, backend="tpu") == "xla"
-    assert resolve_attention_impl(
-        "auto", 1024, needs_bwd=True, backend="tpu") == "flash"
-    monkeypatch.setattr(att, "_flash_tuning_cache", None)
+            impl, (1, 8, 2, 16), 8, backend="cpu") == impl

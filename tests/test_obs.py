@@ -979,3 +979,32 @@ def test_trainer_report_and_fit_events(tmp_path):
     assert "train.fit" in names
     report = next(e for e in events if e["name"] == "train.report")
     assert report["step"] == 1 and report["val_loss"] == 1.5
+
+
+@pytest.mark.parametrize(
+    "device_kind,peak",
+    [
+        ("TPU v5 lite", 197e12),  # what a v5e chip reports
+        ("TPU v5litepod", 197e12),  # the pod-slice spelling
+        ("TPU v5p", 459e12),
+        ("TPU v5", 459e12),  # must not shadow the lite entries above it
+        ("TPU v4", 275e12),
+        ("TPU v6 lite", 918e12),
+        ("TPU v6e", 918e12),
+        ("TPU v9 hyperchip", None),  # unknown: an error, not a default
+    ],
+)
+def test_peak_flops_table_matches_device_kind_strings(device_kind, peak):
+    """The rolling MFU's denominator keys on
+    ``jax.devices()[0].device_kind``, which reads like 'TPU v5 lite', not
+    'v5e': the package's one peak table against the strings each
+    generation reports."""
+    from tpuflow.obs import goodput
+
+    if peak is None:
+        with pytest.raises(ValueError, match=device_kind):
+            goodput.table_value(goodput._PEAK_FLOPS, device_kind, "peak")
+    else:
+        assert goodput.table_value(
+            goodput._PEAK_FLOPS, device_kind, "peak"
+        ) == peak

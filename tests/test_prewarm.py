@@ -46,7 +46,7 @@ def test_prewarm_tool_populates_cache_end_to_end(tmp_path):
             sys.executable, os.path.join(REPO, "tools", "prewarm_cache.py"),
             "--preset", "test", "--batch", "2", "--seq-len", "32",
             "--buckets", "8", "--slots", "2",
-            "--decode-block", "2", "--max-new", "8", "--quant",
+            "--decode-block", "2", "--quant",
             "--spec", "2", "--page-size", "8",
             "--allow-cpu",
         ],

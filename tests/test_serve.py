@@ -64,7 +64,6 @@ def engine(model_params):
         model, params, max_slots=2, buckets=[8, 16], decode_block=4,
         page_size=8,
     )
-    assert eng.paged  # ISSUE 11: paged is the default engine
     eng.warmup()
     return eng
 
@@ -187,6 +186,28 @@ def test_ngram_draft_host():
         ngram_draft(np.array([], np.int32), 2)
 
 
+@pytest.mark.parametrize("who", ["engine", "engine_speculative", "model"])
+def test_slot_row_layout_is_refused(model_params, who):
+    """The contiguous slot rows went with PR 32: the engine's ``paged``
+    keyword stays for the benchmark's files alone and refuses False, and
+    the model refuses per-row positions without a page table."""
+    model, params = model_params
+    if who == "model":
+        with pytest.raises(ValueError, match="slot_index passed without"):
+            jax.eval_shape(
+                lambda p: model.apply(
+                    {"params": p}, jnp.zeros((2, 1), jnp.int32),
+                    decode=True, mutable=["cache"],
+                    slot_index=jnp.zeros((2,), jnp.int32),
+                ),
+                params,
+            )
+    else:
+        kw = {"speculative": 2} if who == "engine_speculative" else {}
+        with pytest.raises(ValueError, match="paged=False"):
+            ServeEngine(model, params, paged=False, **kw)
+
+
 def test_resolve_paged_knobs(monkeypatch):
     from tpuflow.infer.serve import resolve_page_size, resolve_spec_draft
 
@@ -261,7 +282,7 @@ def test_serve_ledger_feeds_metrics_export():
     led.note_serve_ttft(0.05)
     led.note_serve_complete()
     snap = led.snapshot()
-    assert "serve_pages_free" not in snap  # non-paged engine: no keys
+    assert "serve_pages_free" not in snap  # no pool reported yet: no keys
     assert "serve_spec_accept_rate" not in snap
     led.note_serve_pages(free=12, total=16)
     led.note_serve_prefix(hits=3, lookups=4)
@@ -694,7 +715,7 @@ def test_prefix_cache_reuse_eviction_and_residency(model_params):
     counted); after release the pages idle in the cache, a matching
     third request reactivates them, and pool pressure evicts them
     LRU-first with a serve.page_evict trail. Residency efficiency beats
-    the contiguous engine's on the same traffic."""
+    what a contiguous ``n_ctx`` row a live slot would strand."""
     model, params = model_params
     eng = ServeEngine(
         model, params, max_slots=2, buckets=[8, 16, 32], decode_block=4,
@@ -732,24 +753,19 @@ def test_prefix_cache_reuse_eviction_and_residency(model_params):
     )
     assert eng.pool.evictions == 2
     assert eng.compile_stats() == base, "paged engine recompiled"
-    # Residency: short requests on the paged engine keep most allocated
-    # tokens resident, while a contiguous engine strands the n_ctx row.
+    # Residency: short requests keep most allocated tokens resident,
+    # where a contiguous layout would strand an n_ctx row a live slot.
     # max_new outlives one decode block so the sample sees a live slot.
     r1 = eng.submit(pa, max_new_tokens=6)
     eng.step()
     paged_res = eng.residency_efficiency()
+    live = np.nonzero(eng._live)[0]
+    resident = int((eng._lengths[live] - eng._pads[live]).sum())
+    flat_res = resident / (live.size * eng.n_ctx)
     eng.run_until_idle(max_iters=200)
-    flat = ServeEngine(
-        model, params, max_slots=2, buckets=[8, 16, 32], decode_block=4,
-        paged=False,
-    )
-    flat.warmup()
-    r2 = flat.submit(pa, max_new_tokens=6)
-    flat.step()
-    flat_res = flat.residency_efficiency()
-    flat.run_until_idle(max_iters=200)
-    np.testing.assert_array_equal(r1.result(), r2.result())
-    assert paged_res is not None and flat_res is not None
+    np.testing.assert_array_equal(r1.result(), _solo(model, params, pa, 6))
+    assert live.size == 1 and resident >= pa.size
+    assert paged_res == resident / (4 * eng.page_size)  # ceil(25 / 8) pages
     assert paged_res > flat_res, (paged_res, flat_res)
 
 
@@ -836,8 +852,6 @@ def test_speculative_engine_token_exact(model_params):
     )
     with pytest.raises(ValueError, match="spec-armed"):
         plain.submit(prep, max_new_tokens=4, speculative=True)
-    with pytest.raises(ValueError, match="paged"):
-        ServeEngine(model, params, paged=False, speculative=2)
 
 
 @pytest.mark.slow
@@ -857,7 +871,7 @@ def test_mixed_spec_int8_prefix_slot_reuse(model_params, monkeypatch):
     monkeypatch.setenv("TPUFLOW_SERVE_PAGE_SIZE", "8")
     eng = ServeEngine(model, params, max_slots=2, buckets=[8, 16],
                       decode_block=4)
-    assert eng.quant_mode == "mxu" and eng.spec_draft == 3 and eng.paged
+    assert eng.quant_mode == "mxu" and eng.spec_draft == 3
     base = eng.warmup()
     assert {"verify", "verify_q", "prefill_q", "decode_q"} <= set(base)
     rng = np.random.default_rng(25)
@@ -894,33 +908,6 @@ def test_mixed_spec_int8_prefix_slot_reuse(model_params, monkeypatch):
     assert eng.pool.prefix_hits > h0  # pd reused pc's prefix page
     assert eng.compile_stats() == base, "mixed-traffic engine recompiled"
     assert eng.live_slots == 0 and eng.pool.allocated_pages == 0
-
-
-@pytest.mark.slow
-def test_nonpaged_regression_reference(model_params, monkeypatch):
-    """TPUFLOW_SERVE_PAGED=0 keeps the PR 8 contiguous slot rows (the
-    one-release regression reference): exactness + never-recompile hold
-    on the legacy path, and the paged knobs stay inert on it."""
-    model, params = model_params
-    monkeypatch.setenv("TPUFLOW_SERVE_PAGED", "0")
-    eng = ServeEngine(
-        model, params, max_slots=2, buckets=[8, 16], decode_block=4
-    )
-    assert not eng.paged and eng.pool is None
-    base = eng.warmup()
-    rng = np.random.default_rng(26)
-    prompts = [rng.integers(0, 512, size=L).astype(np.int32)
-               for L in (3, 8, 11)]
-    reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
-    eng.run_until_idle(max_iters=200)
-    for p, r in zip(prompts, reqs):
-        np.testing.assert_array_equal(
-            r.result(), _solo(model, params, p, 6)
-        )
-    assert eng.compile_stats() == base
-    # Contiguous capacity semantics: the PADDED width eats cache columns.
-    with pytest.raises(ValueError, match="no prefill bucket"):
-        eng.bucket_for(9, 50)  # bucket 16 + 50 > n_ctx=64
 
 
 # ------------------------------------ chunked prefill admission boundaries

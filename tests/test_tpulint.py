@@ -353,11 +353,11 @@ def test_jit_pass_rebind_same_statement_is_not_reuse(tmp_path):
     src = (
         "import jax\n\n\n"
         "class Engine:\n"
-        "    def _insert_fn(self, cache, row):\n"
+        "    def _page_insert_fn(self, cache, row):\n"
         "        return cache\n\n"
         "    def build(self):\n"
         "        self._insert = jax.jit(\n"
-        "            self._insert_fn, donate_argnums=(0,)\n"
+        "            self._page_insert_fn, donate_argnums=(0,)\n"
         "        )\n\n"
         "    def admit(self, row):\n"
         "        self._cache = self._insert(self._cache, row)\n"
@@ -570,3 +570,23 @@ def test_no_raw_tpuflow_env_reads_outside_registry():
         if f.rule in ("knob-raw-env", "knob-dynamic")
     ]
     assert not found, "\n".join(str(f) for f in found)
+
+
+def test_every_registered_knob_is_named_by_code():
+    """A knob the registry declares and no code names is a table row for
+    nothing: every name appears in some Python file under tpuflow/,
+    flows/, tools/, tests/ or in chip_smoke.py, other than the registry
+    itself. (A name that is a prefix of another, as
+    ``TPUFLOW_SERVE`` is of ``TPUFLOW_SERVE_SLOTS``, must appear whole.)"""
+    import re
+
+    registry = os.path.join("tpuflow", "utils", "knobs.py")
+    tree = core.Tree(REPO, scan=core.DEFAULT_SCAN + ("chip_smoke.py",))
+    seen: set[str] = set()
+    for rel in tree.files():
+        if rel != registry:
+            seen.update(
+                re.findall(r"TPUFLOW_[A-Z0-9_]*[A-Z0-9]", tree.source(rel))
+            )
+    unnamed = sorted(set(knobs.REGISTRY) - seen)
+    assert not unnamed, f"declared in {registry}, named by no code: {unnamed}"

@@ -27,7 +27,7 @@ Usage::
     python tools/prewarm_cache.py --preset gpt2 --batch 8 --seq-len 512 \
         [--no-train] [--no-serve] \
         [--quant] [--spec K] [--slots 8] [--buckets 16,32,64] \
-        [--page-size 16] [--pages N] [--max-new 128]
+        [--page-size 16] [--pages N]
 
 CPU note: the persistent cache is OFF on CPU by default (the XLA:CPU
 AOT loader can abort reloading entries across machine-feature changes —
@@ -80,17 +80,12 @@ def _parse(argv):
     p.add_argument("--pages", type=int, default=None,
                    help="paged-KV pool size (default slots * n_ctx / "
                         "page_size + 1)")
-    p.add_argument("--no-paged", action="store_true",
-                   help="prewarm the legacy contiguous slot-row "
-                        "signatures instead of the paged ones")
     p.add_argument("--slots", type=int, default=None,
                    help="serving slots (default TPUFLOW_SERVE_SLOTS/8)")
     p.add_argument("--buckets", default=None,
                    help="comma prefill bucket widths (default ladder)")
     p.add_argument("--decode-block", type=int, default=None,
                    help="serving decode-block tokens")
-    p.add_argument("--max-new", type=int, default=128,
-                   help="capacity headroom the bucket ladder must keep")
     p.add_argument("--allow-cpu", action="store_true",
                    help="force-enable the persistent cache on CPU (tests)")
     return p.parse_args(argv)
@@ -233,19 +228,16 @@ def prewarm(args) -> dict:
             buckets=buckets,
             decode_block=args.decode_block,
             quant="fused_native" if args.quant else None,
-            paged=False if args.no_paged else None,
             page_size=args.page_size,
             n_pages=args.pages,
             speculative=args.spec,
         )
         # The engine owns its AOT signature list (decode block, verify
-        # block, page/slot insert, bucket prefills, int8 twins) so this
+        # block, page insert, bucket prefills, int8 twins) so this
         # tool can never drift from the programs the scheduler replays
         # — ISSUE 11 moved the per-signature lowering into
         # ServeEngine.aot_lower when the paged/spec programs landed.
-        programs += engine.aot_lower(
-            max_new_tokens=args.max_new, ledger=ledger
-        )
+        programs += engine.aot_lower(ledger=ledger)
 
     # Program ledger + static HBM budget verdict beside the cache (the
     # operator's pre-launch footprint view; budget ratios absent off-TPU

@@ -1424,7 +1424,7 @@ def _restore_raw_inner(
         # on a core, so capping workers at cpu_count starves the device's
         # queue depth on low-core hosts — measured on the 1-core dev box:
         # cold disk restore 1.10 GB/s with 1 worker vs a 1.81 GB/s
-        # 2-stream device ceiling (bench.py probe_disk_ceiling). The
+        # 2-stream device ceiling. The
         # floor of 4 matches the write path's pipeline width. An EXPLICIT
         # TPUFLOW_IO_THREADS is a user cap on inflight IO (e.g. to stay
         # polite on shared storage) — it wins over the floor.

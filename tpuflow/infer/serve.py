@@ -11,21 +11,22 @@ saturated and move requests through it independently.
 
 Shape of the engine:
 
-- **Slot-based KV cache.** One fixed ``(max_slots, n_ctx)`` cache owned
-  by one compiled decode-block program. Each slot carries its own
+- **Slots over one KV cache.** One fixed set of ``max_slots`` rows
+  owned by one compiled decode-block program. Each slot carries its own
   ``live`` / ``length`` / ``pad`` / ``remaining`` state as (S,) operand
   arrays — admissions, generation, and evictions are DATA, never shape,
   so nothing recompiles. The per-row cache positions ride the model's
-  ``slot_index`` decode path (``GPT2.__call__``): row b writes its k/v
-  at its own column and its queries see ``[pad[b], length[b]]`` only, so
-  a reused slot's stale columns stay invisible.
+  ``slot_index`` + ``page_table`` decode path (``GPT2.__call__``): row b
+  writes its k/v at its own column of its own pages and its queries see
+  ``[pad[b], length[b]]`` only, so a reused page's stale columns stay
+  invisible.
 
 - **Chunked prefill as the admission path.** A waiting request is
   admitted by LEFT-padding its prompt to a small set of bucket widths
   (``pad_to`` semantics: a handful of prefill programs compile, ever)
   and running ``chunked_prefill`` on a (1, W) row — bounding peak
   attention memory to O(chunk x n_ctx) — then a jitted insert writes the
-  row's cache into the free slot. Prefill interleaves with decode blocks
+  row's cache into the slot's pages. Prefill interleaves with decode blocks
   at the scheduler loop, the continuous-batching core.
 
 - **Decode blocks.** Between admissions the engine runs the persistent
@@ -56,12 +57,12 @@ Shape of the engine:
   its state). ``compile_stats()`` still never grows after warmup — the
   never-recompile contract covers the quantized program too.
 
-- **Paged KV (ISSUE 11, default on).** The per-slot contiguous
-  ``(max_slots, n_ctx)`` cache rows become a fixed POOL of
-  ``(n_pages, page_size)`` pages plus a per-slot page table threaded
-  through the decode block as data (``Block._paged_attention``) — the
-  same "state is data, never shape" trick that made slots
-  recompile-free now covers page allocation. What paging buys:
+- **Paged KV (ISSUE 11; the only layout since PR 32).** The cache is a
+  fixed POOL of ``(n_pages, page_size)`` pages plus a per-slot page
+  table threaded through the decode block as data
+  (``Block._paged_attention``) — the same "state is data, never shape"
+  trick that made slots recompile-free covers page allocation. What
+  paging buys over a ``(max_slots, n_ctx)`` row a slot:
 
   * **Admission by token budget.** A request is admitted when its page
     need (``ceil((len + max_new [+ draft slack]) / page_size)``) fits
@@ -95,10 +96,9 @@ Knobs: ``TPUFLOW_SERVE_SLOTS`` (default 8), ``TPUFLOW_SERVE_PREFILL_CHUNK``
 power-of-two ladder up to ``n_ctx``), ``TPUFLOW_SERVE_DECODE_BLOCK``
 (tokens per decode dispatch, default 8), ``TPUFLOW_SERVE_QUANT``
 (=1/fused_native/weight_only arms per-request int8; default off),
-``TPUFLOW_SERVE_PAGED`` (=0 keeps the PR 8 contiguous slot rows — the
-regression reference, kept one release), ``TPUFLOW_SERVE_PAGE_SIZE``
-(default 16 tokens), ``TPUFLOW_SERVE_PAGES`` (pool size; default
-``max_slots * n_ctx / page_size + 1`` — equal HBM to the slot rows),
+``TPUFLOW_SERVE_PAGE_SIZE`` (default 16 tokens),
+``TPUFLOW_SERVE_PAGES`` (pool size; default
+``max_slots * n_ctx / page_size + 1`` — a full row a slot),
 ``TPUFLOW_SERVE_PREFIX_CACHE`` (=0 disables shared-prefix reuse),
 ``TPUFLOW_SERVE_SPEC`` (=K arms per-request speculative decode),
 ``TPUFLOW_SERVE`` (=0 keeps ``GenerationPredictor`` on the legacy
@@ -658,7 +658,7 @@ class ServeEngine:
         decode_block: int | None = None,
         pad_id: int = 0,
         quant: str | bool | None = None,
-        paged: bool | None = None,
+        paged: bool = True,
         page_size: int | None = None,
         n_pages: int | None = None,
         prefix_cache: bool | None = None,
@@ -752,21 +752,15 @@ class ServeEngine:
         # tables. The decode model is the SAME module cloned with the
         # pool geometry in its config (params untouched) — geometry is
         # static by construction, tables are data.
-        self.paged = (
-            _env_flag("TPUFLOW_SERVE_PAGED", True) if paged is None
-            else bool(paged)
-        )
+        if not paged:  # the benchmark's files still pass paged=True
+            raise ValueError(
+                "ServeEngine(paged=False): the contiguous slot rows went "
+                "with PR 32; the engine's cache is the page pool"
+            )
         self.spec_draft = resolve_spec_draft(speculative)
         self.spec_ngram = int(spec_ngram)
         if self.spec_ngram < 2:
             raise ValueError(f"spec_ngram must be >= 2, got {spec_ngram}")
-        if self.spec_draft and not self.paged:
-            raise ValueError(
-                "per-request speculative decode needs the paged cache "
-                "(the contiguous slot rows' block write clamps at the "
-                "n_ctx edge; paging routes overshoot to the trash page) "
-                "— drop paged=False or TPUFLOW_SERVE_PAGED=0"
-            )
         # Disaggregated serving (ISSUE 19): the engine role, the
         # shared KV-page store (ship/import), and the tiered prefix
         # cache. Everything defaults off/"both" — an engine built with
@@ -780,65 +774,59 @@ class ServeEngine:
         self._tier: _kvstore.TierCache | None = None
         self._prefill_calls = 0
         self._row_tmpl = None
-        self._pmodel = self._qpmodel = None
-        self.pool = None
-        if self.paged:
-            self.page_size = resolve_page_size(self.n_ctx, page_size)
-            self.pages_per_slot = self.n_ctx // self.page_size
-            default_pages = S * self.pages_per_slot + 1
-            self.n_pages = (
-                int(n_pages) if n_pages is not None
-                else _env_int("TPUFLOW_SERVE_PAGES", default_pages,
-                              minimum=2)
+        self._qpmodel = None
+        self.page_size = resolve_page_size(self.n_ctx, page_size)
+        self.pages_per_slot = self.n_ctx // self.page_size
+        default_pages = S * self.pages_per_slot + 1
+        self.n_pages = (
+            int(n_pages) if n_pages is not None
+            else _env_int("TPUFLOW_SERVE_PAGES", default_pages, minimum=2)
+        )
+        if self.n_pages < 2:
+            raise ValueError(
+                f"n_pages must be >= 2 (page 0 is the trash page), "
+                f"got {self.n_pages}"
             )
-            if self.n_pages < 2:
-                raise ValueError(
-                    f"n_pages must be >= 2 (page 0 is the trash page), "
-                    f"got {self.n_pages}"
-                )
-            use_prefix = (
-                _env_flag("TPUFLOW_SERVE_PREFIX_CACHE", True)
-                if prefix_cache is None else bool(prefix_cache)
-            )
-            # Tiered prefix cache (ISSUE 19): both tiers default OFF —
-            # the untiered pool is byte-identical to PR 11.
-            host_mb = (
-                float(kv_host_mb) if kv_host_mb is not None
-                else float(knobs.get_float("TPUFLOW_KV_HOST_MB"))
-            )
-            tier_disk = (
-                kv_disk_dir if kv_disk_dir is not None
-                else knobs.raw("TPUFLOW_KV_DISK_DIR")
-            )
-            if use_prefix and (host_mb > 0 or tier_disk):
-                self._tier = _kvstore.TierCache(
-                    host_bytes=int(host_mb * 2**20),
-                    disk_dir=tier_disk or None,
-                    index_max=int(knobs.get_int("TPUFLOW_KV_INDEX_MAX")),
-                    disk_max_bytes=int(
-                        float(knobs.get_float("TPUFLOW_KV_DISK_MB"))
-                        * 2**20
-                    ),
-                )
-            self.pool = PagePool(
-                self.n_pages, self.page_size, prefix_cache=use_prefix,
-                tier_cache=self._tier,
-                page_reader=(
-                    self._read_page_host
-                    if self._tier is not None else None
+        use_prefix = (
+            _env_flag("TPUFLOW_SERVE_PREFIX_CACHE", True)
+            if prefix_cache is None else bool(prefix_cache)
+        )
+        # Tiered prefix cache (ISSUE 19): both tiers default OFF —
+        # the untiered pool is byte-identical to PR 11.
+        host_mb = (
+            float(kv_host_mb) if kv_host_mb is not None
+            else float(knobs.get_float("TPUFLOW_KV_HOST_MB"))
+        )
+        tier_disk = (
+            kv_disk_dir if kv_disk_dir is not None
+            else knobs.raw("TPUFLOW_KV_DISK_DIR")
+        )
+        if use_prefix and (host_mb > 0 or tier_disk):
+            self._tier = _kvstore.TierCache(
+                host_bytes=int(host_mb * 2**20),
+                disk_dir=tier_disk or None,
+                index_max=int(knobs.get_int("TPUFLOW_KV_INDEX_MAX")),
+                disk_max_bytes=int(
+                    float(knobs.get_float("TPUFLOW_KV_DISK_MB"))
+                    * 2**20
                 ),
             )
-            self._page_table = np.zeros(
-                (S, self.pages_per_slot), np.int32
+        self.pool = PagePool(
+            self.n_pages, self.page_size, prefix_cache=use_prefix,
+            tier_cache=self._tier,
+            page_reader=(
+                self._read_page_host if self._tier is not None else None
+            ),
+        )
+        self._page_table = np.zeros((S, self.pages_per_slot), np.int32)
+        self._slot_pages: list[list[int]] = [[] for _ in range(S)]
+        self._pmodel = model.clone(
+            config=dataclasses.replace(
+                model.config,
+                kv_pages=self.n_pages,
+                kv_page_size=self.page_size,
             )
-            self._slot_pages: list[list[int]] = [[] for _ in range(S)]
-            self._pmodel = model.clone(
-                config=dataclasses.replace(
-                    model.config,
-                    kv_pages=self.n_pages,
-                    kv_page_size=self.page_size,
-                )
-            )
+        )
         self._queue: collections.deque[ServeRequest] = collections.deque()
         self._slots: list[ServeRequest | None] = [None] * S
         self._tok = np.zeros((S,), np.int32)
@@ -858,25 +846,19 @@ class ServeEngine:
         self._last_gauges: tuple | None = None
         self._cache = self._init_cache()
 
-        decode_model = self._pmodel if self.paged else self.model
         self._prefill = jax.jit(
             functools.partial(self._prefill_fn, self.model),
             static_argnames=("chunk",),
         )
-        if self.paged:
-            self._insert = jax.jit(
-                self._page_insert_fn, donate_argnums=(0,)
-            )
-        else:
-            self._insert = jax.jit(self._insert_fn, donate_argnums=(0,))
+        self._insert = jax.jit(self._page_insert_fn, donate_argnums=(0,))
         self._decode = jax.jit(
-            functools.partial(self._decode_fn, decode_model),
+            functools.partial(self._decode_fn, self._pmodel),
             donate_argnums=(1,),
         )
         self._verify = None
         if self.spec_draft:
             self._verify = jax.jit(
-                functools.partial(self._verify_fn, decode_model),
+                functools.partial(self._verify_fn, self._pmodel),
                 donate_argnums=(1,),
             )
         self._prefill_q = self._decode_q = self._verify_q = None
@@ -885,54 +867,42 @@ class ServeEngine:
             # pytree, bucket widths), different static model + params
             # pytree — so fp and int8 requests interleave through one
             # engine with zero fresh compiles after warmup.
-            qdecode_model = self._qmodel
-            if self.paged:
-                # The int8 wrapper around the PAGED clone for the decode
-                # programs (the prefill twin keeps the row-cache model).
-                self._qpmodel = dataclasses.replace(
-                    self._qmodel, model=self._pmodel
-                )
-                qdecode_model = self._qpmodel
+            # The int8 wrapper around the PAGED clone for the decode
+            # programs (the prefill twin keeps the row-cache model).
+            self._qpmodel = dataclasses.replace(
+                self._qmodel, model=self._pmodel
+            )
             self._prefill_q = jax.jit(
                 functools.partial(self._prefill_fn, self._qmodel),
                 static_argnames=("chunk",),
             )
             self._decode_q = jax.jit(
-                functools.partial(self._decode_fn, qdecode_model),
+                functools.partial(self._decode_fn, self._qpmodel),
                 donate_argnums=(1,),
             )
             if self.spec_draft:
                 self._verify_q = jax.jit(
-                    functools.partial(self._verify_fn, qdecode_model),
+                    functools.partial(self._verify_fn, self._qpmodel),
                     donate_argnums=(1,),
                 )
 
     # ------------------------------------------------------- jitted programs
     def _init_cache(self):
         """Zeroed KV cache with the decode model's exact cache pytree
-        (eval_shape — no compile, no garbage forward): a (n_pages,
-        page_size) pool when paged, per-slot (max_slots, n_ctx) rows
-        otherwise."""
+        (eval_shape — no compile, no garbage forward): the (n_pages,
+        page_size) pool."""
 
         def mk(params):
-            if self.paged:
-                _, variables = self._pmodel.apply(
-                    {"params": params},
-                    jnp.zeros((self.max_slots, 1), jnp.int32),
-                    decode=True,
-                    mutable=["cache"],
-                    slot_index=jnp.zeros((self.max_slots,), jnp.int32),
-                    page_table=jnp.zeros(
-                        (self.max_slots, self.pages_per_slot), jnp.int32
-                    ),
-                )
-            else:
-                _, variables = self.model.apply(
-                    {"params": params},
-                    jnp.zeros((self.max_slots, 1), jnp.int32),
-                    decode=True,
-                    mutable=["cache"],
-                )
+            _, variables = self._pmodel.apply(
+                {"params": params},
+                jnp.zeros((self.max_slots, 1), jnp.int32),
+                decode=True,
+                mutable=["cache"],
+                slot_index=jnp.zeros((self.max_slots,), jnp.int32),
+                page_table=jnp.zeros(
+                    (self.max_slots, self.pages_per_slot), jnp.int32
+                ),
+            )
             return variables["cache"]
 
         shapes = jax.eval_shape(mk, self.params)
@@ -953,26 +923,8 @@ class ServeEngine:
         return tok0, cache
 
     @jax.named_scope("serve.insert")
-    def _insert_fn(self, cache, row_cache, slot):
-        """Write a (1, n_ctx) prefill cache row into ``slot`` of the big
-        cache. K/V leaves are (S, n_ctx, H, D) (or (L, S, n_ctx, H, D)
-        under scan_layers — the slot axis sits 4 dims from the end);
-        scalar index leaves pass through untouched (slot mode never reads
-        them)."""
-
-        def put(big, row):
-            if big.ndim >= 4:
-                start = (0,) * (big.ndim - 4) + (slot, 0, 0, 0)
-                return jax.lax.dynamic_update_slice(
-                    big, row.astype(big.dtype), start
-                )
-            return big
-
-        return jax.tree_util.tree_map(put, cache, row_cache)
-
-    @jax.named_scope("serve.insert")
     def _page_insert_fn(self, cache, row_cache, table_row, pad, write_mask):
-        """Paged admission insert: strip the (1, n_ctx) prefill row's
+        """The admission insert: strip the (1, n_ctx) prefill row's
         LEFT padding (roll by ``pad`` — the real prompt kv moves to
         logical columns [0, len), making cache content pad-invariant,
         the property prefix sharing rests on) and scatter its logical
@@ -1011,7 +963,7 @@ class ServeEngine:
     @jax.named_scope("serve.verify")
     def _verify_fn(self, model, params, cache, page_table, tok, draft,
                    lengths, pads, remaining, live, eos):
-        """The speculative verify block (paged engines only): ONE
+        """The speculative verify block: ONE
         (S, draft_len + 1) forward over [cur, draft...] per slot, then a
         PER-ROW commit — the accepted draft prefix plus the model's
         bonus token at the first disagreement, truncated by each row's
@@ -1078,16 +1030,15 @@ class ServeEngine:
 
     @jax.named_scope("serve.decode")
     def _decode_fn(self, model, params, cache, tok, lengths, pads,
-                   remaining, live, eos, page_table=None):
+                   remaining, live, eos, page_table):
         """THE persistent decode program: ``decode_block`` single-token
         steps over every slot, per-slot freezing inside the scan. One
         host sync per block. Dead slots keep rewriting one cache column
-        with pad-token k/v — masked out of every live row (paged: routed
-        to the trash page by their zeroed tables), overwritten by the
-        next admission's insert. ``model`` is partial-bound per numeric
-        path AND cache layout: the int8 twin runs the same program shape
-        with the fused-native W8A8 matmuls; the paged twin threads
-        ``page_table`` (loop-invariant data) into every step."""
+        with pad-token k/v — routed to the trash page by their zeroed
+        tables. ``model`` is partial-bound per numeric path: the int8
+        twin runs the same program shape with the fused-native W8A8
+        matmuls. ``page_table`` (loop-invariant data) goes into every
+        step."""
         n_ctx = self.n_ctx
         pad_id = self.pad_id
 
@@ -1129,22 +1080,14 @@ class ServeEngine:
 
     # ------------------------------------------------------------ scheduling
     def bucket_for(self, prompt_len: int, max_new_tokens: int) -> int:
-        """Smallest bucket width holding the prompt whose capacity check
-        passes. Paged engines check the REAL prompt length against n_ctx
-        (the page insert strips bucket pads, so pads cost prefill FLOPs
-        only, never cache columns); contiguous slot rows keep the PR 8
-        rule — bucket pads eat cache columns, so the check is on the
-        padded width."""
-        for w in self.buckets:
-            if prompt_len > w:
-                continue
-            fits = (
-                prompt_len + max_new_tokens <= self.n_ctx
-                if self.paged
-                else w + max_new_tokens <= self.n_ctx
-            )
-            if fits:
-                return w
+        """Smallest bucket width holding the prompt, where the prompt and
+        its budget fit n_ctx: the check is on the REAL prompt length (the
+        page insert strips bucket pads, so pads cost prefill FLOPs only,
+        never cache columns)."""
+        if prompt_len + max_new_tokens <= self.n_ctx:
+            for w in self.buckets:
+                if prompt_len <= w:
+                    return w
         raise ValueError(
             f"no prefill bucket fits prompt_len={prompt_len} + "
             f"max_new_tokens={max_new_tokens} within n_ctx={self.n_ctx} "
@@ -1209,7 +1152,7 @@ class ServeEngine:
             speculative
         )
         kv_import = None
-        if kv_key is not None and self.kv_store is not None and self.paged:
+        if kv_key is not None and self.kv_store is not None:
             with obs.span("serve.kv_import", key=kv_key) as sp:
                 pset = self.kv_store.load(kv_key)
                 if pset is not None and self._import_ok(
@@ -1232,7 +1175,7 @@ class ServeEngine:
             bucket=bucket,
             trace_ctx=trace,
         )
-        if self.paged and self._pages_needed(req) > self.pool.usable_pages:
+        if self._pages_needed(req) > self.pool.usable_pages:
             raise ValueError(
                 f"request needs {self._pages_needed(req)} pages but the "
                 f"pool holds {self.pool.usable_pages} usable pages "
@@ -1281,20 +1224,16 @@ class ServeEngine:
 
     def residency_efficiency(self) -> float | None:
         """HBM residency: tokens resident (live slots' committed cache
-        columns) / tokens allocated (live slots' held pages x page_size;
-        contiguous engines hold a full n_ctx row per live slot). The
-        bench's paged-vs-slot headline — short requests strand most of a
-        contiguous row but only their own pages. None when idle."""
+        columns) / tokens allocated (live slots' held pages x page_size):
+        a short request strands only the tail of its last page. None when
+        idle."""
         live = np.nonzero(self._live)[0]
         if live.size == 0:
             return None
         resident = int((self._lengths[live] - self._pads[live]).sum())
-        if self.paged:
-            allocated = sum(
-                len(self._slot_pages[int(s)]) for s in live
-            ) * self.page_size
-        else:
-            allocated = int(live.size) * self.n_ctx
+        allocated = sum(
+            len(self._slot_pages[int(s)]) for s in live
+        ) * self.page_size
         if allocated <= 0:
             return None
         return resident / allocated
@@ -1402,10 +1341,6 @@ class ServeEngine:
         page (private to the request — decode writes land there) and
         the first greedy token, so an exact import admits with zero
         prefill."""
-        if not self.paged:
-            raise ValueError(
-                "KV export needs the paged engine (TPUFLOW_SERVE_PAGED)"
-            )
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("prompt must have at least one token")
@@ -1647,22 +1582,18 @@ class ServeEngine:
         ``L-1`` lands in a covered page the decode write re-writes
         identical bytes, so shared pages stay sound). A request with
         neither rides the classic path byte-identically."""
-        page_ids: list[int] | None = None
-        matched = 0
-        promoted: list[tuple[int, bytes, str]] = []
-        if self.paged:
-            got = self.pool.acquire(req.prompt, self._pages_needed(req))
-            if got is None:
-                self._note_queued(req, "pages")
-                return False
-            page_ids, matched = got
-            promoted = self.pool.take_promotions()
+        got = self.pool.acquire(req.prompt, self._pages_needed(req))
+        if got is None:
+            self._note_queued(req, "pages")
+            return False
+        page_ids, matched = got
+        promoted = self.pool.take_promotions()
         now = time.monotonic()
         req.t_admit = now
         W = req.bucket
         L = req.prompt.size
-        ps = self.page_size if self.paged else 0
-        pset = req.kv_import if self.paged else None
+        ps = self.page_size
+        pset = req.kv_import
         # Restored pages: logical page index -> bundle, contiguous from
         # where HBM matching broke — tier promotions first, then shipped
         # pages extend the run. A failed tier fetch truncates the run;
@@ -1697,7 +1628,6 @@ class ServeEngine:
         full_ship = exact and pset.tok0 is not None and covered * ps >= L
         feed_decode = (
             not full_ship
-            and self.paged
             and (pset is not None or self.pool.tier is not None)
             and covered >= 1
             and covered * ps >= L - 1
@@ -1705,14 +1635,12 @@ class ServeEngine:
         mode = (
             "ship" if full_ship else "feed" if feed_decode else "prefill"
         )
-        table_row = write_mask = None
-        if self.paged:
-            table_row = np.zeros((self.pages_per_slot,), np.int32)
-            table_row[: len(page_ids)] = page_ids
-            write_mask = np.zeros((self.pages_per_slot,), bool)
-            write_mask[matched: len(page_ids)] = True
-            for j in restored:
-                write_mask[j] = False  # restored bytes, not prefill's
+        table_row = np.zeros((self.pages_per_slot,), np.int32)
+        table_row[: len(page_ids)] = page_ids
+        write_mask = np.zeros((self.pages_per_slot,), bool)
+        write_mask[matched: len(page_ids)] = True
+        for j in restored:
+            write_mask[j] = False  # restored bytes, not prefill's
         n_host = sum(1 for s in restore_src.values() if s == "host")
         n_disk = sum(1 for s in restore_src.values() if s == "disk")
         if n_host or n_disk:
@@ -1769,14 +1697,14 @@ class ServeEngine:
         span.set(
             slot=slot, bucket=W, prompt_len=int(L),
             queue_wait_s=round(now - req.t_submit, 6),
-            pages=0 if page_ids is None else len(page_ids),
+            pages=len(page_ids),
             shared_pages=matched,
             **self._tid(req),
         )
         self._trace(
             req, "admitted", slot=slot, bucket=W,
             queue_wait_s=round(now - req.t_submit, 6),
-            pages=0 if page_ids is None else len(page_ids),
+            pages=len(page_ids),
             shared_pages=matched, **extra_trace,
         )
         if first is not None:
@@ -1788,37 +1716,26 @@ class ServeEngine:
             obs.goodput_live().note_serve_tokens(1)
             obs.counter("serve.tokens", 1)
             if done:
-                if page_ids is not None:
-                    self.pool.release(page_ids)
+                self.pool.release(page_ids)
                 self._finish(
                     req, "eos" if req.max_new_tokens > 1 else "budget"
                 )
                 return True
-        if self.paged:
-            if mode == "prefill":
-                # Pad-stripped page insert: real prompt kv moves to
-                # logical [0, L); shared prefix pages and restored pages
-                # are masked OFF the write.
-                with self.ledger.bucket("insert"), obs.span(
-                    "serve.insert", request=req.id
-                ):
-                    self._cache = self._insert(
-                        self._cache, row_cache, jnp.asarray(table_row),
-                        jnp.int32(W - L), jnp.asarray(write_mask),
-                    )
-            self._page_table[slot] = table_row
-            self._slot_pages[slot] = list(page_ids)
-            self._lengths[slot] = L if mode != "feed" else L - 1
-            self._pads[slot] = 0
-        else:
+        if mode == "prefill":
+            # Pad-stripped page insert: real prompt kv moves to
+            # logical [0, L); shared prefix pages and restored pages
+            # are masked OFF the write.
             with self.ledger.bucket("insert"), obs.span(
                 "serve.insert", request=req.id
             ):
                 self._cache = self._insert(
-                    self._cache, row_cache, np.int32(slot)
+                    self._cache, row_cache, jnp.asarray(table_row),
+                    jnp.int32(W - L), jnp.asarray(write_mask),
                 )
-            self._lengths[slot] = W
-            self._pads[slot] = W - L
+        self._page_table[slot] = table_row
+        self._slot_pages[slot] = list(page_ids)
+        self._lengths[slot] = L if mode != "feed" else L - 1
+        self._pads[slot] = 0
         self._slots[slot] = req
         self._tok[slot] = (
             first if first is not None else int(req.prompt[L - 1])
@@ -1870,12 +1787,12 @@ class ServeEngine:
         periodic refresh) — a long idle server must not flood the event
         stream."""
         pool = self.pool
-        tier = None if pool is None else pool.tier
+        tier = pool.tier
         state = (
             len(self._queue),
             self.live_slots,
-            None if pool is None else pool.free_pages,
-            None if pool is None else pool.prefix_hits,
+            pool.free_pages,
+            pool.prefix_hits,
             None if tier is None else tier.pages_host,
             None if tier is None else tier.pages_disk,
         )
@@ -1895,9 +1812,8 @@ class ServeEngine:
                 "serve.slot_occupancy",
                 round(state[1] / self.max_slots, 4),
             )
-            if pool is not None:
-                obs.gauge("serve.pages_free", state[2])
-                obs.gauge("serve.prefix_hits", state[3])
+            obs.gauge("serve.pages_free", state[2])
+            obs.gauge("serve.prefix_hits", state[3])
             if tier is not None:
                 obs.gauge("serve.pages_host", state[4])
                 obs.gauge("serve.pages_disk", state[5])
@@ -1933,9 +1849,8 @@ class ServeEngine:
             slo_violations=self.ledger.slo_violations,
             slo_by_group=self.ledger.slo_by_group,
         )
-        if pool is not None:
-            led.note_serve_pages(pool.free_pages, pool.usable_pages)
-            led.note_serve_prefix(pool.prefix_hits, pool.prefix_lookups)
+        led.note_serve_pages(pool.free_pages, pool.usable_pages)
+        led.note_serve_prefix(pool.prefix_hits, pool.prefix_lookups)
         led.note_serve_role(self.role)
         if tier is not None:
             led.note_serve_tiers(
@@ -1951,7 +1866,7 @@ class ServeEngine:
         Returns emitted token count.
 
         Why masking composes: each slot row only ever attends within its
-        own cache row (paged: its own pages), and a program only
+        own pages, and a program only
         advances (and only writes real k/v for) rows live in ITS set — a
         masked-out row's garbage k/v writes land at its frozen
         ``lengths`` column onward, exactly where that row's OWN program
@@ -2009,15 +1924,13 @@ class ServeEngine:
                     )
                 else:
                     decode = self._decode_q if quant else self._decode
-                    args = [
-                        prm, self._cache, self._tok, self._lengths,
-                        self._pads, self._remaining, mask, self._eos,
-                    ]
-                    if self.paged:
-                        args.append(jnp.asarray(self._page_table))
                     (
                         self._cache, toks, tok, lengths, remaining, live
-                    ) = decode(*args)
+                    ) = decode(
+                        prm, self._cache, self._tok, self._lengths,
+                        self._pads, self._remaining, mask, self._eos,
+                        jnp.asarray(self._page_table),
+                    )
             # The host copy of the block's tokens IS the fence.
             with obs.span("serve.decode.fence"):
                 toks = np.asarray(toks)
@@ -2122,10 +2035,9 @@ class ServeEngine:
                 self._slots[s] = None
                 self._quant[s] = False
                 self._spec[s] = False
-                if self.paged:
-                    self.pool.release(self._slot_pages[s])
-                    self._slot_pages[s] = []
-                    self._page_table[s, :] = 0
+                self.pool.release(self._slot_pages[s])
+                self._slot_pages[s] = []
+                self._page_table[s, :] = 0
 
     @property
     def spec_accept_rate(self) -> float | None:
@@ -2137,8 +2049,8 @@ class ServeEngine:
 
     def step(self, admit: bool = True) -> bool:
         """One scheduler iteration: admit waiting requests into free
-        slots (chunked prefill; paged engines also need the page pool to
-        fit — a blocked head-of-queue request applies backpressure),
+        slots (chunked prefill; the page pool has to
+        fit too — a blocked head-of-queue request applies backpressure),
         then run one decode block per live group — (fp, int8) x (plain,
         speculative). Returns False when there was nothing to do."""
         self._iters += 1
@@ -2208,26 +2120,20 @@ class ServeEngine:
     # ---------------------------------------------------------------- warmup
     def _insert_warm_args(self):
         """The insert call's non-cache operands for a warmup/AOT pass:
-        paged engines write one full table of trash-routed pages (table
-        zeros + mask all-on exercises the real scatter against the
-        reserved page), contiguous engines take slot 0."""
-        if self.paged:
-            return (
-                jnp.zeros((self.pages_per_slot,), jnp.int32),
-                jnp.int32(0),
-                jnp.ones((self.pages_per_slot,), bool),
-            )
-        return (np.int32(0),)
+        one full table of trash-routed pages (table zeros + mask all-on
+        exercises the real scatter against the reserved page)."""
+        return (
+            jnp.zeros((self.pages_per_slot,), jnp.int32),
+            jnp.int32(0),
+            jnp.ones((self.pages_per_slot,), bool),
+        )
 
     def _decode_warm_args(self):
         """Dead-slot operands for one decode/verify warmup execution."""
-        args = [
+        return [
             self._tok, self._lengths, self._pads, self._remaining,
-            self._live, self._eos,
+            self._live, self._eos, jnp.asarray(self._page_table),
         ]
-        if self.paged:
-            args.append(jnp.asarray(self._page_table))
-        return args
 
     def warmup(self) -> dict[str, int]:
         """Compile-or-load every program the engine will ever run: the
@@ -2236,7 +2142,7 @@ class ServeEngine:
         compile cache (``maybe_enable_compile_cache``), so a server
         restart pays cache loads, not compiles. Executes each program once on
         dead-slot state (guaranteed jit-cache hits afterwards; the
-        garbage forwards are masked by ``live=False`` everywhere — paged
+        garbage forwards are masked by ``live=False`` everywhere — their
         writes land in the trash page) and restores a pristine cache.
         Returns ``compile_stats()``."""
         from tpuflow.dist import maybe_enable_compile_cache
@@ -2255,8 +2161,7 @@ class ServeEngine:
 
         with obs.span(
             "serve.warmup", buckets=len(self.buckets),
-            quant=self.quant_mode or "off", paged=self.paged,
-            spec=self.spec_draft,
+            quant=self.quant_mode or "off", spec=self.spec_draft,
         ) as sp:
             row_cache = None
             for w in self.buckets:
@@ -2370,19 +2275,17 @@ class ServeEngine:
                 )
         return stats
 
-    def aot_lower(
-        self, max_new_tokens: int = 128, ledger=None
-    ) -> int:
+    def aot_lower(self, ledger=None) -> int:
         """AOT-lower (``jit(...).lower(...).compile()``) every program
         signature this engine replays — decode block, speculative verify,
-        page/slot insert, and each admittable bucket's prefill, plus the
+        page insert, and each bucket's prefill, plus the
         int8 twins on a quant-armed engine — WITHOUT executing anything
         (row caches come from ``eval_shape``). With the persistent
         compile cache enabled the executables land on disk, which is
         ``tools/prewarm_cache.py``'s whole job; the engine owns the
         signature list so the tool can't drift from the programs the
-        scheduler actually runs. ``max_new_tokens`` prunes buckets the
-        run could never admit into. Returns the program count.
+        scheduler actually runs (every bucket can host a short-enough
+        prompt: capacity is the real length). Returns the program count.
 
         ``ledger`` (a ``tpuflow.obs.device.ProgramLedger``) records each
         compiled program's wall-s + cost/memory analysis as it lands —
@@ -2430,12 +2333,6 @@ class ServeEngine:
                 )
                 programs += 1
             for w in self.buckets:
-                # Contiguous rows admit on the PADDED width, so buckets
-                # the budget can never fit are dead signatures; paged
-                # capacity is the real length — every bucket can host a
-                # short-enough prompt.
-                if not self.paged and w + max_new_tokens > self.n_ctx:
-                    continue
                 chunk = normalize_prefill_chunk(self.prefill_chunk, w)
                 pf_args = (
                     prm,
@@ -2464,9 +2361,7 @@ class ServeEngine:
             programs += 1
         return programs
 
-    def collect_program_ledger(
-        self, max_new_tokens: int = 128, path: str | None = None
-    ):
+    def collect_program_ledger(self, path: str | None = None):
         """The engine's device ledger (ISSUE 15): AOT-compile every
         signature through :meth:`aot_lower` with a recording ledger,
         run the static HBM budget check, and persist ``programs.json``
@@ -2476,7 +2371,7 @@ class ServeEngine:
         ``compile_stats()`` is identical before and after — pinned by
         tests/test_serve.py. Returns the ledger."""
         ledger = _device.ProgramLedger(source="serve")
-        self.aot_lower(max_new_tokens=max_new_tokens, ledger=ledger)
+        self.aot_lower(ledger=ledger)
         ledger.budget_check()
         ledger.write(path)
         return ledger

@@ -27,7 +27,7 @@ import re
 # tests/ are walked too but individual rules scope themselves (e.g. the
 # raw-env-read ban exempts tests, the undeclared-name rule does not —
 # a typo'd monkeypatch.setenv would otherwise test nothing).
-DEFAULT_SCAN = ("tpuflow", "tools", "flows", "bench.py", "tests")
+DEFAULT_SCAN = ("tpuflow", "tools", "flows", "tests")
 
 _PRAGMA_RE = re.compile(
     r"#\s*tpulint:\s*disable=([a-z0-9_,\- ]+?)\s*(?:--\s*(.*\S))?\s*$"

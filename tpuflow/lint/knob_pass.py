@@ -16,7 +16,7 @@ Rules:
 - ``knob-undeclared``   — any exact ``TPUFLOW_*`` string literal (reads,
   writes, ``monkeypatch.setenv``, manifest env lists) naming a knob the
   registry does not declare. This is where a
-  ``TPUFLOW_SERVE_PAGED``-style typo dies at lint time instead of
+  ``TPUFLOW_SERVE_PAGE``-style typo dies at lint time instead of
   silently defaulting.
 - ``knob-readme-stale`` — the README's generated knob-table region is
   missing or does not match ``python -m tpuflow.utils.knobs

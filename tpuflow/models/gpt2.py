@@ -313,7 +313,7 @@ class Block(nn.Module):
     def _paged_attention(self, q, k, v, pad_lens, precision, slot_index,
                          page_table, layer=None):
         """Paged (block-pooled) KV-cache attention — the serving engine's
-        slot mode over a page pool (ISSUE 11).
+        per-row cache positions over a page pool (ISSUE 11).
 
         The cache is ONE (kv_pages, kv_page_size, H, D) pool per layer,
         shared by every slot; ``page_table`` (B, n_ctx/page_size) int32
@@ -341,17 +341,16 @@ class Block(nn.Module):
         dead slots (tables zeroed by the engine) route to the layer's
         page 0 — the reserved TRASH page nothing ever reads — so a page
         freed and re-allocated to a new request can never be corrupted
-        by its old slot's frozen garbage write (the paged analogue of
-        the slot engine's overwritten-at-own-column argument).
+        by its old slot's frozen garbage write.
 
         Reads: each row gathers its logical (n_ctx, H, D) view through
         its table (pages ``layer * kv_pages + table[b]``, one gather)
-        and runs the SAME masked attention as the contiguous slot path
-        — columns ``[pad_lens[b], slot_index[b] + t]`` only.
+        and runs masked attention over it — columns
+        ``[pad_lens[b], slot_index[b] + t]`` only.
         Masked columns may be backed by the trash page or a stale page:
         their scores are the -1e30 constant either way, so the gathered
-        garbage never reaches a real query (and the gathered bytes equal
-        the contiguous row read — paging moves capacity accounting, not
+        garbage never reaches a real query (and the gathered bytes are a
+        whole ``n_ctx`` row's — paging moves capacity accounting, not
         the attention's HBM traffic).
         """
         cfg = self.config
@@ -423,15 +422,11 @@ class Block(nn.Module):
         ``< pad_lens[b]`` are invisible to every query of row b (ragged
         prompt batches; tpuflow.infer.generate ``prompt_lens``).
 
-        ``slot_index`` (B,) switches to PER-ROW cache positions (the
-        continuous-batching serving engine, tpuflow.infer.serve): row b's
-        k/v land at column ``slot_index[b]`` via a vmapped update, and
-        row b's queries see columns ``[pad_lens[b], slot_index[b] + t]``
-        only — so sequences of different lengths admit, decode, and evict
-        independently inside ONE compiled program, and a reused slot's
-        stale columns beyond the new sequence's frontier stay invisible.
-        The scalar ``cache_index`` is not consulted or advanced: the
-        engine owns per-slot lengths.
+        ``slot_index`` (B,) with ``page_table`` switches to PER-ROW cache
+        positions in the page pool (the continuous-batching serving
+        engine, tpuflow.infer.serve; ``_paged_attention``). The scalar
+        ``cache_index`` is then not consulted or advanced: the engine
+        owns per-slot lengths.
 
         Multi-token calls: a fresh-cache prefill (``start == 0``, no pads)
         takes the T x T fast path through the pluggable attention dispatch;
@@ -442,7 +437,13 @@ class Block(nn.Module):
         """
         cfg = self.config
         B, T, H, D = q.shape
-        if slot_index is not None and page_table is not None:
+        if slot_index is not None:
+            if page_table is None:
+                raise ValueError(
+                    "slot_index passed without page_table: per-row cache "
+                    "positions live in the page pool alone (the "
+                    "contiguous slot rows went with PR 32)"
+                )
             if cfg.kv_pages <= 0:
                 raise ValueError(
                     "page_table passed but the config declares no page "
@@ -470,32 +471,6 @@ class Block(nn.Module):
         idx = self.variable(
             "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
         )
-        if slot_index is not None:
-            def row_write(cache_row, new_row, s):
-                return jax.lax.dynamic_update_slice(
-                    cache_row, new_row, (s, 0, 0)
-                )
-
-            with jax.named_scope("kv_write"):
-                ck.value = jax.vmap(row_write)(
-                    ck.value, k.astype(cdt), slot_index
-                )
-                cv.value = jax.vmap(row_write)(
-                    cv.value, v.astype(cdt), slot_index
-                )
-            q_pos = slot_index[:, None] + jnp.arange(T)[None, :]  # (B, T)
-            k_pos = jnp.arange(cfg.n_ctx)
-            valid = (
-                k_pos[None, None, None, :] <= q_pos[:, None, :, None]
-            )  # (B, 1, T, n_ctx)
-            if pad_lens is not None:
-                valid = valid & (
-                    k_pos[None, None, None, :]
-                    >= pad_lens[:, None, None, None]
-                )
-            return _masked_attention(
-                q, ck.value, cv.value, valid, precision=precision
-            )
         start = idx.value
         with jax.named_scope("kv_write"):
             ck.value = jax.lax.dynamic_update_slice(
@@ -525,15 +500,10 @@ class Block(nn.Module):
             # the pluggable dispatch when dense, the left-padded masked
             # form when ragged — instead of softmaxing over n_ctx - T dead
             # cache columns; warm-cache (chunked) prefill takes the general
-            # cache path. Runtime branch: start is traced. Decode mode is
-            # never differentiated, so 'auto' dispatch uses the FWD-ONLY
-            # flash crossover (needs_bwd=False): prefill gets the flash
-            # win from the much lower fwd threshold even at sequence
-            # lengths where the backward would have lost to XLA.
+            # cache path. Runtime branch: start is traced.
             fast = (
                 (lambda: attention(
-                    q, k, v, causal=True, impl=cfg.attn_impl,
-                    needs_bwd=False,
+                    q, k, v, causal=True, impl=cfg.attn_impl
                 ).astype(q.dtype))
                 if pad_lens is None
                 else (lambda: _left_pad_attention(q, k, v, pad_lens))
@@ -581,16 +551,14 @@ class GPT2(nn.Module):
         compute dtype (same-width in every decode strategy, so no
         width-dependent rounding; and it is the compute-bound decode
         call) while verify chunks and single-token steps run in
-        ``decode_dtype``. ``slot_index`` (B,) int32 switches decode mode
-        to PER-ROW cache positions (the serving engine's slot-based KV
-        cache): row b writes/reads at its own column, positions come
-        from ``slot_index - pad_lens``, and the model-level ``pos_index``
-        is neither consulted nor advanced. ``page_table``
-        (B, n_ctx/kv_page_size) int32 further switches slot mode to the
-        PAGED cache pool (``kv_pages``/``kv_page_size`` config fields):
-        logical columns route through the table onto shared pool pages
-        (Block._paged_attention) — positions and masking are identical
-        to contiguous slot mode."""
+        ``decode_dtype``. ``slot_index`` (B,) int32 with ``page_table``
+        (B, n_ctx/kv_page_size) int32 switches decode mode to PER-ROW
+        cache positions in the PAGED cache pool (the serving engine;
+        ``kv_pages``/``kv_page_size`` config fields): row b writes/reads
+        at its own logical column, routed through the table onto shared
+        pool pages (Block._paged_attention), positions come from
+        ``slot_index - pad_lens``, and the model-level ``pos_index`` is
+        neither consulted nor advanced."""
         cfg = self.config
         B, T = tokens.shape
         if pad_lens is not None:

@@ -558,10 +558,8 @@ CATALOG: dict[str, tuple[str, str]] = {
     # ----------------------------------------------------------------- ops
     "ops.flash_bwd_fused": (
         "event",
-        "a differentiated flash-attention call traced the FUSED two-"
-        "kernel backward (ISSUE 10; seq/heads/causal/blocks) — absent "
-        "when TPUFLOW_FLASH_BWD=split|blockwise selected a fallback, so "
-        "a run's backward provenance is auditable from the stream",
+        "a differentiated flash-attention call traced the fused one-"
+        "kernel backward (seq/heads/causal/blocks)",
     ),
     # ---------------------------------------------------------------- dist
     "dist.mesh_generation": (
@@ -721,12 +719,6 @@ CATALOG: dict[str, tuple[str, str]] = {
         "the live metrics endpoint started serving /metrics (Prometheus "
         "text) + /status (JSON) on gang member 0 "
         "(TPUFLOW_OBS_HTTP_PORT); carries the bound port",
-    ),
-    # ------------------------------------------------------------ warnings
-    "warn.flash_min_seq_malformed": (
-        "event",
-        "TPUFLOW_FLASH_MIN_SEQ env var was set but unparsable; the "
-        "threshold fell through to the tuning file / shipped default",
     ),
 }
 

@@ -1,14 +1,13 @@
 """Run registry + cross-run regression ledger (ISSUE 16).
 
-Every training run, serving run, and ``bench.py`` invocation appends ONE
-schema-versioned headline record to the ``TPUFLOW_REGISTRY_PATH`` JSONL
-— goodput fraction, tokens/s, TTFT/ITL percentiles (from the mergeable
-buckets when the snapshot carries them), ``hbm_peak_frac``, the bench
-digest keys the exit-3/4/5/6 gates read, git commit + dirty flag, and
-platform provenance. The sensors existed (PRs 13–15); this file is the
-memory that lets anything *compare* them: five rounds of BENCH history
-become queryable the moment the one-shot importer backfills
-BENCH_r*.json captures.
+Every training run and serving run appends ONE schema-versioned
+headline record to the ``TPUFLOW_REGISTRY_PATH`` JSONL — goodput
+fraction, tokens/s, TTFT/ITL percentiles (from the mergeable buckets
+when the snapshot carries them), ``hbm_peak_frac``, git commit + dirty
+flag, and platform provenance. The sensors existed (PRs 13–15); this
+file is the memory that lets anything *compare* them. The one-shot
+importer backfills ``BENCH_r*.json`` captures of the benchmark this
+repository had before ``benchmark/`` (none are committed; ROADMAP.md D4).
 
 Durability contract:
 
@@ -28,8 +27,7 @@ Regression math is the PR 15 detector idiom reused host-side: the last
 value vs the trailing window's **median + MAD** (``TPUFLOW_REGISTRY_
 WINDOW`` / ``TPUFLOW_REGISTRY_ZMADS``), so one jittery round does not
 read as a regression and a real cliff does. ``python -m tpuflow.obs
-trend`` / ``compare`` render it jax-free; ``bench.py`` renders the
-"vs last 5 runs" verdict table from the same rows.
+trend`` / ``compare`` render it jax-free.
 """
 
 from __future__ import annotations
@@ -45,7 +43,7 @@ from tpuflow.utils import knobs
 
 SCHEMA = 1
 
-# Registry filename bench.py defaults to (beside its BENCH_r*.json
+# Registry filename the backfill CLI defaults to (beside the BENCH_r*.json
 # records) when TPUFLOW_REGISTRY_PATH is unset.
 DEFAULT_BASENAME = "TPU_REGISTRY.jsonl"
 
@@ -550,7 +548,7 @@ def _fmt(v: Any) -> str:
 
 
 def format_rows(rows: list[dict], columns: tuple[str, ...]) -> str:
-    """Aligned text table (the CLI / bench verdict rendering)."""
+    """Aligned text table (the CLI rendering)."""
     headers = columns
     body = [[_fmt(r.get(c)) for c in headers] for r in rows]
     widths = [
@@ -561,42 +559,3 @@ def format_rows(rows: list[dict], columns: tuple[str, ...]) -> str:
     for b in body:
         lines.append("  ".join(c.ljust(w) for c, w in zip(b, widths)))
     return "\n".join(lines)
-
-
-def bench_append_and_verdict(
-    compact: dict, repo: str, log=print
-) -> list[dict]:
-    """bench.py's registry hook: append this invocation's digest to the
-    registry (knob path, else ``TPU_REGISTRY.jsonl`` beside the bench
-    records) and render the auto "vs last N runs" verdict table from
-    the trailing history. Returns the verdict rows."""
-    path = registry_path(os.path.join(repo, DEFAULT_BASENAME))
-    history = read_registry(path)
-    metrics, prov = bench_metrics(compact)
-    commit, dirty = git_stamp(repo)
-    rec = make_record(
-        "bench",
-        metrics,
-        source="bench.py",
-        platform=prov.get("platform"),
-        git=commit,
-        git_dirty=dirty,
-    )
-    append_record(path, rec)
-    window = knobs.get_int("TPUFLOW_REGISTRY_WINDOW")
-    rows = verdict_rows(history, metrics)
-    judged = [r for r in rows if r["verdict"] not in ("absent",)]
-    if judged:
-        log(f"[bench] vs last {min(len(history), window)} runs ({path}):")
-        for line in format_rows(
-            judged, ("metric", "last", "median", "delta", "z", "verdict")
-        ).splitlines():
-            log(f"[bench]   {line}")
-        regressed = [r["metric"] for r in judged
-                     if r["verdict"] == "REGRESSED"]
-        if regressed:
-            log(
-                "[bench] REGRESSED vs trailing median+MAD: "
-                + ", ".join(regressed)
-            )
-    return rows

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from tpuflow.utils import knobs
 
 
 def xla_attention(q, k, v, *, causal: bool = True):
@@ -46,209 +45,55 @@ def xla_attention(q, k, v, *, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-# Shipped defaults for the auto-dispatch thresholds, from chip calls 93 and
-# 95 of PR 31 (PERF.md §6; TPU v5e, bf16, causal, heads of 64, the kernels
-# against `xla_attention` in one process). The crossover is not a length:
-# XLA's attention is fast while the (B, H, T, T) scores stay on the chip
-# and slow once they go through HBM, and the kernels' time follows B·H·T.
-# Up to 21M score elements XLA is level or ahead (forward, device ms a
-# call, XLA against the kernels: B·H = 20 at T=1024 0.071 against 0.071,
-# B·H = 80 at T=512 0.072 against 0.115, at T=256 0.017 against 0.054;
-# forward + backward at B·H = 80, T=256 0.041 against 0.110); from 42M the
-# kernels are ahead (forward / forward + backward at 8,192 tokens of 20
-# heads: T=256 0.61 / 1.49 against 0.51 / 1.22, T=512 1.09 / 3.44 against
-# 0.55 / 1.30, T=1024 2.05 / 6.64 against 0.63 / 1.59, T=2048 4.09 / 12.6
-# against 1.04 / 2.67; B·H = 48 at T=1024 0.59 against 0.16 forward). A
-# threshold on T cannot say that, so both defaults are the shortest row at
-# which no measured shape lost: 1,024 (level at one row of 12 to 20 heads,
-# 3.2 to 4.2 times ahead from four). They stay two names because the
-# tuning file and the two environment variables set them apart.
-_DEFAULT_FLASH_MIN_SEQ = 1024
-_DEFAULT_FLASH_MIN_SEQ_FWD = 1024
-_flash_tuning_cache: dict | None = None
-_warned_malformed_env = False
-_warned_malformed_tuning = False
-
-
-def flash_tuning_path() -> str:
-    """Where ``bench.py`` persists the measured flash/XLA crossovers on
-    this host: ``$TPUFLOW_HOME/flash_tuning.json`` with
-    ``{"flash_min_seq": T_fwdbwd, "flash_min_seq_fwd": T_fwd,
-    "flash_min_seq_bwd": T_bwdonly}``."""
-    import os
-
-    home = knobs.raw(
-        "TPUFLOW_HOME", os.path.join(os.path.expanduser("~"), ".tpuflow")
-    )
-    return os.path.join(home, "flash_tuning.json")
-
-
-def _flash_tuning() -> dict:
-    import json
-
-    global _flash_tuning_cache
-    if _flash_tuning_cache is None:
-        try:
-            with open(flash_tuning_path()) as f:
-                _flash_tuning_cache = json.load(f)
-        except (OSError, ValueError):
-            _flash_tuning_cache = {}
-    return _flash_tuning_cache
-
-
-def _flash_min_seq(*, needs_bwd: bool = True) -> int:
-    """Dispatch threshold resolution, independently for the fwd+bwd
-    (training) and fwd-only (inference) paths: the env var
-    (TPUFLOW_FLASH_MIN_SEQ / TPUFLOW_FLASH_MIN_SEQ_FWD) beats the host's
-    measured tuning file beats the shipped default. A MALFORMED env var
-    falls through to the tuning-file lookup (the host's measured
-    crossover — strictly better information than the shipped constant)
-    and warns once per process, through the obs stream when one is live.
-    An unset fwd-only env var falls back to only its own sources; the
-    two paths never borrow each other's thresholds. The file read is
-    cached per process (this runs at trace time).
-
-    The training path additionally consults the fitted BWD-ONLY
-    crossover (ISSUE 10 satellite; bench's T512/T2048 ``jax.vjp`` timing
-    split, persisted as ``flash_min_seq_bwd``): the effective fwd+bwd
-    threshold is the max of the valid measured entries — below the
-    measured backward-kernel crossover the bwd kernels are a MEASURED
-    loss, so fwd+bwd dispatch must pick XLA there even when the fwd+bwd
-    composition point is absent or was discarded as timing-suspect. A
-    malformed tuning entry (present but not a positive integer) is
-    ignored with a once-per-process warning; no valid entry at all falls
-    back to the shipped default."""
-    import os
-
-    global _warned_malformed_env
-    env_name = (
-        "TPUFLOW_FLASH_MIN_SEQ" if needs_bwd else "TPUFLOW_FLASH_MIN_SEQ_FWD"
-    )
-    # tpulint: disable=knob-dynamic -- env_name is one of two literal
-    # TPUFLOW_FLASH_MIN_SEQ* names selected two lines up; both are
-    # declared and the string-literal rule validates them.
-    env = knobs.raw(env_name)
-    if env is not None:
-        try:
-            return int(env)
-        except ValueError:
-            if not _warned_malformed_env:
-                _warned_malformed_env = True
-                import warnings
-
-                from tpuflow import obs
-
-                warnings.warn(
-                    f"{env_name}={env!r} is not an integer; "
-                    "falling through to the tuning file / default",
-                    stacklevel=2,
-                )
-                obs.event("warn.flash_min_seq_malformed", value=env)
-            # fall through to the measured tuning file below
-    keys = (
-        ("flash_min_seq", "flash_min_seq_bwd")
-        if needs_bwd
-        else ("flash_min_seq_fwd",)
-    )
-    fitted = [_tuning_entry(k) for k in keys]
-    fitted = [v for v in fitted if v is not None]
-    if fitted:
-        return max(fitted)
-    return (
-        _DEFAULT_FLASH_MIN_SEQ if needs_bwd else _DEFAULT_FLASH_MIN_SEQ_FWD
-    )
-
-
-def _tuning_entry(key: str) -> int | None:
-    """One tuning-file entry, validated: a positive int passes through,
-    an absent key is None, and a MALFORMED value (bench never writes one,
-    but a hand-edited file might) is ignored with the same
-    once-per-process warning discipline as the env path — a typo'd
-    tuning file must degrade to the shipped defaults, never crash a
-    trace or silently dispatch off a garbage threshold."""
-    global _warned_malformed_tuning
-    v = _flash_tuning().get(key)
-    if v is None:
-        return None
-    if isinstance(v, bool) or not isinstance(v, int) or v <= 0:
-        if not _warned_malformed_tuning:
-            _warned_malformed_tuning = True
-            import warnings
-
-            from tpuflow import obs
-
-            warnings.warn(
-                f"flash tuning entry {key}={v!r} is not a positive "
-                "integer; ignoring it",
-                stacklevel=3,
-            )
-            obs.event("warn.flash_min_seq_malformed", value=repr(v))
-        return None
-    return v
+# The shortest row at which `auto` takes the flash kernels, from chip calls
+# 93 and 95 of PR 31 (PERF.md §6; TPU v5e, bf16, causal, heads of 64, the
+# kernels against `xla_attention` in one process). The crossover is not a
+# length: XLA's attention is fast while the (B, H, T, T) scores stay on the
+# chip and slow once they go through HBM, and the kernels' time follows
+# B·H·T. Up to 21M score elements XLA is level or ahead, from 42M the kernels
+# are (forward + backward at 8,192 tokens of 20 heads: T=512 3.44 against
+# 1.30 ms, T=1024 6.64 against 1.59). A threshold on T cannot say that, so
+# this is the shortest row at which no measured shape lost, forward alone or
+# with its backward: level at one row of 12 to 20 heads, 3.2 to 4.2 times
+# ahead from four.
+_FLASH_MIN_SEQ = 1024
 
 
 def resolve_attention_impl(
-    impl: str, seq_len: int, *, needs_bwd: bool = True,
+    impl: str, q_shape, tk: int, *, causal: bool = True,
     backend: str | None = None,
 ) -> str:
-    """Resolve ``impl='auto'`` to a concrete implementation for one
-    (backend, seq_len, path) combination — factored out of ``attention``
-    so the dispatch choice is unit-testable without a TPU. Non-'auto'
-    impls pass through unchanged. ``needs_bwd`` selects which measured
-    crossover applies: the fwd+bwd threshold for calls that will be
-    differentiated (training), the fwd-only threshold for pure-inference
-    forwards (decode prefill) — see ``_flash_min_seq``."""
+    """The whole choice of attention kernel. A named ``impl`` passes
+    through. ``'auto'`` is ``'flash'`` exactly when the kernels can run
+    this call where it is traced and are known to win there, else
+    ``'xla'``: on the TPU backend (off it flash is interpret mode, for
+    tests only); on one device (a Mosaic kernel is opaque to the
+    partitioner, and jax refuses to lower one inside a program partitioned
+    over several devices: "wrap the call in a shard_map"); on a row of at
+    least ``_FLASH_MIN_SEQ`` positions; at a shape the kernels tile
+    (``flash_tiles``: a 600-token prefill does not tile the 256-row score
+    tiles, gpt2-xl's 25 heads of 64 do not pair into 128-lane tiles).
+    ``q_shape`` is (B, Tq, H, D) and ``tk`` the K/V length. Resolved at
+    trace time: under jit the choice is part of the compiled program."""
     if impl != "auto":
         return impl
     backend = backend if backend is not None else jax.default_backend()
-    if backend == "tpu" and seq_len >= _flash_min_seq(needs_bwd=needs_bwd):
-        return "flash"
-    return "xla"
-
-
-def _flash_runs(q_shape, tk: int, causal: bool) -> bool:
-    """Whether the flash kernels can run this call where it is traced:
-    'auto' never picks a kernel that cannot. Two things stop them. The
-    shape (``flash_tiles``): a 600-token prefill does not tile the 256-row
-    score tiles, gpt2-xl's 25 heads of 64 do not pair into 128-lane tiles.
-    And the placement: a Mosaic kernel is opaque to the partitioner, and
-    jax refuses to lower one inside a program partitioned over several
-    devices ("wrap the call in a shard_map"), so under a mesh of more
-    than one device 'auto' stays with XLA, as it did on four chips before
-    ISSUE 31 (PERF.md §7: the four-chip issue's first change)."""
+    _, tq, h, d = q_shape
+    if backend != "tpu" or tq < _FLASH_MIN_SEQ:
+        return "xla"
     from tpuflow.ops.flash_attention import flash_tiles
     from tpuflow.parallel.sharding import active_mesh
 
     mesh = active_mesh()
     if mesh is not None and mesh.size > 1:
-        return False
-    _, tq, h, d = q_shape
-    return flash_tiles(tq, tk, h, d, causal=causal)
+        return "xla"
+    return "flash" if flash_tiles(tq, tk, h, d, causal=causal) else "xla"
 
 
-def attention(q, k, v, *, causal: bool = True, impl: str = "xla",
-              needs_bwd: bool = True):
-    """Dispatch to the selected implementation (see module docstring).
-
-    ``impl='auto'`` picks by measured crossover: flash only on TPU, only
-    for a call the kernels can run (``_flash_runs``: a shape they tile,
-    on one device), at T >= the resolved threshold — the
-    fwd+bwd threshold when ``needs_bwd`` (TPUFLOW_FLASH_MIN_SEQ /
-    tuning-file ``flash_min_seq`` / 1024), else the fwd-only threshold
-    (TPUFLOW_FLASH_MIN_SEQ_FWD / ``flash_min_seq_fwd`` / 1024); the
-    defaults are the chip calls of PR 31, above — and XLA everywhere
-    else; CPU always takes XLA (flash there is interpret-mode, for tests
-    only).
-    """
-    if impl == "auto":
-        # NB: resolved at trace time — under jit the choice is baked into
-        # the compiled program for each shape; changing the env var after
-        # compilation does not retune existing executables.
-        impl = resolve_attention_impl(
-            "auto", q.shape[1], needs_bwd=needs_bwd
-        )
-        if impl == "flash" and not _flash_runs(q.shape, k.shape[1], causal):
-            impl = "xla"
+def attention(q, k, v, *, causal: bool = True, impl: str = "xla"):
+    """Dispatch to the selected implementation (see the module docstring;
+    ``impl='auto'``: ``resolve_attention_impl``)."""
+    impl = resolve_attention_impl(impl, q.shape, k.shape[1], causal=causal)
     if impl == "xla":
         fn = xla_attention
     elif impl == "flash":

@@ -10,10 +10,7 @@ Two tiers with identical numerics:
   logsumexp); ONE backward kernel recomputes P a tile at a time and feeds
   dq, dk and dv from it — the flash-style compute-for-memory trade. Block
   sizes and the heads a program owns follow from the shape alone (see the
-  kernels' section comment). ``TPUFLOW_FLASH_BWD`` selects the backward:
-  ``fused`` (default; the ISSUE 31 kernel), ``split`` (the older dq and
-  dk/dv pair, kept as the on-chip regression reference), ``blockwise``
-  (the pure-JAX recompute VJP).
+  kernels' section comment).
 
 The reference has no attention anywhere (its model is an image MLP,
 my_ray_module.py:94-112); these exist for the GPT-2 acceptance config and
@@ -28,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from tpuflow.utils import knobs
 
 _NEG_INF = -1e30
 
@@ -120,8 +116,9 @@ def _reference_attention(q, k, v, *, causal: bool):
 #   dk += dSᵀ·q are plain products (only p·v and dS·k take a transposed
 #   operand).
 # - The backward is ONE kernel: per tile it recomputes p from (q, k, lse)
-#   once and feeds all three gradients (five products; the two-kernel
-#   design recomputed p in each, seven). D is one XLA reduction outside.
+#   once and feeds all three gradients (five products; a dq kernel beside
+#   a dk/dv kernel recomputes p in each, seven). D is one XLA reduction
+#   outside.
 # - The compiler schedules a program's tiles in the order they are
 #   written, so both kernels issue the NEXT tile's score products before
 #   the current tile's elementwise work: the MXU and the VPU overlap
@@ -449,7 +446,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _flash_bwd_fused(q, k, v, o, lse, g, causal: bool, interpret: bool):
-    """The fused one-kernel backward (default; see the section comment).
+    """The one-kernel backward (see the section comment).
     ``lse`` is the forward's (B, H, Tq) residual, ``g`` the cotangent of
     o."""
     B, Tq, H, D = q.shape
@@ -509,202 +506,6 @@ def _flash_bwd_fused(q, k, v, o, lse, g, causal: bool, interpret: bool):
     )
 
 
-# ------------------------------------------- the split (reference) backward
-# TPUFLOW_FLASH_BWD=split: the pre-ISSUE-10 pair — a dq kernel with k
-# innermost and a dk/dv kernel with q innermost over (B·H, T, D) operands,
-# D = rowsum(dO ∘ O) recomputed from (o, do) inside EVERY block visit of
-# both. Kept one release as the on-chip regression reference the bench
-# flash leg races the fused kernel against.
-
-
-def _masked_scores(q_ref, k_ref, iq, ik, *, scale, causal, block_q, block_k):
-    """Scaled (block_q, block_k) f32 score tile with the causal mask applied.
-
-    Shared by the forward and both backward kernels so the mask/scale
-    semantics cannot diverge between them. MXU feeds stay in the input dtype
-    (bf16 multiplies at full MXU rate); accumulation is f32 via
-    preferred_element_type.
-    """
-    s = jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    if causal:
-        q_pos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    return s
-
-
-def _row_delta(o_ref, do_ref):
-    """D_i = rowsum(dO ∘ O) for the current q block → (block_q, 1) f32."""
-    return jnp.sum(
-        do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )
-
-
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-                   dq_scr, *, scale, causal, block_q, block_k):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    def _compute():
-        s = _masked_scores(
-            q_ref, k_ref, iq, ik,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        )
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - _row_delta(o_ref, do_ref)) * scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        @pl.when(ik * block_k <= iq * block_q + block_q - 1)
-        def _maybe():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(ik == nk - 1)
-    def _final():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
-                    dv_ref, dk_scr, dv_scr, *, scale, causal, block_q,
-                    block_k):
-    ik = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    def _compute():
-        s = _masked_scores(
-            q_ref, k_ref, iq, ik,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        )
-        p = jnp.exp(s - lse_ref[0][:, :1])  # (block_q, block_k)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - _row_delta(o_ref, do_ref)) * scale
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        # A q block entirely before this k block contributes nothing.
-        @pl.when(iq * block_q + block_q - 1 >= ik * block_k)
-        def _maybe():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(iq == nq - 1)
-    def _final():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _flash_bwd_split(q, k, v, o, lse, g, causal, block_q, block_k,
-                     interpret):
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    scale = 1.0 / (D ** 0.5)
-    BH = B * H
-
-    def flat(x, T):
-        return x.transpose(0, 2, 1, 3).reshape(BH, T, D)
-
-    qf, kf, vf = flat(q, Tq), flat(k, Tk), flat(v, Tk)
-    of, gf = flat(o, Tq), flat(g, Tq)
-    # The forward's residual is a (BH, Tq) row; these kernels read it
-    # broadcast over 128 lanes.
-    lse = jnp.broadcast_to(lse[..., None], (*lse.shape, 128))
-
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    lse_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(BH, Tq // block_q, Tk // block_k),
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            q_spec,
-            q_spec,
-            lse_spec,
-        ],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(qf, kf, vf, of, gf, lse)
-
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    qi_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
-    lsei_spec = pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(BH, Tk // block_k, Tq // block_q),
-        in_specs=[qi_spec, k_spec, k_spec, qi_spec, qi_spec, lsei_spec],
-        out_specs=[k_spec, k_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Tk, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(qf, kf, vf, of, gf, lse)
-
-    def unflat(x, T):
-        return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
-
-    return unflat(dq, Tq), unflat(dk, Tk), unflat(dv, Tk)
-
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _flash(q, k, v, causal):
     return _flash_fwd(q, k, v, causal, _interpret())
@@ -720,24 +521,6 @@ def _flash_vjp_fwd(q, k, v, causal):
 
 def _flash_vjp_bwd(causal, res, g):
     q, k, v, o, lse = res
-    mode = knobs.raw("TPUFLOW_FLASH_BWD", "fused")
-    if mode == "blockwise":
-        # Fallback: recompute through the O(T)-memory blockwise path.
-        _, vjp = jax.vjp(
-            lambda q, k, v: blockwise_attention(q, k, v, causal=causal),
-            q, k, v,
-        )
-        return vjp(g)
-    interpret = _interpret()
-    if mode == "split":
-        # The pre-ISSUE-10 two-pass kernels at their own 256 blocks, kept
-        # as the regression reference (the bench flash leg races them
-        # against the fused kernel).
-        B, Tq, H, _ = q.shape
-        return _flash_bwd_split(
-            q, k, v, o, lse.reshape(B * H, Tq), g, causal,
-            min(_SUB, Tq), min(_SUB, k.shape[1]), interpret,
-        )
     # Trace-time marker: which compiled programs took the fused backward
     # (each jit trace of a differentiated flash call lands here once).
     from tpuflow import obs
@@ -747,7 +530,7 @@ def _flash_vjp_bwd(causal, res, g):
         "ops.flash_bwd_fused", seq=int(q.shape[1]), heads=int(q.shape[2]),
         causal=bool(causal), block_q=block_q, block_k=block_k,
     )
-    return _flash_bwd_fused(q, k, v, o, lse, g, causal, interpret)
+    return _flash_bwd_fused(q, k, v, o, lse, g, causal, _interpret())
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

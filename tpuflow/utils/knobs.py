@@ -3,7 +3,7 @@
 Eleven PRs grew ~90 env knobs across the tree, read by scattered
 ``os.environ`` calls and documented (or not) by hand-maintained README
 tables. Each of those hand-kept agreements rots silently: a typo'd name
-(``..._SERVE_PAGED`` misspelled ``..._SERVE_PAGE``) silently defaults, a
+(``..._SERVE_PAGES`` misspelled ``..._SERVE_PAGE``) silently defaults, a
 knob added in code never reaches the README, a README row outlives the
 code that read it. This module is the single source of truth the Orbax
 checkpoint-as-contract argument (PAPERS.md) asks for, applied to the
@@ -60,7 +60,6 @@ _A_OBS = "goodput--live-monitoring-runbook"
 _A_OBS_BASE = "observability"
 _A_SETUP = "setup"
 _A_FSDP = "gpt-2-fsdp-fully-sharded-training"
-_A_BENCH = "tests-and-benchmark"
 _A_DEPLOY = "deploy--schedule--trigger"
 _A_LINT = "static-analysis-runbook"
 
@@ -209,9 +208,6 @@ REGISTRY: dict[str, Knob] = dict(
            "fails at config time", "train", _A_STEP,
            choices=("full", "dots", "none"),
            default_doc="model preset's policy"),
-        _k("TPUFLOW_TRAIN_MODE", "str", None,
-           "`tpu` = the bench train child must run on the `tpu` backend "
-           "(it fails on any other); unset = the CPU", "train", _A_BENCH),
         # ----------------------------------------------------------- data
         _k("TPUFLOW_DATA_DIR", "path", None,
            "dataset root (IDX/corpus files); unset → synthetic "
@@ -328,20 +324,6 @@ REGISTRY: dict[str, Knob] = dict(
         _k("TPUFLOW_PROFILE_DIR", "path", None,
            "profiler output dir outside a flow run", "health", _A_HEALTH),
         # ------------------------------------------------------------ ops
-        _k("TPUFLOW_FLASH_MIN_SEQ", "int", None,
-           "min seq length where flash attention beats XLA for "
-           "forward+backward programs (auto-tuned; malformed → tuning "
-           "file with a once-per-process warning)", "ops", _A_STEP,
-           default_doc="1024 / tuned"),
-        _k("TPUFLOW_FLASH_MIN_SEQ_FWD", "int", None,
-           "min seq length where flash attention beats XLA for "
-           "forward-only (decode prefill) programs", "ops", _A_STEP,
-           default_doc="1024 / tuned"),
-        _k("TPUFLOW_FLASH_BWD", "enum", "fused",
-           "flash backward implementation (fused = one kernel for dq, dk "
-           "and dv; split = the two-kernel pair, regression reference)",
-           "ops", _A_STEP,
-           choices=("fused", "split", "blockwise")),
         _k("TPUFLOW_INT8_MATMUL", "enum", "auto",
            "int8 matmul impl: force xla/pallas, or auto-dispatch by "
            "shape", "quant", _A_QUANT,
@@ -369,9 +351,6 @@ REGISTRY: dict[str, Knob] = dict(
         _k("TPUFLOW_SERVE_QUANT", "str", None,
            "1/fused_native/weight_only arms per-request int8 decode",
            "serve", _A_SERVE, default_doc="off"),
-        _k("TPUFLOW_SERVE_PAGED", "bool", True,
-           "0 = keep the contiguous per-slot cache rows (regression "
-           "reference, kept one release)", "serve", _A_SERVE),
         _k("TPUFLOW_SERVE_PAGE_SIZE", "int", 16,
            "tokens per KV page (must divide n_ctx; env values that "
            "don't degrade to a divisor)", "serve", _A_SERVE),
@@ -561,11 +540,10 @@ REGISTRY: dict[str, Knob] = dict(
            "(default <obs_dir>/profile)", "device", _A_DEVICE),
         # --------------------------------------------------------- alerts
         _k("TPUFLOW_REGISTRY_PATH", "path", None,
-           "run-registry JSONL: every training run, serving run, and "
-           "bench.py invocation appends one schema-versioned headline "
-           "record here (unset = implicit run-end appends off; "
-           "bench.py defaults to TPU_REGISTRY.jsonl beside its "
-           "records)", "alerts", _A_ALERTS, default_doc="unset"),
+           "run-registry JSONL: every training run and serving run "
+           "appends one schema-versioned headline record here (unset = "
+           "implicit run-end appends off)", "alerts", _A_ALERTS,
+           default_doc="unset"),
         _k("TPUFLOW_REGISTRY_WINDOW", "int", 5,
            "trailing runs the trend/verdict median+MAD window spans",
            "alerts", _A_ALERTS),
@@ -633,40 +611,6 @@ REGISTRY: dict[str, Knob] = dict(
         _k("TPUFLOW_HEARTBEAT_FILE", "path", None,
            "member heartbeat file the supervisor assigns", "flow",
            _A_FLOW, internal=True),
-        # ---------------------------------------------------------- bench
-        _k("TPUFLOW_BENCH_TRAIN", "bool", True,
-           "0 = skip bench train legs", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_SERVE", "bool", True,
-           "0 = skip the serving bench leg", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_ROUTER", "bool", True,
-           "0 = skip the serving.router bench leg (3 in-process "
-           "replicas + one kill behind the front door; records "
-           "dropped_requests — must be 0 — and routed p99)", "bench",
-           _A_BENCH),
-        _k("TPUFLOW_BENCH_DISAGG", "bool", True,
-           "0 = skip the serving.disagg bench leg (TTFT cold vs "
-           "tier-hit vs cross-engine ship on one hot prompt set; "
-           "records ttft_tier_hit_vs_cold — on-chip gate < 1.0 "
-           "— per-tier hit rates, and ship exactness)", "bench",
-           _A_BENCH),
-        _k("TPUFLOW_BENCH_INT8", "bool", True,
-           "0 = skip the int8 bench legs", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_OVERLAP", "bool", True,
-           "0 = skip the save-overlap bench leg", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_OVERLAP_GB", "float", 3.4,
-           "save-overlap bench payload (GiB)", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_DISK", "bool", True,
-           "0 = skip the disk-ceiling bench probe", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_DISK_DIR", "path", None,
-           "disk-ceiling probe directory", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_DEVICE", "bool", False,
-           "1 = bench device-sharded checkpoint IO", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_DEVICES", "int", 8,
-           "device shards for the device-IO bench", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_GB", "float", 1.0,
-           "device-IO bench payload (GiB)", "bench", _A_BENCH),
-        _k("TPUFLOW_BENCH_DIR", "path", None,
-           "bench scratch/output directory", "bench", _A_BENCH),
         # ---------------------------------------------------------- flows
         _k("TPUFLOW_STORAGE", "path", "/tmp/tpuflow_run",
            "checkpoint storage path the example custom-Trainer flow "
@@ -693,7 +637,6 @@ _SUBSYSTEM_TITLES = (
     ("device", "Device observatory"),
     ("alerts", "Run registry & alerting"),
     ("testing", "Fault injection & testing"),
-    ("bench", "Benchmark"),
 )
 
 MARKDOWN_BEGIN = (
