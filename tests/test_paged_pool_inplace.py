@@ -9,6 +9,10 @@ into it or selects over it.
   loops have it in their carry; the insert has no pool-sized ``select_n``,
   ``dynamic_update_slice`` or ``dynamic_slice``. This is the guard that
   keeps a later refactor from putting the copies back.
+  A decode block traced at fewer rows or pages than the engine's all
+  (ISSUE 33) gathers ``(rows, width, H, D)`` and, at less than the full
+  width, no gather or product in it has the extent of ``n_ctx``: the
+  guard that keeps the full read from coming back.
 - **Exactness over both cache layouts** (one pool per block, and the
   layer-stacked pool the benchmark's cell serves): engine tokens equal
   solo ``generate()``; a shared prefix page is never rewritten by a
@@ -85,11 +89,11 @@ def _has_elems(var, n: int) -> bool:
     return shape is not None and math.prod(shape) == n
 
 
-def _cold_engine(**config):
+def _cold_engine(n_ctx=32, max_slots=2, **config):
     """An engine that is only traced (zero weights, nothing compiled),
     and the number of elements of one of its pool leaves."""
     model = GPT2(
-        GPT2Config.small_test(n_ctx=32, n_layer=3, dropout=0.0, **config)
+        GPT2Config.small_test(n_ctx=n_ctx, n_layer=3, dropout=0.0, **config)
     )
     params = jax.tree_util.tree_map(
         lambda s: jnp.zeros(s.shape, s.dtype),
@@ -99,7 +103,7 @@ def _cold_engine(**config):
         ),
     )
     eng = ServeEngine(
-        model, params, max_slots=2, buckets=[16], decode_block=2,
+        model, params, max_slots=max_slots, buckets=[16], decode_block=2,
         page_size=PAGE, speculative=2,
     )
     sizes = {
@@ -117,7 +121,8 @@ def test_layer_scan_carries_the_pool_and_scans_none_of_it(program, remat):
     table = jnp.asarray(eng._page_table)
     if program == "decode":
         jaxpr = jax.make_jaxpr(eng._decode)(
-            eng.params, eng._cache, *eng._decode_warm_args()
+            eng.params, eng._cache,
+            *eng._decode_warm_args(eng.max_slots, eng.pages_per_slot),
         )
         loops = 2  # the step scan and the layer scan inside it
     else:
@@ -155,6 +160,48 @@ def test_layer_scan_carries_the_pool_and_scans_none_of_it(program, remat):
                 if hasattr(v, "aval")
                 and (_has_elems(v, n_pool) or _has_elems(v, n_layer))
             ], eqn
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["blocks", "scan"])
+@pytest.mark.parametrize(
+    "rung", [(1, 16), (2, 24), (2, 32), (4, 32)], ids=str
+)
+def test_decode_variant_reads_its_rung_and_no_more(rung, scan_layers):
+    """The decode program traced at one shape (rows, pages), of the
+    engine's ladder or not: every page gather yields ``(rows, pages,
+    page_size, H, D)`` and, at less than the full width, no gather or
+    product touches an operand with an extent of ``n_ctx`` positions.
+    Sizes chosen so that no model width (4 heads of 32, 128 wide, 512
+    in the feed-forward and the vocabulary) equals a read width (128,
+    192 positions) or ``n_ctx`` (256)."""
+    n_ctx, slots = 256, 4
+    eng, _ = _cold_engine(
+        n_ctx=n_ctx, max_slots=slots, scan_layers=scan_layers
+    )
+    assert eng.decode_shapes == [(1, 16), (1, 32), (2, 32), (4, 32)]
+    rows, pages = rung
+    jaxpr = jax.make_jaxpr(eng._decode)(
+        eng.params, eng._cache, *eng._decode_warm_args(rows, pages)
+    )
+    width = pages * PAGE
+    gathered, extents = [], set()
+    for eqn in _eqns(jaxpr.jaxpr):
+        if eqn.primitive.name not in ("gather", "dot_general"):
+            continue
+        shapes = [
+            tuple(v.aval.shape)
+            for v in list(eqn.invars) + list(eqn.outvars)
+            if hasattr(v, "aval") and hasattr(v.aval, "shape")
+        ]
+        if eqn.primitive.name == "gather" and len(shapes[-1]) == 5:
+            gathered.append(shapes[-1])
+        # The position table (n_ctx, n_embd) is looked up by a gather
+        # too: its operand is two-dimensional and no part of the read.
+        extents.update(d for sh in shapes if len(sh) > 2 for d in sh)
+    layers = 1 if scan_layers else 3  # the layer scan's body is one block
+    assert gathered == [(rows, pages, PAGE, 4, 32)] * (2 * layers)
+    assert width in extents  # the scores' and the weighted sum's products
+    assert (n_ctx in extents) == (pages == n_ctx // PAGE)
 
 
 @pytest.mark.parametrize("scan_layers", [False, True], ids=["blocks", "scan"])

@@ -706,6 +706,177 @@ def test_page_boundary_lengths_exact(served):
     assert engine.pool.allocated_pages == 0
 
 
+# ------------------------------------- the decode ladder (ISSUE 33)
+@pytest.fixture(scope="module")
+def ladder_run():
+    """A warmed 4-slot engine over 256 positions in pages of 16 (decode
+    shapes 1 x 8, 1 x 16, 2 x 16 and 4 x 16 pages), and one run through
+    it in which the long request's row crosses the width rung while it
+    decodes and the live count crosses the row rungs upward (admissions)
+    and downward (completions). Returns what the cases below read."""
+    cfg = GPT2Config.small_test(n_ctx=256, dropout=0.0, scan_layers=True)
+    model = GPT2(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    eng = ServeEngine(
+        model, params, max_slots=4, buckets=[32, 128], decode_block=4,
+        page_size=16,
+    )
+    warm = eng.warmup()
+    rungs: list[tuple[int, int, int]] = []  # (live rows, rows, pages)
+    pick = eng._decode_rung
+
+    def spy(mask):
+        rows, pages = pick(mask)
+        rungs.append((int(mask.sum()), len(rows), pages))
+        return rows, pages
+
+    eng._decode_rung = spy
+    rng = np.random.default_rng(33)
+    plan = {  # name: (prompt length, tokens out)
+        "long": (110, 60), "first": (20, 6), "second": (30, 10),
+        "third": (9, 14), "fourth": (40, 7),
+    }
+    prompts = {
+        k: rng.integers(0, 512, size=L).astype(np.int32)
+        for k, (L, _) in plan.items()
+    }
+
+    def submit(k):
+        return eng.submit(prompts[k], max_new_tokens=plan[k][1])
+
+    reqs = {"long": submit("long")}
+    for _ in range(7):  # alone: 110 + 4 fits 128 positions, 126 + 4 not
+        eng.step()
+    reqs["first"] = submit("first")
+    while not reqs["first"].done:  # two live, then one
+        eng.step()
+    for k in ("second", "third", "fourth"):  # four live, then fewer
+        reqs[k] = submit(k)
+    eng.run_until_idle(max_iters=400)
+    return {
+        "model": model, "params": params, "eng": eng, "warm": warm,
+        "after": eng.compile_stats(), "rungs": rungs, "plan": plan,
+        "prompts": prompts, "reqs": reqs,
+    }
+
+
+@pytest.mark.parametrize(
+    "name", ["long", "first", "second", "third", "fourth"]
+)
+def test_ladder_tokens_equal_generate(ladder_run, name):
+    """Whatever shapes a request's blocks ran at, beside whichever rows,
+    its tokens are solo ``generate()``'s."""
+    r = ladder_run
+    np.testing.assert_array_equal(
+        r["reqs"][name].result(),
+        _solo(r["model"], r["params"], r["prompts"][name],
+              r["plan"][name][1]),
+    )
+    assert r["reqs"][name].finish_reason == "budget"
+
+
+@pytest.mark.parametrize(
+    "what", ["compiled", "width_up", "rows_up", "rows_down", "smallest"]
+)
+def test_ladder_rungs_visited_and_never_recompile(ladder_run, what):
+    r = ladder_run
+    eng, rungs = r["eng"], r["rungs"]
+    shapes = [(rows, pages) for _, rows, pages in rungs]
+    if what == "compiled":
+        # warmup() compiles the ladder, all of it and nothing else.
+        assert eng.decode_shapes == [(1, 8), (1, 16), (2, 16), (4, 16)]
+        assert r["warm"]["decode"] == len(eng.decode_shapes)
+        assert r["after"] == r["warm"], "a shape compiled after warmup"
+    elif what == "width_up":
+        # The long row, alone: half the width, then all of it.
+        assert shapes[0] == (1, 8) and (1, 16) in shapes
+        widths = [pages for _, pages in shapes]
+        assert widths == sorted(widths)
+    elif what == "rows_up":
+        assert any(a < b for (a, _), (b, _) in zip(shapes, shapes[1:]))
+        assert max(rows for rows, _ in shapes) == 4
+    elif what == "rows_down":
+        assert any(a > b for (a, _), (b, _) in zip(shapes, shapes[1:]))
+        assert shapes[-1] == (1, 16)
+    else:
+        # Every block ran at the smallest row count that held its live
+        # rows, and every shape of the ladder was run.
+        for live, rows, _ in rungs:
+            assert rows == min(x for x, _ in eng.decode_shapes if x >= live)
+        assert set(shapes) == set(eng.decode_shapes)
+        assert eng.ledger.decode_read_fraction < 0.75
+
+
+def _slot_state(eng, live, lengths, group=None):
+    """Set a cold engine's host state: which slots are live, how long
+    their rows are, and which of them belong to the group asked about
+    (default: all the live ones)."""
+    S = eng.max_slots
+    eng._live = np.zeros((S,), bool)
+    eng._live[list(live)] = True
+    eng._lengths = np.zeros((S,), np.int32)
+    eng._lengths[list(live)] = lengths
+    mask = np.zeros((S,), bool)
+    mask[list(live if group is None else group)] = True
+    return mask
+
+
+_RUNG_CASES = {
+    # name: (live slots, their lengths, the group or None, rows, pages)
+    "quarter-live-short": ([2, 5, 11], [300, 272, 500], None, 4, 32),
+    "reach-on-the-rung": ([0], [504], None, 4, 32),
+    "reach-past-the-rung": ([0], [505], None, 4, 64),
+    "five-live-short": (range(5), [280, 290, 300, 310, 320], None, 8, 64),
+    "five-live-one-long": (range(5), [280, 290, 300, 310, 760], None, 8, 64),
+    "row-at-the-end": ([7], [1020], None, 4, 64),
+    "all-live": (range(16), [400] * 16, None, 16, 64),
+    "group-beside-a-full-house": (range(16), [400] * 16, [3, 4, 9], 4, 32),
+}
+
+
+@pytest.fixture(scope="module")
+def cell_shape_engine():
+    """A cold engine (zero weights, nothing compiled) with the serving
+    cell's slots, positions and pages around the tests' tiny model."""
+    model = GPT2(GPT2Config.small_test(n_ctx=1024, dropout=0.0))
+    params = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(
+            lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32))["params"],
+            jax.random.PRNGKey(0),
+        ),
+    )
+    return ServeEngine(
+        model, params, max_slots=16, buckets=[16], decode_block=8,
+        page_size=16,
+    )
+
+
+@pytest.mark.parametrize("case", sorted(_RUNG_CASES))
+def test_decode_rung_choice(cell_shape_engine, case):
+    """``_decode_rung`` at the serving cell's geometry (16 slots of
+    1,024 positions, pages of 16): the first shape of the ladder that
+    holds the group and its frontier; the rows are the group's live
+    slots first, padded with dead slots and only then with other groups'
+    live ones; a slot set with no dead slot to pad with takes what is
+    there, and all 16 live take the top rung."""
+    eng = cell_shape_engine
+    assert eng.decode_shapes == [(4, 32), (4, 64), (8, 64), (16, 64)]
+    live, lengths, group, want_rows, want_pages = _RUNG_CASES[case]
+    mask = _slot_state(eng, live, lengths, group)
+    rows, pages = eng._decode_rung(mask)
+    assert (len(rows), pages) == (want_rows, want_pages)
+    assert len(set(rows.tolist())) == len(rows)
+    members = np.flatnonzero(mask)
+    np.testing.assert_array_equal(rows[: len(members)], members)
+    padding = rows[len(members):]
+    dead = int((~eng._live).sum())
+    assert not eng._live[padding[:dead]].any()  # dead slots come first
+    assert eng._live[padding[dead:]].all()
+
+
 # ------------------------------------------- paged engine (ISSUE 11, slow)
 @pytest.mark.slow
 def test_prefix_cache_reuse_eviction_and_residency(model_params):
@@ -1327,7 +1498,11 @@ def test_device_observatory_acceptance(engine, model_params, tmp_path):
                 n == key or n.split("@")[0] == key for n in names
             ), f"ledger missing {key}: {names}"
         by_name = {e["name"]: e for e in ledger.programs}
-        decode = by_name["decode"]
+        # One decode entry per rung pair of the ladder, rows x positions.
+        assert {n for n in names if n.startswith("decode")} == {
+            "decode@1x64", "decode@2x64"
+        }
+        decode = by_name["decode@2x64"]
         assert decode["compile_s"] >= 0
         assert decode["flops"] > 0 and decode["bytes_accessed"] > 0
         assert decode["argument_bytes"] > 0  # CPU memory_analysis works

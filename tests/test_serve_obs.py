@@ -82,6 +82,49 @@ def test_serve_ledger_efficiency_and_spec_economics():
     assert snap["spec_wasted"] == 1
 
 
+# One scripted block: (rows, pages, group_live). The engine of the
+# script has 16 slots of 1,024 positions in pages of 16.
+_READ_SCRIPTS = {
+    "top-rung-only": [(16, 64, 3), (16, 64, 16)],
+    "rows-and-width": [(4, 32, 3), (8, 64, 5), (16, 64, 12), (4, 64, 1)],
+    "one-short-block": [(4, 32, 4)],
+}
+
+
+@pytest.mark.parametrize("script", sorted(_READ_SCRIPTS))
+def test_decode_read_fraction_over_scripted_blocks(script):
+    """``serve.decode_read_fraction`` is in the catalog and reads
+    sum(R * W * page_size) / sum(max_slots * n_ctx) over the blocks,
+    while ``decode_utilization`` still reads live rows over
+    ``max_slots`` whatever rung a block ran at (the benchmark's
+    ``decode_bw_share.serve`` multiplies it back by ``max_slots``)."""
+    from tpuflow.obs.catalog import CATALOG
+
+    assert CATALOG["serve.decode_read_fraction"][0] == "gauge"
+    slots, n_ctx, page = 16, 1024, 16
+    led = sl.ServeLedger()
+    assert led.decode_read_fraction is None
+    blocks = _READ_SCRIPTS[script]
+    for rows, pages, live in blocks:
+        led.note_decode_block(
+            slots, live, live, read_positions=rows * pages * page,
+            full_positions=slots * n_ctx,
+        )
+    want = sum(r * w * page for r, w, _ in blocks) / (
+        len(blocks) * slots * n_ctx
+    )
+    assert led.decode_read_fraction == pytest.approx(want)
+    assert (want == 1.0) == (script == "top-rung-only")
+    assert led.decode_utilization == pytest.approx(
+        sum(live for _, _, live in blocks) / (len(blocks) * slots)
+    )
+    snap = led.snapshot()
+    assert snap["decode_read_fraction"] == pytest.approx(want)
+    assert snap["decode_utilization"] == led.decode_utilization
+    led.reset()
+    assert led.decode_read_fraction is None
+
+
 def test_serve_ledger_slo_checks_and_env_resolution(monkeypatch):
     led = sl.ServeLedger(slo_ttft_s=0.1, slo_itl_s=0.01)
     assert not led.check_ttft(0.05)

@@ -12,10 +12,10 @@ saturated and move requests through it independently.
 Shape of the engine:
 
 - **Slots over one KV cache.** One fixed set of ``max_slots`` rows
-  owned by one compiled decode-block program. Each slot carries its own
-  ``live`` / ``length`` / ``pad`` / ``remaining`` state as (S,) operand
-  arrays — admissions, generation, and evictions are DATA, never shape,
-  so nothing recompiles. The per-row cache positions ride the model's
+  owned by one decode-block program. Each slot carries its own
+  ``live`` / ``length`` / ``pad`` / ``remaining`` state in (S,) host
+  arrays — admissions, generation, and evictions are DATA, never a new
+  shape, so nothing recompiles. The per-row cache positions ride the model's
   ``slot_index`` + ``page_table`` decode path (``GPT2.__call__``): row b
   writes its k/v at its own column of its own pages and its queries see
   ``[pad[b], length[b]]`` only, so a reused page's stale columns stay
@@ -31,17 +31,25 @@ Shape of the engine:
 
 - **Decode blocks.** Between admissions the engine runs the persistent
   decode program: a ``lax.scan`` of ``decode_block`` single-token steps
-  over all slots at once, with per-slot eos / budget / capacity freezing
-  inside the program (one host sync per BLOCK, not per token). Greedy
-  decoding; ``decode_precision`` (PR 4) makes batched decode
-  width-independent, so every request's tokens are exactly what a solo
-  ``generate()`` of its prompt produces.
+  with per-row eos / budget / capacity freezing inside the program (one
+  host sync per BLOCK, not per token). A block reads what is live: its
+  operands are the group's live slots, padded with dead ones to a row
+  count of a short ladder (``decode_ladder``: ``max_slots`` / 4, / 2,
+  / 1), and the page-table columns that hold the longest live row plus
+  the block (all of ``n_ctx``, or half of it at the smallest row
+  count). The host picks the shape from the numpy state it already
+  holds (``_decode_rung``) and merges the results back by slot index;
+  each (rows, pages) shape is one more entry of the one jitted
+  function's cache, four in all. Greedy decoding; ``decode_precision`` (PR 4)
+  makes batched decode width-independent, so every request's tokens are
+  exactly what a solo ``generate()`` of its prompt produces.
 
 - **AOT warm path.** ``warmup()`` routes through
-  ``maybe_enable_compile_cache`` and executes the decode program, the
-  insert, and every prefill bucket once, so a restarted server pays
-  cache loads instead of compiles. ``compile_stats()`` exposes the
-  jit cache sizes; after warmup they must never grow — pinned by
+  ``maybe_enable_compile_cache`` and executes the decode program at
+  every shape of its ladder, the insert, and every prefill bucket
+  once, so a restarted server pays cache loads instead of compiles.
+  ``compile_stats()`` exposes the jit cache sizes (``decode`` = the
+  ladder's size); after warmup they must never grow — pinned by
   tests/test_serve.py.
 
 - **Per-request int8 (ISSUE 9).** ``TPUFLOW_SERVE_QUANT`` (or the
@@ -528,6 +536,38 @@ def default_buckets(n_ctx: int) -> list[int]:
     return out
 
 
+# Narrowest decode read, in positions: one lane tile of a score row. Below
+# it a narrower read saves nothing a chip can see, and a tiny engine (the
+# tests') keeps one width.
+_MIN_READ_POSITIONS = 128
+
+
+def decode_ladder(
+    max_slots: int, n_ctx: int, page_size: int
+) -> list[tuple[int, int]]:
+    """The decode block's operand shapes ``(rows, pages)``, as
+    ``default_buckets`` gives the prefill's widths: the row counts
+    ``max_slots`` / 4, / 2 and / 1 at the full ``n_ctx / page_size``
+    pages, and the smallest of them at half the pages too; rounded up,
+    shapes that coincide merged, ascending by positions read. A decode
+    block runs at the first that holds its live rows and their frontier
+    (``ServeEngine._decode_rung``), so this is the whole compile set of
+    a numeric path's decode program.
+
+    Four shapes and not a full rows x widths grid, because every shape
+    is traced and lowered at every start-up (0.8 s each on the serving
+    cell's host, compile cache filled: PERF.md, PR 33), and because
+    rows cost more than width: at 4 rows a block of 8 steps takes 62 /
+    69 / 71 ms at 512 / 768 / 1,024 positions, at 8 rows 102 ms at
+    512."""
+    pages = n_ctx // page_size
+    rows = sorted({max(-(-max_slots * k // 4), 1) for k in (1, 2, 4)})
+    least = min(-(-_MIN_READ_POSITIONS // page_size), pages)
+    half = max(-(-pages // 2), least)
+    shapes = {(rows[0], half)} | {(r, pages) for r in rows}
+    return sorted(shapes, key=lambda s: (s[0] * s[1], s))
+
+
 def resolve_buckets(n_ctx: int, buckets=None) -> list[int]:
     """Bucket widths from the explicit arg, TPUFLOW_SERVE_BUCKETS, or the
     default ladder — validated, deduped, ascending, capped at the widest
@@ -819,6 +859,7 @@ class ServeEngine:
             ),
         )
         self._page_table = np.zeros((S, self.pages_per_slot), np.int32)
+        self.decode_shapes = decode_ladder(S, self.n_ctx, self.page_size)
         self._slot_pages: list[list[int]] = [[] for _ in range(S)]
         self._pmodel = model.clone(
             config=dataclasses.replace(
@@ -1032,13 +1073,18 @@ class ServeEngine:
     def _decode_fn(self, model, params, cache, tok, lengths, pads,
                    remaining, live, eos, page_table):
         """THE persistent decode program: ``decode_block`` single-token
-        steps over every slot, per-slot freezing inside the scan. One
-        host sync per block. Dead slots keep rewriting one cache column
-        with pad-token k/v — routed to the trash page by their zeroed
-        tables. ``model`` is partial-bound per numeric path: the int8
-        twin runs the same program shape with the fused-native W8A8
-        matmuls. ``page_table`` (loop-invariant data) goes into every
-        step."""
+        steps over the R rows it is given, per-row freezing inside the
+        scan. One host sync per block. The operands are (R,) arrays and
+        an (R, W) ``page_table`` (loop-invariant data, into every step):
+        the scheduler hands it the group's live slots, padded with dead
+        ones to a shape of ``decode_ladder``, and the table columns that
+        hold their frontier, so each (R, W) is one entry of this jit's
+        cache and the read is R x W pages a layer. Dead rows keep
+        rewriting one cache column with pad-token k/v — routed to the
+        trash page by their zeroed tables, as is any write beyond the
+        table's width. ``model`` is partial-bound per numeric path: the
+        int8 twin runs the same program shapes with the fused-native
+        W8A8 matmuls."""
         n_ctx = self.n_ctx
         pad_id = self.pad_id
 
@@ -1831,6 +1877,9 @@ class ServeEngine:
             util = self.ledger.decode_utilization
             if util is not None:
                 obs.gauge("serve.decode_utilization", round(util, 4))
+            read = self.ledger.decode_read_fraction
+            if read is not None:
+                obs.gauge("serve.decode_read_fraction", round(read, 4))
             waste = self.ledger.masked_row_waste
             if waste is not None:
                 obs.gauge("serve.masked_row_waste", round(waste, 4))
@@ -1857,19 +1906,39 @@ class ServeEngine:
                 tier.pages_host, tier.pages_disk, pool.tier_hits
             )
 
+    def _decode_rung(self, mask) -> tuple[np.ndarray, int]:
+        """The operand shapes of one group's decode block, from what the
+        host holds: the first of ``decode_shapes`` that holds the
+        group's live slots and the frontier the block can reach, the
+        longest live row plus ``decode_block`` positions. Returns the
+        slots it runs over — the group's live slots first, then dead
+        ones, then (only where those run out) other groups' live ones —
+        and the pages it reads of each."""
+        live = int(mask.sum())
+        reach = min(
+            int(self._lengths[mask].max()) + self.decode_block, self.n_ctx
+        )
+        n_rows, pages = next(
+            (r, w) for r, w in self.decode_shapes
+            if r >= live and w * self.page_size >= reach
+        )
+        return np.lexsort((self._live, ~mask))[:n_rows], pages
+
     def _run_decode_block(self, quant: bool, spec: bool = False) -> int:
         """One decode (or speculative verify) block over ONE group's
         slots — the groups partition the live set by (numeric path,
-        speculative): run that group's persistent program with every
-        OTHER group masked out of the live set, merge the per-slot state
-        back through the group mask, harvest tokens, free exited slots.
-        Returns emitted token count.
+        speculative): run that group's persistent program on the rows
+        ``_decode_rung`` picks (a verify block: every slot, full
+        width) with every row outside the group masked out of the live
+        set, merge the group's per-slot state back by index, harvest
+        tokens, free exited slots. Returns emitted token count.
 
         Why masking composes: each slot row only ever attends within its
         own pages, and a program only
         advances (and only writes real k/v for) rows live in ITS set — a
         masked-out row's garbage k/v writes land at its frozen
-        ``lengths`` column onward, exactly where that row's OWN program
+        ``lengths`` column onward (or, beyond the block's read width,
+        in the trash page), exactly where that row's OWN program
         writes real k/v next, so they are always overwritten before
         anything can attend to them (a verify block's K+1 garbage
         columns sit beyond the frozen frontier — masked out of every
@@ -1884,15 +1953,27 @@ class ServeEngine:
         old_remaining = self._remaining.copy()
         group_live = int(mask.sum())
         total_live = int(self._live.sum())
+        if spec:
+            rows, pages = np.arange(self.max_slots), self.pages_per_slot
+        else:
+            rows, pages = self._decode_rung(mask)
         # The whole block — host drafts, device dispatch, the fence, the
         # state merge — charges to the decode (or verify) ledger bucket;
         # everything between blocks lands in host_sched by construction.
         # The span's three children split it: only the fence waits for
         # the device.
         with self.ledger.bucket("verify" if spec else "decode"), obs.span(
-            "serve.decode", slots=group_live, spec=spec, quant=quant
+            "serve.decode", slots=group_live, spec=spec, quant=quant,
+            rows=len(rows), pages=pages,
         ) as sp:
             with obs.span("serve.decode.dispatch"):
+                tok, lengths, pads, remaining, live, eos = (
+                    a[rows] for a in (
+                        self._tok, self._lengths, self._pads,
+                        self._remaining, mask, self._eos,
+                    )
+                )
+                table = self._page_table[rows, :pages]
                 if spec:
                     # Host-side prompt-lookup drafts per slot (a wrong
                     # draft only costs speed; the verify forward
@@ -1911,49 +1992,43 @@ class ServeEngine:
                     (
                         self._cache, toks, tok, lengths, remaining, live
                     ) = verify(
-                        prm,
-                        self._cache,
-                        jnp.asarray(self._page_table),
-                        self._tok,
-                        jnp.asarray(drafts),
-                        self._lengths,
-                        self._pads,
-                        self._remaining,
-                        mask,
-                        self._eos,
+                        prm, self._cache, jnp.asarray(table), tok,
+                        jnp.asarray(drafts), lengths, pads, remaining,
+                        live, eos,
                     )
                 else:
                     decode = self._decode_q if quant else self._decode
                     (
                         self._cache, toks, tok, lengths, remaining, live
                     ) = decode(
-                        prm, self._cache, self._tok, self._lengths,
-                        self._pads, self._remaining, mask, self._eos,
-                        jnp.asarray(self._page_table),
+                        prm, self._cache, tok, lengths, pads, remaining,
+                        live, eos, table,
                     )
             # The host copy of the block's tokens IS the fence.
             with obs.span("serve.decode.fence"):
                 toks = np.asarray(toks)
-            # np.array (not asarray): the zero-copy view of a jax
-            # array is read-only, and admissions write these. Merge
-            # through the group mask — the program's carries hold
-            # pad_id tokens for every row outside its live set,
-            # including the OTHER groups' mid-flight slots.
+            # Merge by index, the group's rows alone — the program's
+            # carries hold pad_id tokens for every row outside its live
+            # set, the OTHER groups' mid-flight slots among them.
             with obs.span("serve.decode.merge"):
-                self._tok = np.where(mask, np.array(tok), self._tok)
-                self._lengths = np.where(
-                    mask, np.array(lengths), self._lengths
+                ours = mask[rows]
+                at = rows[ours]
+                self._tok[at] = np.asarray(tok)[ours]
+                self._lengths[at] = np.asarray(lengths)[ours]
+                self._remaining[at] = np.asarray(remaining)[ours]
+                self._live[at] = np.asarray(live)[ours]
+                by_slot = np.full(
+                    (self.max_slots, toks.shape[1]), self.pad_id, toks.dtype
                 )
-                self._remaining = np.where(
-                    mask, np.array(remaining), self._remaining
-                )
-                self._live = np.where(mask, np.array(live), self._live)
+                by_slot[rows] = toks
             emitted = int((old_remaining - self._remaining).sum())
             sp.set(tokens=emitted)
             self.ledger.note_decode_block(
                 self.max_slots, group_live, total_live, spec=spec,
                 drafted=group_live * self.spec_draft if spec else 0,
                 committed=emitted,
+                read_positions=len(rows) * pages * self.page_size,
+                full_positions=self.max_slots * self.n_ctx,
             )
             if spec:
                 self._spec_committed += emitted
@@ -1964,7 +2039,9 @@ class ServeEngine:
                     self._spec_committed, self._spec_forwards
                 )
         with obs.span("serve.harvest"):
-            self._harvest(mask, toks, old_remaining - self._remaining, spec)
+            self._harvest(
+                mask, by_slot, old_remaining - self._remaining, spec
+            )
         return emitted
 
     def _harvest(self, mask, toks, emitted_by_row, spec: bool) -> None:
@@ -2128,16 +2205,30 @@ class ServeEngine:
             jnp.ones((self.pages_per_slot,), bool),
         )
 
-    def _decode_warm_args(self):
-        """Dead-slot operands for one decode/verify warmup execution."""
+    def _decode_variants(self) -> list[tuple[str, int, int]]:
+        """Every (rows, pages) shape a decode block can take, each
+        under the name the device ledger files it by (rows x positions,
+        as ``prefill@<width>`` names a bucket)."""
         return [
-            self._tok, self._lengths, self._pads, self._remaining,
-            self._live, self._eos, jnp.asarray(self._page_table),
+            (f"@{r}x{w * self.page_size}", r, w)
+            for r, w in self.decode_shapes
+        ]
+
+    def _decode_warm_args(self, n_rows: int, pages: int):
+        """Dead-row operands for one decode warmup execution at one shape
+        of the ladder: tok, lengths, pads, remaining, live, eos,
+        page_table."""
+        zeros = np.zeros((n_rows,), np.int32)
+        return [
+            zeros, zeros, zeros, zeros, np.zeros((n_rows,), bool),
+            np.full((n_rows,), -1, np.int32),
+            np.zeros((n_rows, pages), np.int32),
         ]
 
     def warmup(self) -> dict[str, int]:
         """Compile-or-load every program the engine will ever run: the
-        decode block (and the speculative verify block when armed), the
+        decode block at every shape of ``decode_ladder`` (and the
+        speculative verify block when armed), the
         insert, and one prefill per bucket — through the persistent
         compile cache (``maybe_enable_compile_cache``), so a server
         restart pays cache loads, not compiles. Executes each program once on
@@ -2192,12 +2283,14 @@ class ServeEngine:
                     self._cache, row_cache, *self._insert_warm_args()
                 )
                 _fence("insert", t0)
-            t0 = time.monotonic()
-            out = self._decode(
-                self.params, self._cache, *self._decode_warm_args()
-            )
-            self._cache = out[0]
-            _fence("decode", t0)
+            for at, n_rows, pages in self._decode_variants():
+                t0 = time.monotonic()
+                out = self._decode(
+                    self.params, self._cache,
+                    *self._decode_warm_args(n_rows, pages),
+                )
+                self._cache = out[0]
+                _fence(f"decode{at}", t0)
             if self.spec_draft:
                 # The verify block (and below, its int8 twin): dead-slot
                 # drafts of zeros exercise the exact (S, K+1) signature
@@ -2217,12 +2310,14 @@ class ServeEngine:
             if self.quant_mode is not None:
                 # The int8 decode block on the decode-committed cache —
                 # the exact signature the mixed-traffic scheduler replays.
-                t0 = time.monotonic()
-                out = self._decode_q(
-                    self._qparams, self._cache, *self._decode_warm_args()
-                )
-                self._cache = out[0]
-                _fence("decode_q", t0)
+                for at, n_rows, pages in self._decode_variants():
+                    t0 = time.monotonic()
+                    out = self._decode_q(
+                        self._qparams, self._cache,
+                        *self._decode_warm_args(n_rows, pages),
+                    )
+                    self._cache = out[0]
+                    _fence(f"decode_q{at}", t0)
                 if self.spec_draft:
                     t0 = time.monotonic()
                     out = self._verify_q(
@@ -2277,8 +2372,9 @@ class ServeEngine:
 
     def aot_lower(self, ledger=None) -> int:
         """AOT-lower (``jit(...).lower(...).compile()``) every program
-        signature this engine replays — decode block, speculative verify,
-        page insert, and each bucket's prefill, plus the
+        signature this engine replays — the decode block at each shape
+        of its ladder (``decode@<rows>x<positions>``), speculative
+        verify, page insert, and each bucket's prefill, plus the
         int8 twins on a quant-armed engine — WITHOUT executing anything
         (row caches come from ``eval_shape``). With the persistent
         compile cache enabled the executables land on disk, which is
@@ -2313,11 +2409,15 @@ class ServeEngine:
         programs = 0
         row_shape = None
         for suffix, prefill, decode, verify, prm in pairs:
-            _compile(
-                f"decode{suffix}",
-                decode.lower(prm, self._cache, *self._decode_warm_args()),
-            )
-            programs += 1
+            for at, n_rows, pages in self._decode_variants():
+                _compile(
+                    f"decode{suffix}{at}",
+                    decode.lower(
+                        prm, self._cache,
+                        *self._decode_warm_args(n_rows, pages),
+                    ),
+                )
+                programs += 1
             if verify is not None:
                 _compile(
                     f"verify{suffix}",

@@ -93,7 +93,7 @@ class GPT2Config:
     # a ``page_table`` to a POOLED cache: instead of one contiguous
     # (B, n_ctx, H, D) row per slot, the cache is a fixed
     # (kv_pages, kv_page_size, H, D) pool and each slot's logical row is
-    # scattered across the pages its (B, n_ctx/kv_page_size) table
+    # scattered across the pages its (B, up to n_ctx/kv_page_size) table
     # names. kv_page_size must divide n_ctx. Page 0 is the engine's
     # TRASH page: out-of-range writes and dead slots (zeroed tables)
     # land there and nothing ever reads it, so a freed page can be
@@ -316,11 +316,16 @@ class Block(nn.Module):
         per-row cache positions over a page pool (ISSUE 11).
 
         The cache is ONE (kv_pages, kv_page_size, H, D) pool per layer,
-        shared by every slot; ``page_table`` (B, n_ctx/page_size) int32
-        maps each row's logical cache columns onto pool pages, and is
-        threaded through the decode program as DATA — admissions,
-        evictions and prefix-page sharing never change a shape, so the
-        engine's never-recompile contract extends to page management.
+        shared by every slot; ``page_table`` (B, W) int32, W at most
+        ``n_ctx / page_size``, maps the first ``W * page_size`` logical
+        cache columns of each row onto pool pages, and is threaded
+        through the decode program as DATA — admissions, evictions and
+        prefix-page sharing never change a shape, so the engine's
+        never-recompile contract extends to page management. The
+        table's own width is the width of the read: the caller passes
+        the columns ``[:W]`` that hold every row's frontier (the engine
+        picks W from a short ladder, ``infer.serve.decode_ladder``) and
+        nothing beyond them is gathered, masked or multiplied.
 
         Threading: the pool is one buffer that is only ever indexed into,
         never sliced, restacked or copied. Without a layer scan each
@@ -337,27 +342,28 @@ class Block(nn.Module):
         ``slot_index[b] + t``, each routed to
         ``(layer * kv_pages + table[b, col // ps]) * ps + col % ps`` of
         the flattened pool, in one scatter.
-        Out-of-range columns (>= n_ctx: a dying row's overshoot) and
-        dead slots (tables zeroed by the engine) route to the layer's
-        page 0 — the reserved TRASH page nothing ever reads — so a page
-        freed and re-allocated to a new request can never be corrupted
-        by its old slot's frozen garbage write.
+        Columns beyond the table (>= W * page_size: a dying row's
+        overshoot past n_ctx, or a row the caller did not size the
+        table for) and dead slots (tables zeroed by the engine) route
+        to the layer's page 0 — the reserved TRASH page nothing ever
+        reads — so a page freed and re-allocated to a new request can
+        never be corrupted by its old slot's frozen garbage write.
 
-        Reads: each row gathers its logical (n_ctx, H, D) view through
-        its table (pages ``layer * kv_pages + table[b]``, one gather)
-        and runs masked attention over it — columns
+        Reads: each row gathers its logical (W * page_size, H, D) view
+        through its table (pages ``layer * kv_pages + table[b]``, one
+        gather) and runs masked attention over it — columns
         ``[pad_lens[b], slot_index[b] + t]`` only.
         Masked columns may be backed by the trash page or a stale page:
         their scores are the -1e30 constant either way, so the gathered
-        garbage never reaches a real query (and the gathered bytes are a
-        whole ``n_ctx`` row's — paging moves capacity accounting, not
-        the attention's HBM traffic).
+        garbage never reaches a real query. The gathered bytes are
+        B x W pages a layer for K and for V: the attention's HBM
+        traffic follows the table it is given, not ``n_ctx``.
         """
         cfg = self.config
         B, T, H, D = q.shape
         ps = cfg.kv_page_size
         n_pages = cfg.kv_pages
-        pages_per_row = cfg.n_ctx // ps
+        width = page_table.shape[1] * ps  # positions this call reads
         cdt = cfg.kv_cache_dtype()
         stack = () if layer is None else (cfg.n_layer,)
         first_page = 0 if layer is None else layer * n_pages
@@ -377,10 +383,11 @@ class Block(nn.Module):
         )
         pos = slot_index[:, None] + jnp.arange(T)[None, :]  # (B, T) logical
         page = jnp.take_along_axis(
-            page_table, jnp.clip(pos // ps, 0, pages_per_row - 1), axis=1
+            page_table, jnp.clip(pos // ps, 0, page_table.shape[1] - 1),
+            axis=1,
         )
         flat = first_page * ps + jnp.where(
-            pos < cfg.n_ctx, page * ps + pos % ps, 0
+            pos < width, page * ps + pos % ps, 0
         )
 
         def scatter(pool, new):
@@ -392,7 +399,7 @@ class Block(nn.Module):
 
         def gather(pool):
             pages = pool.reshape(-1, ps, H, D)[first_page + page_table]
-            return pages.reshape(B, cfg.n_ctx, H, D)
+            return pages.reshape(B, width, H, D)
 
         with jax.named_scope("kv_write"):
             ck.value = scatter(ck.value, k)
@@ -400,7 +407,7 @@ class Block(nn.Module):
         with jax.named_scope("kv_read"):
             k_all = gather(ck.value)
             v_all = gather(cv.value)
-        k_pos = jnp.arange(cfg.n_ctx)
+        k_pos = jnp.arange(width)
         valid = k_pos[None, None, None, :] <= pos[:, None, :, None]
         if pad_lens is not None:
             valid = valid & (
@@ -552,7 +559,7 @@ class GPT2(nn.Module):
         width-dependent rounding; and it is the compute-bound decode
         call) while verify chunks and single-token steps run in
         ``decode_dtype``. ``slot_index`` (B,) int32 with ``page_table``
-        (B, n_ctx/kv_page_size) int32 switches decode mode to PER-ROW
+        (B, up to n_ctx/kv_page_size) int32 switches decode mode to PER-ROW
         cache positions in the PAGED cache pool (the serving engine;
         ``kv_pages``/``kv_page_size`` config fields): row b writes/reads
         at its own logical column, routed through the table onto shared

@@ -122,6 +122,15 @@ def _fmt_lat(p: dict | None) -> str:
     )
 
 
+# The serve ledger's efficiency gauges serve-summary reads beside the
+# bucket fractions.
+_LEDGER_EFFICIENCY = (
+    "serve.decode_utilization",
+    "serve.decode_read_fraction",
+    "serve.masked_row_waste",
+)
+
+
 def _serve_summary(run_dir: str, as_json: bool) -> int:
     records = load_access_log(run_dir)
     if not records:
@@ -143,8 +152,7 @@ def _serve_summary(run_dir: str, as_json: bool) -> int:
             "serve.idle_fraction",
             "serve.decode_fraction",
             "serve.prefill_fraction",
-            "serve.decode_utilization",
-            "serve.masked_row_waste",
+            *_LEDGER_EFFICIENCY,
         ):
             try:
                 ledger[name] = float(ev.get("value", 0.0))
@@ -176,7 +184,7 @@ def _serve_summary(run_dir: str, as_json: bool) -> int:
             v = ledger.get(f"serve.{b}_fraction")
             if v is not None:
                 print(f"  {b}: {100.0 * v:.1f}%")
-        for extra in ("serve.decode_utilization", "serve.masked_row_waste"):
+        for extra in _LEDGER_EFFICIENCY:
             if extra in ledger:
                 print(f"  {extra.split('.', 1)[1]}: {ledger[extra]:.4f}")
     return 0

@@ -399,6 +399,14 @@ CATALOG: dict[str, tuple[str, str]] = {
         "earned its FLOPs; low values say raise arrival rate or shrink "
         "slots)",
     ),
+    "serve.decode_read_fraction": (
+        "gauge",
+        "cache positions the decode blocks gathered (rows x read width "
+        "of the rung each ran at) over max_slots x n_ctx a block: 1.0 = "
+        "every block read every slot's whole row; against "
+        "decode_utilization x mean live context / n_ctx it sizes what a "
+        "per-row (ragged) read would still save",
+    ),
     "serve.masked_row_waste": (
         "gauge",
         "fraction of dispatched batch rows live engine-wide but masked "

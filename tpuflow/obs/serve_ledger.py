@@ -146,6 +146,8 @@ class ServeLedger:
         self._block_rows = 0       # batch rows dispatched, all blocks
         self._block_live = 0       # rows live in the dispatching group
         self._block_masked = 0     # rows live overall but masked out
+        self._read_positions = 0   # cache positions the blocks gathered
+        self._full_positions = 0   # what max_slots x n_ctx reads take
         # Speculative economics.
         self.spec_drafted = 0      # draft tokens sent to verify blocks
         self.spec_accepted = 0     # draft tokens the model agreed with
@@ -181,17 +183,24 @@ class ServeLedger:
         spec: bool = False,
         drafted: int = 0,
         committed: int = 0,
+        read_positions: int = 0,
+        full_positions: int = 0,
     ) -> None:
         """One group's decode/verify dispatch: ``batch_rows`` is the
-        program's fixed batch, ``group_live`` the rows live in THIS
+        engine's slot count, ``group_live`` the rows live in THIS
         group, ``total_live`` the rows live engine-wide — the difference
         is the masked-row waste the group partition pays. Speculative
         blocks also report drafted tokens vs committed (committed
         includes one bonus token per live row, so accepted drafts =
-        committed - group_live, floored at 0)."""
+        committed - group_live, floored at 0). ``read_positions`` is
+        what the block's program gathered a layer (its rows x its read
+        width) and ``full_positions`` what every slot's whole row would
+        have been (``max_slots x n_ctx``)."""
         self._block_rows += int(batch_rows)
         self._block_live += int(group_live)
         self._block_masked += max(int(total_live) - int(group_live), 0)
+        self._read_positions += int(read_positions)
+        self._full_positions += int(full_positions)
         if spec:
             self.spec_drafted += int(drafted)
             self.spec_accepted += max(int(committed) - int(group_live), 0)
@@ -204,6 +213,16 @@ class ServeLedger:
         if not self._block_rows:
             return None
         return self._block_live / self._block_rows
+
+    @property
+    def decode_read_fraction(self) -> float | None:
+        """Cache positions the dispatched blocks gathered over what
+        whole-row reads of every slot would have taken (1.0 = every
+        block read ``max_slots x n_ctx``; what the engine's decode
+        ladder saves is 1 minus this)."""
+        if not self._full_positions:
+            return None
+        return self._read_positions / self._full_positions
 
     @property
     def masked_row_waste(self) -> float | None:
@@ -298,6 +317,7 @@ class ServeLedger:
                 b: self.buckets[b] / wall for b in SERVE_BUCKETS
             },
             "decode_utilization": self.decode_utilization,
+            "decode_read_fraction": self.decode_read_fraction,
             "masked_row_waste": self.masked_row_waste,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
