@@ -877,6 +877,25 @@ def test_decode_rung_choice(cell_shape_engine, case):
     assert eng._live[padding[dead:]].all()
 
 
+@pytest.mark.parametrize("slots, n_ctx, want", [
+    (16, 1024, [(4, 32), (4, 64), (8, 64), (16, 64)]),  # serve-medium-chat: as PR 33 left it
+    (8, 1024, [(2, 32), (2, 64), (4, 64), (8, 64)]),
+    (32, 4096, [(8, 128), (8, 256), (16, 256), (24, 256), (32, 256)]),  # serve-xing4-reason
+    (64, 4096, [(16, 128)] + [(r, 256) for r in range(16, 65, 8)]),
+    (1, 64, [(1, 8)]),
+])
+def test_decode_ladder_steps_by_at_most_eight_rows(slots, n_ctx, want):
+    """A quarter, a half and all of the slots, and no shape more than 8
+    rows above the one below: up to 16 slots the four shapes of PR 33."""
+    from tpuflow.infer.serve import decode_ladder
+
+    shapes = decode_ladder(slots, n_ctx, 16 if n_ctx > 64 else 8)
+    assert shapes == want
+    rows = sorted({r for r, _ in shapes})
+    assert rows[-1] == slots
+    assert all(b - a <= 8 for a, b in zip(rows, rows[1:]))
+
+
 # ------------------------------------------- paged engine (ISSUE 11, slow)
 @pytest.mark.slow
 def test_prefix_cache_reuse_eviction_and_residency(model_params):

@@ -35,12 +35,12 @@ Shape of the engine:
   host sync per BLOCK, not per token). A block reads what is live: its
   operands are the group's live slots, padded with dead ones to a row
   count of a short ladder (``decode_ladder``: ``max_slots`` / 4, / 2,
-  / 1), and the page-table columns that hold the longest live row plus
+  / 1, and no step of more than 8 rows), and the page-table columns that hold the longest live row plus
   the block (all of ``n_ctx``, or half of it at the smallest row
   count). The host picks the shape from the numpy state it already
   holds (``_decode_rung``) and merges the results back by slot index;
   each (rows, pages) shape is one more entry of the one jitted
-  function's cache, four in all. Greedy decoding; ``decode_precision`` (PR 4)
+  function's cache, four in all up to 16 slots. Greedy decoding; ``decode_precision`` (PR 4)
   makes batched decode width-independent, so every request's tokens are
   exactly what a solo ``generate()`` of its prompt produces.
 
@@ -540,6 +540,11 @@ def default_buckets(n_ctx: int) -> list[int]:
 # it a narrower read saves nothing a chip can see, and a tiny engine (the
 # tests') keeps one width.
 _MIN_READ_POSITIONS = 128
+# Most rows between one decode shape and the next: a block pays for the
+# rows of its shape, live or not (gathers, attention, the head), so with
+# 16 rows and then 32 the seventeenth live row cost 40% of a block
+# (PERF.md section 6, PR 34).
+_MAX_ROW_STEP = 8
 
 
 def decode_ladder(
@@ -547,21 +552,27 @@ def decode_ladder(
 ) -> list[tuple[int, int]]:
     """The decode block's operand shapes ``(rows, pages)``, as
     ``default_buckets`` gives the prefill's widths: the row counts
-    ``max_slots`` / 4, / 2 and / 1 at the full ``n_ctx / page_size``
-    pages, and the smallest of them at half the pages too; rounded up,
-    shapes that coincide merged, ascending by positions read. A decode
-    block runs at the first that holds its live rows and their frontier
-    (``ServeEngine._decode_rung``), so this is the whole compile set of
-    a numeric path's decode program.
+    ``max_slots`` / 4, / 2 and / 1, and between them whatever keeps a
+    count within ``_MAX_ROW_STEP`` rows of the one below, at the full
+    ``n_ctx / page_size`` pages, and the smallest of them at half the
+    pages too; rounded up, shapes that coincide merged, ascending by
+    positions read. A decode block runs at the first that holds its live
+    rows and their frontier (``ServeEngine._decode_rung``), so this is the
+    whole compile set of a numeric path's decode program.
 
-    Four shapes and not a full rows x widths grid, because every shape
+    Few shapes and not a full rows x widths grid, because every shape
     is traced and lowered at every start-up (0.8 s each on the serving
     cell's host, compile cache filled: PERF.md, PR 33), and because
     rows cost more than width: at 4 rows a block of 8 steps takes 62 /
     69 / 71 ms at 512 / 768 / 1,024 positions, at 8 rows 102 ms at
-    512."""
+    512. Up to 16 slots that is four shapes; 32 slots get 24 rows
+    between 16 and 32, where one more live row cost two fifths of a
+    block (PERF.md, PR 34)."""
     pages = n_ctx // page_size
     rows = sorted({max(-(-max_slots * k // 4), 1) for k in (1, 2, 4)})
+    for lo, hi in zip(rows, rows[1:]):
+        rows += range(lo + _MAX_ROW_STEP, hi, _MAX_ROW_STEP)
+    rows.sort()
     least = min(-(-_MIN_READ_POSITIONS // page_size), pages)
     half = max(-(-pages // 2), least)
     shapes = {(rows[0], half)} | {(r, pages) for r in rows}
@@ -931,22 +942,49 @@ class ServeEngine:
     def _init_cache(self):
         """Zeroed KV cache with the decode model's exact cache pytree
         (eval_shape — no compile, no garbage forward): the (n_pages,
-        page_size) pool."""
+        page_size) pool. Also ``self._token_ranks``: per leaf (by
+        ``keystr`` of its path, which a prefill row's leaves share), how
+        many trailing axes one token holds of a pool leaf ``(...,
+        n_pages, page_size, *token)`` — 2 for K or V of (H, D), 1 for a
+        latent vector, None for a leaf the pool does not hold by pages
+        (the index scalars). The page axis is the one that grows with
+        ``kv_pages``: asked of the model, never guessed from sizes."""
 
-        def mk(params):
-            _, variables = self._pmodel.apply(
-                {"params": params},
-                jnp.zeros((self.max_slots, 1), jnp.int32),
-                decode=True,
-                mutable=["cache"],
-                slot_index=jnp.zeros((self.max_slots,), jnp.int32),
-                page_table=jnp.zeros(
-                    (self.max_slots, self.pages_per_slot), jnp.int32
-                ),
+        def shapes_at(n_pages):
+            model = self._pmodel.clone(
+                config=dataclasses.replace(
+                    self._pmodel.config, kv_pages=n_pages
+                )
             )
-            return variables["cache"]
 
-        shapes = jax.eval_shape(mk, self.params)
+            def mk(params):
+                _, variables = model.apply(
+                    {"params": params},
+                    jnp.zeros((self.max_slots, 1), jnp.int32),
+                    decode=True,
+                    mutable=["cache"],
+                    slot_index=jnp.zeros((self.max_slots,), jnp.int32),
+                    page_table=jnp.zeros(
+                        (self.max_slots, self.pages_per_slot), jnp.int32
+                    ),
+                )
+                return variables["cache"]
+
+            return jax.eval_shape(mk, self.params)
+
+        shapes = shapes_at(self.n_pages)
+        self._token_ranks = {}
+        for (path, s), more in zip(
+            jax.tree_util.tree_flatten_with_path(shapes)[0],
+            jax.tree_util.tree_leaves(shapes_at(self.n_pages + 1)),
+        ):
+            grew = [
+                i for i, (a, b) in enumerate(zip(s.shape, more.shape))
+                if a != b
+            ]
+            self._token_ranks[jax.tree_util.keystr(path)] = (
+                s.ndim - grew[0] - 2 if grew else None
+            )
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes
         )
@@ -976,30 +1014,32 @@ class ServeEngine:
         All three controls are DATA (no recompile per admission).
 
         The pool is updated in place by index, as the decode program
-        does it (``Block._paged_attention``): a K or V leaf
-        ``(..., n_pages, page_size, H, D)`` — one block's pool, or the
-        layer-stacked one — is flattened over its leading axes to
-        ``(layers * n_pages, page_size, H, D)`` and takes ONE scatter of
+        does it (``Block._paged_attention``): a leaf
+        ``(..., n_pages, page_size, *what a token holds)`` — K or V of
+        (H, D), a latent vector, one block's pool or the layer-stacked
+        one — is flattened over its leading axes to
+        ``(layers * n_pages, page_size, ...)`` and takes ONE scatter of
         the row's ``layers * pages_per_slot`` pages at
         ``layer * n_pages + page``. Nothing is read back from the pool
         and no other page is touched."""
         idx = jnp.where(write_mask, table_row, 0)
 
-        def put(pool, row):
-            if pool.ndim < 4 or row.ndim < 4:
+        def put(path, pool, row):
+            token = self._token_ranks[jax.tree_util.keystr(path)]
+            if token is None:
                 return pool  # scalar index leaves pass through
-            n_pages, tail = pool.shape[-4], pool.shape[-3:]
-            rows = row.reshape((-1,) + row.shape[-3:])  # (layers, n_ctx, H, D)
+            tail = pool.shape[pool.ndim - token - 1:]  # (page_size, ...)
+            rows = row.reshape((-1, self.n_ctx) + tail[1:])  # (layers, n_ctx, ...)
             pages = jnp.roll(rows, -pad, axis=1).reshape(
                 (-1,) + tail
-            ).astype(pool.dtype)  # (layers * pages_per_slot, ps, H, D)
-            first_page = jnp.arange(rows.shape[0]) * n_pages
+            ).astype(pool.dtype)  # (layers * pages_per_slot, ps, ...)
+            first_page = jnp.arange(rows.shape[0]) * self.n_pages
             at = (first_page[:, None] + idx[None, :]).reshape(-1)
             return pool.reshape((-1,) + tail).at[at].set(pages).reshape(
                 pool.shape
             )
 
-        return jax.tree_util.tree_map(put, cache, row_cache)
+        return jax.tree_util.tree_map_with_path(put, cache, row_cache)
 
     @jax.named_scope("serve.verify")
     def _verify_fn(self, model, params, cache, page_table, tok, draft,
@@ -1084,7 +1124,12 @@ class ServeEngine:
         trash page by their zeroed tables, as is any write beyond the
         table's width. ``model`` is partial-bound per numeric path: the
         int8 twin runs the same program shapes with the fused-native
-        W8A8 matmuls."""
+        W8A8 matmuls.
+
+        What the model sows of a step into its ``step_sum`` and
+        ``step_max`` collections (scalars: the experts a routed layer
+        touched, say; GPT-2 sows nothing) comes back as the last result,
+        summed and taken the largest of over the block's steps."""
         n_ctx = self.n_ctx
         pad_id = self.pad_id
 
@@ -1094,7 +1139,7 @@ class ServeEngine:
                 {"params": params, "cache": cache},
                 tok[:, None],
                 decode=True,
-                mutable=["cache"],
+                mutable=["cache", "step_sum", "step_max"],
                 pad_lens=pads,
                 slot_index=lengths,
                 page_table=page_table,
@@ -1112,17 +1157,25 @@ class ServeEngine:
                 & (remaining > 0)
                 & (lengths < n_ctx)
             )
+            sown = (
+                dict(variables.get("step_sum", {})),
+                dict(variables.get("step_max", {})),
+            )
             return (
                 variables["cache"], emitted, lengths, remaining, live
-            ), emitted
+            ), (emitted, sown)
 
-        (cache, tok, lengths, remaining, live), toks = jax.lax.scan(
+        (cache, tok, lengths, remaining, live), (toks, sown) = jax.lax.scan(
             one,
             (cache, tok, lengths, remaining, live),
             None,
             length=self.decode_block,
         )
-        return cache, toks.T, tok, lengths, remaining, live
+        steps = (
+            jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), sown[0]),
+            jax.tree_util.tree_map(lambda a: jnp.max(a, axis=0), sown[1]),
+        )
+        return cache, toks.T, tok, lengths, remaining, live, steps
 
     # ------------------------------------------------------------ scheduling
     def bucket_for(self, prompt_len: int, max_new_tokens: int) -> int:
@@ -1286,33 +1339,38 @@ class ServeEngine:
 
     # ------------------------------------- disaggregated serving (ISSUE 19)
     def _cache_leaf_items(self, tree):
-        """``(path-key, leaf)`` for every pool-shaped KV leaf (>= 4
-        dims: ``(..., pages_or_slot, tokens, H, D)``) in canonical
+        """``(path-key, leaf)`` for every leaf of ``tree`` (the pool, or
+        a prefill row of the same structure) that the pool holds by
+        pages: ``(..., pages_or_slot, tokens, *token)``, in canonical
         flatten order — the shared leaf naming that page bundles,
-        shipped sets, and the tier store all key on."""
+        shipped sets, and the tier store all key on. How many axes a
+        token holds of each: ``self._token_ranks[key]``."""
         out = []
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-            if getattr(leaf, "ndim", 0) >= 4:
-                out.append((jax.tree_util.keystr(path), leaf))
+            key = jax.tree_util.keystr(path)
+            if self._token_ranks.get(key) is not None:
+                out.append((key, leaf))
         return out
 
     def _read_page_host(self, pid: int) -> dict[str, np.ndarray]:
         """Pool page ``pid`` as a host-side per-leaf bundle ``(...,
-        page_size, H, D)`` — the spill/promotion unit. Eager gathers:
+        page_size, *token)`` — the spill/promotion unit. Eager gathers:
         no named program, so ``compile_stats()`` never sees this."""
         out = {}
         for key, leaf in self._cache_leaf_items(self._cache):
             out[key] = np.asarray(
-                jnp.take(leaf, pid, axis=leaf.ndim - 4)
+                jnp.take(
+                    leaf, pid, axis=leaf.ndim - self._token_ranks[key] - 2
+                )
             )
         return out
 
     def _row_template(self):
         """Shape/dtype pytree of a prefill cache row via
         ``jax.eval_shape`` (no compile, no device work), cached. Row
-        leaves are bucket-independent — ``(..., 1, n_ctx, H, D)`` KV
-        plus the row model's index scalars — so one template serves
-        every restore."""
+        leaves are bucket-independent — ``(..., 1, n_ctx, *token)`` plus
+        the row model's index scalars — so one template serves every
+        restore."""
         if self._row_tmpl is None:
             W = self.buckets[0]
             pads = prompt_lens_to_pad_lens([1], 1, W)
@@ -1335,19 +1393,22 @@ class ServeEngine:
         arrays: the insert passes them through unread, and a fresh
         buffer never aliases the donated cache operand."""
         ps = self.page_size
-
-        def mk(path, leaf):
-            row = np.zeros(leaf.shape, leaf.dtype)
-            if row.ndim < 4:
-                return row
-            key = jax.tree_util.keystr(path)
+        tmpl = self._row_template()
+        rows = jax.tree_util.tree_map(
+            lambda leaf: np.zeros(leaf.shape, leaf.dtype), tmpl
+        )
+        flat = dict(
+            (jax.tree_util.keystr(path), row)
+            for path, row in jax.tree_util.tree_flatten_with_path(rows)[0]
+        )
+        for key, _ in self._cache_leaf_items(tmpl):
+            token = self._token_ranks[key]
             for j, bundle in pages.items():
                 page = bundle.get(key)
                 if page is not None:
-                    row[..., 0, j * ps:(j + 1) * ps, :, :] = page
-            return row
-
-        return jax.tree_util.tree_map_with_path(mk, self._row_template())
+                    at = (Ellipsis, 0, slice(j * ps, (j + 1) * ps))
+                    flat[key][at + (slice(None),) * token] = page
+        return rows
 
     def _restore_pages(
         self, table_row: np.ndarray, pages: dict[int, dict], request: int
@@ -1408,14 +1469,15 @@ class ServeEngine:
         k_ship = -(-L // ps)
         pages: dict[str, np.ndarray] = {}
         for key, leaf in self._cache_leaf_items(row_cache):
-            row = np.asarray(leaf)  # (..., 1, n_ctx, H, D)
-            shifted = np.roll(row, -(W - L), axis=row.ndim - 3)
-            sq = np.take(shifted, 0, axis=row.ndim - 4)
-            lead = sq.shape[: sq.ndim - 3]
+            token = self._token_ranks[key]
+            row = np.asarray(leaf)  # (..., 1, n_ctx, *token)
+            shifted = np.roll(row, -(W - L), axis=row.ndim - token - 1)
+            sq = np.take(shifted, 0, axis=row.ndim - token - 2)
+            lead = sq.shape[: sq.ndim - token - 1]
             paged = sq.reshape(
-                lead + (self.pages_per_slot, ps) + sq.shape[-2:]
+                lead + (self.pages_per_slot, ps) + sq.shape[sq.ndim - token:]
             )
-            paged = np.moveaxis(paged, paged.ndim - 4, 0)
+            paged = np.moveaxis(paged, len(lead), 0)
             pages[key] = np.ascontiguousarray(paged[:k_ship])
         return _kvstore.KVPageSet(
             page_size=ps,
@@ -1999,7 +2061,8 @@ class ServeEngine:
                 else:
                     decode = self._decode_q if quant else self._decode
                     (
-                        self._cache, toks, tok, lengths, remaining, live
+                        self._cache, toks, tok, lengths, remaining, live,
+                        steps,
                     ) = decode(
                         prm, self._cache, tok, lengths, pads, remaining,
                         live, eos, table,
@@ -2007,6 +2070,15 @@ class ServeEngine:
             # The host copy of the block's tokens IS the fence.
             with obs.span("serve.decode.fence"):
                 toks = np.asarray(toks)
+                if not spec:
+                    sums, maxes = (
+                        {k: v.item() for k, v in d.items()}
+                        for d in jax.device_get(steps)
+                    )
+                    sp.set(**sums, **maxes)
+                    self.ledger.note_model_steps(
+                        self.decode_block, sums, maxes
+                    )
             # Merge by index, the group's rows alone — the program's
             # carries hold pad_id tokens for every row outside its live
             # set, the OTHER groups' mid-flight slots among them.

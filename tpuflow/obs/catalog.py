@@ -212,7 +212,14 @@ CATALOG: dict[str, tuple[str, str]] = {
         "span",
         "one decode (spec: verify) block of the persistent slot-based "
         "program over one group's slots (quant = the int8 twin; "
-        "decode_block tokens per live slot, one host sync)",
+        "decode_block tokens per live slot, one host sync); rows, pages = "
+        "the block's operand shape; plus whatever the model sowed of its "
+        "steps into its step_sum / step_max collections, summed / the "
+        "largest over the block (a routed model: experts_touched, the "
+        "distinct experts a live row chose over steps and routed layers; "
+        "expert_max_load, the most tokens one expert got in a step over "
+        "the mean) — ServeLedger's snapshot keeps both (step_sum, "
+        "step_max, model_steps)",
     ),
     "serve.decode.dispatch": (
         "span",

@@ -148,6 +148,11 @@ class ServeLedger:
         self._block_masked = 0     # rows live overall but masked out
         self._read_positions = 0   # cache positions the blocks gathered
         self._full_positions = 0   # what max_slots x n_ctx reads take
+        # What the model sowed of its decode steps (``step_sum`` /
+        # ``step_max`` collections: a routed model's experts touched).
+        self._model_steps = 0
+        self.step_sum: dict[str, float] = {}
+        self.step_max: dict[str, float] = {}
         # Speculative economics.
         self.spec_drafted = 0      # draft tokens sent to verify blocks
         self.spec_accepted = 0     # draft tokens the model agreed with
@@ -204,6 +209,15 @@ class ServeLedger:
         if spec:
             self.spec_drafted += int(drafted)
             self.spec_accepted += max(int(committed) - int(group_live), 0)
+
+    def note_model_steps(self, steps: int, sums: dict, maxes: dict) -> None:
+        """One decode block's ``steps`` steps as the model counted them:
+        ``sums`` already summed over the block, ``maxes`` its largest."""
+        self._model_steps += int(steps)
+        for k, v in sums.items():
+            self.step_sum[k] = self.step_sum.get(k, 0) + v
+        for k, v in maxes.items():
+            self.step_max[k] = max(self.step_max.get(k, v), v)
 
     @property
     def decode_utilization(self) -> float | None:
@@ -318,6 +332,9 @@ class ServeLedger:
             },
             "decode_utilization": self.decode_utilization,
             "decode_read_fraction": self.decode_read_fraction,
+            "model_steps": self._model_steps,
+            "step_sum": dict(self.step_sum),
+            "step_max": dict(self.step_max),
             "masked_row_waste": self.masked_row_waste,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,

@@ -5,6 +5,7 @@ from tpuflow.ops.attention import (
     resolve_attention_impl,
     xla_attention,
 )
+from tpuflow.ops.grouped_matmul import grouped_dot, resolve_grouped_impl
 from tpuflow.ops.int8_matmul import (
     int8_matmul,
     quantize_rows,
@@ -13,9 +14,11 @@ from tpuflow.ops.int8_matmul import (
 
 __all__ = [
     "attention",
+    "grouped_dot",
     "int8_matmul",
     "quantize_rows",
     "resolve_attention_impl",
+    "resolve_grouped_impl",
     "resolve_int8_impl",
     "xla_attention",
 ]

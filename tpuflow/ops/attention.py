@@ -61,7 +61,7 @@ _FLASH_MIN_SEQ = 1024
 
 def resolve_attention_impl(
     impl: str, q_shape, tk: int, *, causal: bool = True,
-    backend: str | None = None,
+    backend: str | None = None, v_dim: int | None = None,
 ) -> str:
     """The whole choice of attention kernel. A named ``impl`` passes
     through. ``'auto'`` is ``'flash'`` exactly when the kernels can run
@@ -73,13 +73,18 @@ def resolve_attention_impl(
     least ``_FLASH_MIN_SEQ`` positions; at a shape the kernels tile
     (``flash_tiles``: a 600-token prefill does not tile the 256-row score
     tiles, gpt2-xl's 25 heads of 64 do not pair into 128-lane tiles).
-    ``q_shape`` is (B, Tq, H, D) and ``tk`` the K/V length. Resolved at
-    trace time: under jit the choice is part of the compiled program."""
+    ``q_shape`` is (B, Tq, H, D) and ``tk`` the K/V length; ``v_dim`` is
+    the values' head size where it is not the queries' (latent attention:
+    192-wide queries and keys, 128-wide values), which the kernels, one
+    head size throughout, do not run. Resolved at trace time: under jit
+    the choice is part of the compiled program."""
     if impl != "auto":
         return impl
     backend = backend if backend is not None else jax.default_backend()
     _, tq, h, d = q_shape
     if backend != "tpu" or tq < _FLASH_MIN_SEQ:
+        return "xla"
+    if v_dim is not None and v_dim != d:
         return "xla"
     from tpuflow.ops.flash_attention import flash_tiles
     from tpuflow.parallel.sharding import active_mesh
@@ -93,7 +98,9 @@ def resolve_attention_impl(
 def attention(q, k, v, *, causal: bool = True, impl: str = "xla"):
     """Dispatch to the selected implementation (see the module docstring;
     ``impl='auto'``: ``resolve_attention_impl``)."""
-    impl = resolve_attention_impl(impl, q.shape, k.shape[1], causal=causal)
+    impl = resolve_attention_impl(
+        impl, q.shape, k.shape[1], causal=causal, v_dim=v.shape[-1]
+    )
     if impl == "xla":
         fn = xla_attention
     elif impl == "flash":
