@@ -323,3 +323,42 @@ def test_ckpt_manager_marker_rides_the_same_commit_helper(tmp_path):
     kvs.atomic_write_json(path, {"step": 3})
     assert json.load(open(path)) == {"step": 3}
     assert os.listdir(str(tmp_path)) == ["marker.json"]
+
+
+# ------------------------------------------------- sets of an older build
+OLD_SETS = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "data", "kv_sets_pr34"
+)
+
+
+@pytest.mark.parametrize(
+    "name,tokens,leaves,shape",
+    [
+        ("gpt2-blocks", 13, 4, (2, 8, 4, 32)),  # K and V of two blocks
+        ("gpt2-scan", 13, 2, (2, 2, 8, 4, 32)),  # the layer-stacked K and V
+        ("xing4", 35, 1, (3, 3, 16, 20)),  # one latent leaf, 3 layers
+    ],
+)
+def test_a_set_committed_by_an_older_build_still_loads(
+    tmp_path, name, tokens, leaves, shape
+):
+    """The page set's format is no property of the pool's leaf: sets
+    that PR 34's build committed (``data/kv_sets_pr34/make_sets.py``),
+    whose pages end in ``(page_size, H, D)`` or in a bare latent, load
+    crc-clean with their digest chain, and commit again to the same
+    bytes."""
+    import shutil
+
+    store = kvs.KVStore(shutil.copytree(os.path.join(OLD_SETS, name), tmp_path / name))
+    (key,) = store.keys()
+    pset = store.load(key)
+    assert pset is not None and pset.key == key
+    assert pset.n_tokens == tokens == pset.prompt.size
+    assert pset.digests == kvs.chain_digests(pset.prompt, pset.page_size)
+    assert [a.shape for a in pset.pages.values()] == [shape] * leaves
+    with open(os.path.join(store.root, key + kvs.BLOB_SUFFIX), "rb") as f:
+        blob = f.read()
+    again = kvs.KVStore(str(tmp_path / "again"))
+    again.commit(pset)
+    with open(os.path.join(again.root, key + kvs.BLOB_SUFFIX), "rb") as f:
+        assert f.read() == blob

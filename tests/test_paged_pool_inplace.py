@@ -10,9 +10,17 @@ into it or selects over it.
   ``dynamic_update_slice`` or ``dynamic_slice``. This is the guard that
   keeps a later refactor from putting the copies back.
   A decode block traced at fewer rows or pages than the engine's all
-  (ISSUE 33) gathers ``(rows, width, H, D)`` and, at less than the full
-  width, no gather or product in it has the extent of ``n_ctx``: the
-  guard that keeps the full read from coming back.
+  (ISSUE 33) gathers ``(rows, pages, page_size, H * D)`` and, at less
+  than the full width, no gather or product in it has the extent of
+  ``n_ctx``: the guard that keeps the full read from coming back.
+- **The pool's shape, and what the chip makes of it** (ISSUE 35): every
+  paged leaf of both model families ends in ``(pages, page_size, width)``
+  with ``width`` a whole number of 128-lane rows, and the decode block,
+  the verify block and the insert, lowered and compiled here for a
+  described v5e at the benchmark cells' pool shapes, take the pool in
+  row-major order and hold no ``copy`` of it. With ``(page_size, H, D)``
+  or a 576-number latent as the tail the chip put the page axis on the
+  lanes and every block and insert copied the pool there and back.
 - **Exactness over both cache layouts** (one pool per block, and the
   layer-stacked pool the benchmark's cell serves): engine tokens equal
   solo ``generate()``; a shared prefix page is never rewritten by a
@@ -21,15 +29,24 @@ into it or selects over it.
 """
 
 import math
+import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpuflow.infer import generate
-from tpuflow.infer.serve import ServeEngine
-from tpuflow.models.gpt2 import GPT2, GPT2Config
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.harness import manifest  # noqa: E402
+from tpuflow.infer import generate  # noqa: E402
+from tpuflow.infer.serve import ServeEngine  # noqa: E402
+from tpuflow.models.gpt2 import GPT2, GPT2Config  # noqa: E402
+from tpuflow.ops import paged_pool  # noqa: E402
 
 PAGE = 8
 
@@ -65,9 +82,10 @@ def _solo(model, params, prompt, n_new):
 
 
 def _pool_leaves(eng) -> dict[str, np.ndarray]:
-    """Host copies of the pool's K/V leaves with the page axis first."""
+    """Host copies of the pool's K/V leaves with the page axis first:
+    ``(pages, ..., page_size, H * D)``."""
     return {
-        key: np.moveaxis(np.asarray(leaf), leaf.ndim - 4, 0)
+        key: np.moveaxis(np.asarray(leaf), eng._page_axis[key], 0)
         for key, leaf in eng._cache_leaf_items(eng._cache)
     }
 
@@ -169,11 +187,13 @@ def test_layer_scan_carries_the_pool_and_scans_none_of_it(program, remat):
 def test_decode_variant_reads_its_rung_and_no_more(rung, scan_layers):
     """The decode program traced at one shape (rows, pages), of the
     engine's ladder or not: every page gather yields ``(rows, pages,
-    page_size, H, D)`` and, at less than the full width, no gather or
-    product touches an operand with an extent of ``n_ctx`` positions.
-    Sizes chosen so that no model width (4 heads of 32, 128 wide, 512
-    in the feed-forward and the vocabulary) equals a read width (128,
-    192 positions) or ``n_ctx`` (256)."""
+    page_size, H * D)`` and, at less than the full width, no gather or
+    product touches an operand of more than two axes with an extent of
+    ``n_ctx`` positions. Sizes chosen so that no model width (4 heads
+    of 32, 128 wide, 512 in the feed-forward and the vocabulary)
+    equals ``n_ctx`` (256) or the 192-position read; the 128-position
+    read shares its extent with the model's width, so that rung is told
+    by its gathers alone."""
     n_ctx, slots = 256, 4
     eng, _ = _cold_engine(
         n_ctx=n_ctx, max_slots=slots, scan_layers=scan_layers
@@ -193,13 +213,13 @@ def test_decode_variant_reads_its_rung_and_no_more(rung, scan_layers):
             for v in list(eqn.invars) + list(eqn.outvars)
             if hasattr(v, "aval") and hasattr(v.aval, "shape")
         ]
-        if eqn.primitive.name == "gather" and len(shapes[-1]) == 5:
+        if eqn.primitive.name == "gather" and len(shapes[-1]) == 4:
             gathered.append(shapes[-1])
         # The position table (n_ctx, n_embd) is looked up by a gather
         # too: its operand is two-dimensional and no part of the read.
         extents.update(d for sh in shapes if len(sh) > 2 for d in sh)
     layers = 1 if scan_layers else 3  # the layer scan's body is one block
-    assert gathered == [(rows, pages, PAGE, 4, 32)] * (2 * layers)
+    assert gathered == [(rows, pages, PAGE, 4 * 32)] * (2 * layers)
     assert width in extents  # the scores' and the weighted sum's products
     assert (n_ctx in extents) == (pages == n_ctx // PAGE)
 
@@ -271,13 +291,13 @@ def test_shared_prefix_page_is_never_rewritten(rig):
     ]
     assert len(shared) == 2 and 0 not in shared
 
-    def poison(leaf):
-        if leaf.ndim < 4:
+    def poison(path, leaf):
+        axis = eng._page_axis[jax.tree_util.keystr(path)]
+        if axis is None:
             return leaf
-        at = (slice(None),) * (leaf.ndim - 4) + (np.asarray(shared),)
-        return leaf.at[at].set(-7.0)
+        return leaf.at[(slice(None),) * axis + (np.asarray(shared),)].set(-7.0)
 
-    eng._cache = jax.tree_util.tree_map(poison, eng._cache)
+    eng._cache = jax.tree_util.tree_map_with_path(poison, eng._cache)
     r = eng.submit(prompts[2], max_new_tokens=6)
     eng.run_until_idle(max_iters=100)
     assert r.done and eng.pool.prefix_hits == hits + 4
@@ -330,8 +350,8 @@ def test_masked_insert_touches_the_trash_page_alone(rig):
         r = np.asarray(rows[key])  # (..., 1, n_ctx, H, D)
         r = np.roll(np.take(r, 0, axis=r.ndim - 4), -pad, axis=r.ndim - 4)
         lead = r.shape[: r.ndim - 3]
-        pages = np.moveaxis(
-            r.reshape(lead + (pps, PAGE) + r.shape[-2:]), len(lead), 0
+        pages = np.moveaxis(  # a token's (H, D) is one vector in the pool
+            r.reshape(lead + (pps, PAGE, -1)), len(lead), 0
         )
         np.testing.assert_array_equal(after[key][written], pages[mask])
 
@@ -357,6 +377,9 @@ def test_export_import_round_trips_a_page_set(rig):
     for j, pid in enumerate(pids):
         got = eng._read_page_host(pid)
         for leaf_key, pages in pset.pages.items():
+            # The set's format is the row's: (page_size, H, D) a page,
+            # whatever the pool's leaf ends in.
+            assert pages.shape[-3:] == (PAGE, 4, 32)
             np.testing.assert_array_equal(got[leaf_key], pages[j])
     np.testing.assert_array_equal(h.result(), _solo(model, params, prompt, 5))
     assert eng._prefill_calls == prefills
@@ -364,3 +387,189 @@ def test_export_import_round_trips_a_page_set(rig):
         t for t in h.trace if t["phase"] == "admitted"
     )["prefilled"] == "ship"
     assert eng.compile_stats() == base
+
+
+# ------------------------- the pool's shape, and what the chip makes of it
+CELLS = {"gpt2": "serve-medium-chat", "xing4": "serve-xing4-reason"}
+
+
+def _param_shapes(model):
+    """Shapes in the weights' place: enough for an engine that is traced
+    and lowered, never run."""
+    return jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+
+
+def _cell_engine(family, **serve):
+    """The cell's engine at its own widths, slots and pool, cut to two
+    layers (the layer axis is the pool's major one) and holding shapes
+    for weights: it is traced and lowered, never run."""
+    cell = manifest.load_cell(CELLS[family])
+    cfg, fam = cell["config"], cell["family"]
+    m = dict(cfg["model"], n_layer=2)
+    if "first_k_dense" in m:
+        m["first_k_dense"] = 1  # one dense layer and one routed
+    model = fam.module(m)
+    return ServeEngine(
+        model, _param_shapes(model), buckets=list(cell["traffic"]["buckets"])[:1],
+        **{**cfg["serve"], **serve},
+    )
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a v5e 2x2 that is described, not attached. Asked for
+    inside a fixture: only the worker that runs this file loads the
+    TPU's library."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 -- whatever keeps libtpu from describing it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize(
+    "family,program",
+    [("gpt2", "decode"), ("gpt2", "insert"), ("gpt2", "verify"),
+     ("xing4", "decode"), ("xing4", "insert")],
+)
+def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
+    one_chip, family, program
+):
+    """Compiled for the chip at the cell's pool shape
+    (``f32[2,897,16,1024]`` twice, ``bf16[2,8193,16,640]``): the pool
+    enters in row-major order, pages major, and no ``copy`` in the
+    program has an operand of its size."""
+    eng = _cell_engine(family, **({"speculative": 2} if program == "verify" else {}))
+    pool = [leaf for _, leaf in eng._cache_leaf_items(eng._cache)]
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    if program == "decode":
+        lowered = eng._decode.lower(
+            described(eng.params), described(eng._cache),
+            *described(eng._decode_warm_args(*eng.decode_shapes[0])),
+        )
+    elif program == "verify":
+        lowered = eng._verify.lower(
+            described(eng.params), described(eng._cache),
+            *described((
+                jnp.asarray(eng._page_table), eng._tok,
+                jnp.zeros((eng.max_slots, eng.spec_draft), jnp.int32),
+                eng._lengths, eng._pads, eng._remaining, eng._live, eng._eos,
+            )),
+        )
+    else:
+        lowered = eng._insert.lower(
+            described(eng._cache), described(eng._row_template()),
+            *described(eng._insert_warm_args()),
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    entry = re.search(r"entry_computation_layout=\{(.*)\}\n", text).group(1)
+    for leaf in pool:
+        assert leaf.shape[-1] % paged_pool.LANES == 0
+        dims = ",".join(map(str, leaf.shape))
+        layouts = set(re.findall(r"\[" + dims + r"\]\{([\d,]*)", entry))
+        major_to_minor = ",".join(str(i) for i in reversed(range(leaf.ndim)))
+        assert layouts == {major_to_minor}, (
+            f"the chip lays {leaf.dtype}[{dims}] out as {layouts}: not pages "
+            "major, so every program that indexes it copies it"
+        )
+    n_pool = min(math.prod(leaf.shape) for leaf in pool)
+    copies = [
+        line.strip()[:200]
+        for line in text.splitlines()
+        if (m := re.match(r"\s*(?:ROOT )?%?copy[\w.\-]* = \w+\[([\d,]+)\]", line))
+        and math.prod(map(int, m.group(1).split(","))) >= n_pool
+    ]
+    assert not copies, copies
+    # A copy of the pool under any other name would need room for it
+    # (the verify block reads every slot's whole row: its gathers alone
+    # are the size of this two-layer pool).
+    if program != "verify":
+        pool_bytes = min(leaf.size * leaf.dtype.itemsize for leaf in pool)
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize(
+    "family,layout",
+    [("gpt2", "blocks"), ("gpt2", "scan"), ("gpt2-96", "blocks"), ("xing4", "scan")],
+)
+def test_every_paged_leaf_ends_in_whole_lane_rows(family, layout):
+    """On the CPU, the model asked alone: a pool leaf is ``(..., pages,
+    page_size, width)``, width what a token holds rounded up to whole
+    128-lane rows (3 heads of 32 pad 96 to 128; a latent of 20, 576 at
+    the cell's size, pads to 128, 640 there)."""
+    if family == "xing4":
+        fam = manifest.load_family("xing4")
+        m = fam.test_config()["model"]
+        model, held = fam.module(m), m["kv_lora_rank"] + m["qk_rope_head_dim"]
+    else:
+        heads = 3 if family == "gpt2-96" else 4
+        model = GPT2(GPT2Config.small_test(
+            n_ctx=32, n_head=heads, n_embd=32 * heads, dropout=0.0,
+            scan_layers=layout == "scan",
+        ))
+        held = 32 * heads
+    eng = ServeEngine(
+        model, _param_shapes(model), max_slots=2, buckets=[16], page_size=PAGE,
+        n_pages=9,
+    )
+    leaves = eng._cache_leaf_items(eng._cache)
+    assert leaves
+    for key, leaf in leaves:
+        assert leaf.shape[eng._page_axis[key]:] == (
+            9, PAGE, paged_pool.token_width(held)
+        ), key
+        assert leaf.shape[-1] % 128 == 0 and leaf.shape[-1] - held < 128
+    widths = eng.ledger.pool_token_widths
+    assert set(widths) == {key for key, _ in leaves}
+    assert all(w == (held, paged_pool.token_width(held)) for w in widths.values())
+    assert eng.ledger.snapshot()["pool_pad_fraction"] == pytest.approx(
+        1 - held / paged_pool.token_width(held)
+    )
+
+
+def test_a_width_that_pads_serves_the_same_tokens():
+    """3 heads of 32: a token's 96 numbers lie in 128 lanes of the pool.
+    Engine tokens equal solo ``generate()``, garbage in the pad lanes
+    changes nothing (they are sliced off the gathered rows), and an
+    exported page has the row's tail, (page_size, H, D)."""
+    model = GPT2(GPT2Config.small_test(
+        n_ctx=32, n_head=3, n_embd=96, dropout=0.0, scan_layers=True
+    ))
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ServeEngine(
+        model, params, max_slots=2, buckets=[16], decode_block=4, page_size=PAGE
+    )
+    assert eng.ledger.pool_pad_fraction == pytest.approx(0.25)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, 512, size=n).astype(np.int32) for n in (11, 5)]
+    reqs = [eng.submit(p, max_new_tokens=7) for p in prompts]
+    eng.step()  # both admitted: their prompts' pages are in the pool
+    assert all(r.tokens for r in reqs)
+    eng._cache = jax.tree_util.tree_map(
+        lambda leaf: leaf.at[..., 96:].set(1e9) if leaf.ndim == 4 else leaf,
+        eng._cache,
+    )
+    eng.run_until_idle(max_iters=100)
+    for p, r in zip(prompts, reqs):
+        np.testing.assert_array_equal(r.result(), _solo(model, params, p, 7))
+    pset = eng.prefill_export(prompts[0])
+    assert {a.shape for a in pset.pages.values()} == {(2, 2, PAGE, 3, 32)}
+    pid = eng.pool._hash_to_page[eng.pool.prefix_digests(prompts[0])[0]]
+    for key, page in eng._read_page_host(pid).items():
+        np.testing.assert_array_equal(page, pset.pages[key][0])

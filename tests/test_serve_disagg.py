@@ -24,6 +24,8 @@ slow-marked below.
 """
 
 import json as _json
+import os
+import shutil
 import threading
 import time
 import urllib.error as _uerr
@@ -161,6 +163,35 @@ def test_ship_roundtrip_bit_equal_zero_decode_prefill(shipping):
     assert _admitted(h)["prefilled"] == "ship"
     assert pf.compile_stats() == pf_base
     assert dc.compile_stats() == dc_base
+
+
+def test_a_set_shipped_by_an_older_build_imports_and_a_new_ship_equals_it(
+    shipping,
+):
+    """Peers of two builds still talk (ISSUE 35): a set that PR 34's
+    build shipped, pages ``(k, ..., page_size, H, D)`` out of a pool
+    whose leaves ended so, admits its prompt on this build's decode
+    engine, whose pool keeps a token's heads as one vector, with no
+    prefill and solo ``generate()``'s tokens; and what this build's
+    prefill engine ships for the same prompt is the same bytes."""
+    (model, params), (pf, dc) = shipping
+    name = "gpt2-scan" if model.config.scan_layers else "gpt2-blocks"
+    src = os.path.join(os.path.dirname(__file__), "data", "kv_sets_pr34", name)
+    for f in os.listdir(src):  # one set: its blob and its manifest
+        shutil.copy(os.path.join(src, f), dc.kv_store.root)
+    key = "37e7333b3db0156f3617edd75ed3594cd8388fb8"
+    old = dc.kv_store.load(key)
+    assert {a.shape[-3:] for a in old.pages.values()} == {(8, 4, 32)}
+    dc_base, dc_prefills = dc.compile_stats(), dc._prefill_calls
+    h = dc.submit(old.prompt, max_new_tokens=6, kv_key=key)
+    assert _drive(dc, h) == _solo(model, params, old.prompt, 6).tolist()
+    assert dc._prefill_calls == dc_prefills
+    assert _admitted(h)["prefilled"] == "ship"
+    assert dc.compile_stats() == dc_base
+    new = pf.prefill_export(old.prompt)
+    assert new.tok0 == old.tok0 and list(new.pages) == list(old.pages)
+    for leaf, pages in old.pages.items():
+        np.testing.assert_array_equal(new.pages[leaf], pages)
 
 
 def test_ship_suffix_resume_prefills_only_the_suffix(

@@ -105,6 +105,42 @@ def test_the_absorbed_path_equals_the_expanded_path(with_mtp, tokens, forward, p
     assert float(jnp.abs(got[1, lo:] - want[1, lo:]).max()) < TOL
 
 
+@pytest.mark.parametrize("lanes", ["zeros", "garbage"])
+def test_the_paged_decode_equals_the_rows_decode_whatever_the_pad_lanes_hold(
+    with_mtp, tokens, lanes
+):
+    """The pool keeps a token's 20 numbers (576 at the cell's size) in
+    128 lanes (640): prefill 8 through the page table, then 16 single
+    steps, give the logits of the same calls over contiguous rows, also
+    with the pad lanes of every page filled with garbage after the
+    prefill (they are sliced off the gathered rows)."""
+    m, model, params = with_mtp
+    ps, held = 8, m["kv_lora_rank"] + m["qk_rope_head_dim"]
+    paged = model.clone(config=dataclasses.replace(model.config, kv_pages=9, kv_page_size=ps))
+    table = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)  # 32 positions a row
+    call = dict(decode=True, mutable=["cache"])
+    rows_first = jax.jit(lambda p, t: model.apply({"params": p}, t, prefill=True, **call))
+    rows_step = jax.jit(lambda p, c, t: model.apply({"params": p, "cache": c}, t, **call))
+    pool_first = jax.jit(lambda p, t: paged.apply(
+        {"params": p}, t, slot_index=jnp.zeros((2,), jnp.int32), page_table=table, **call))
+    pool_step = jax.jit(lambda p, c, t, at: paged.apply(
+        {"params": p, "cache": c}, t, slot_index=at, page_table=table, **call))
+    want, vs = rows_first(params, tokens[:, :8])
+    got, pvs = pool_first(params, tokens[:, :8])
+    rows, pool = vs["cache"], pvs["cache"]
+    assert rows["latent"].shape[-1] == held
+    assert pool["latent"].shape == (3, 9, ps, 128)
+    assert not np.asarray(pool["latent"][..., held:]).any()  # written as zeros
+    if lanes == "garbage":
+        pool = {**pool, "latent": pool["latent"].at[..., held:].set(3e4)}
+    assert float(jnp.abs(got - want).max()) < TOL
+    for i in range(8, 24):
+        want, vs = rows_step(params, rows, tokens[:, i:i + 1])
+        got, pvs = pool_step(params, pool, tokens[:, i:i + 1], jnp.full((2,), i, jnp.int32))
+        rows, pool = vs["cache"], pvs["cache"]
+        assert float(jnp.abs(got - want).max()) < TOL, i
+
+
 # ------------------------------------------------------------- the router
 def _router_case(case):
     m = _config()
@@ -286,10 +322,13 @@ def served():
 def test_the_cache_is_one_latent_vector_a_token(served):
     eng, m = served["engine"], served["m"]
     shapes = jax.tree_util.tree_map(lambda a: a.shape, eng._cache)
-    assert shapes["latent"] == (3, eng.n_pages, eng.page_size, 16 + 4)
-    # what a token holds of each leaf, asked of the model (the axis that grows with kv_pages)
-    assert eng._token_ranks == {"['latent']": 1, "['cache_index']": None}
+    # 16 + 4 numbers a token, kept in whole 128-lane rows (576 in 640 at the cell's size)
+    assert shapes["latent"] == (3, eng.n_pages, eng.page_size, 128)
     assert m["kv_lora_rank"] + m["qk_rope_head_dim"] == 20
+    assert eng.ledger.pool_token_widths == {"['latent']": (20, 128)}
+    assert eng.ledger.pool_pad_fraction == pytest.approx(108 / 128)
+    # the page axis of each leaf, asked of the model (the axis that grows with kv_pages)
+    assert eng._page_axis == {"['latent']": 1, "['cache_index']": None}
 
 
 def test_served_tokens_equal_the_reference_at_every_position(served):
@@ -348,6 +387,37 @@ def test_export_and_import_move_latent_pages(served, tmp_path):
     dst.run_until_idle()
     assert dst._prefill_calls == 0
     assert h.tokens == served["handles"][2].tokens
+
+
+def test_a_page_set_of_the_older_build_imports_and_a_new_export_equals_it(served, tmp_path):
+    """`tests/data/kv_sets_pr34/xing4` was committed by the build whose
+    pool leaf ended in the bare latent (20 numbers here, 576 at the
+    cell's size). It loads, admits its prompt into the padded pool with
+    no prefill and the reference's tokens follow; what this build
+    exports for the same prompt is the same bytes, pad lanes stripped."""
+    import shutil
+
+    from tpuflow.infer.kv_store import KVStore
+
+    store = str(tmp_path / "store")
+    shutil.copytree(os.path.join(ROOT, "tests", "data", "kv_sets_pr34", "xing4"), store)
+    (key,) = KVStore(store).keys()
+    old = KVStore(store).load(key)
+    assert {a.shape for a in old.pages.values()} == {(3, 3, 16, 20)}
+    eng = ServeEngine(
+        served["model"], served["params"], buckets=[32, 48], kv_store_dir=store,
+        **FAM.test_config()["serve"],
+    )
+    new = eng.prefill_export(old.prompt)
+    assert new.tok0 == old.tok0 and new.digests == old.digests
+    for leaf, pages in old.pages.items():
+        np.testing.assert_array_equal(new.pages[leaf], pages)
+    prefills = eng._prefill_calls
+    h = eng.submit(old.prompt, max_new_tokens=12, kv_key=key)
+    eng.run_until_idle()
+    assert eng._prefill_calls == prefills
+    gaps = FAM.serve_gaps(served["m"], SEED, [(old.prompt, np.asarray(h.tokens, np.int32))])
+    assert gaps["tokens"] == 12 and gaps["widest_gap"] <= TOL
 
 
 # ---------------------------------------------------------------- the kernel

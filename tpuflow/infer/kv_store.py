@@ -134,10 +134,19 @@ class KVPageSet:
     """One request's committed KV pages: the shippable artifact.
 
     ``pages`` maps each cache-leaf key (the engine's flattened pytree
-    path) to a page-major array ``(k, ..., page_size, H, D)`` holding
+    path) to a page-major array ``(k, ..., page_size, *token)`` holding
     the first ``k = ceil(n_tokens / page_size)`` logical pages —
     including the partial tail page (private to the request: decode
-    writes land there). ``digests`` covers only the FULL pages (the
+    writes land there). ``token`` is what a prefill row holds of a
+    token: ``(H, D)`` for GPT-2, the 576-number latent for Xing4. That
+    is the format on disk and on the wire, whatever the pool's leaf
+    looks like: the pool keeps a token as ONE vector padded to whole
+    128-lane rows (``(..., page_size, H * D)``, 640 for the latent),
+    because the chip lays a leaf out page-major only when its minor
+    axis fills whole 128-lane rows, and the engine reshapes and strips
+    the pad lanes when it reads a page out (``ServeEngine.
+    _read_page_host``) and flattens and pads in the insert — so sets
+    of builds on either side of that change (ISSUE 35) load alike. ``digests`` covers only the FULL pages (the
     shareable ones). ``tok0`` is the prefill's first greedy token, so a
     decode-side import of the exact prompt admits with zero prefill."""
 

@@ -69,8 +69,16 @@ Shape of the engine:
   fixed POOL of ``(n_pages, page_size)`` pages plus a per-slot page
   table threaded through the decode block as data
   (``Block._paged_attention``) — the same "state is data, never shape"
-  trick that made slots recompile-free covers page allocation. What
-  paging buys over a ``(max_slots, n_ctx)`` row a slot:
+  trick that made slots recompile-free covers page allocation. A pool
+  leaf is ``(..., n_pages, page_size, width)``: what a token holds (K
+  or V of all heads, a latent vector) as ONE vector, padded to whole
+  128-lane rows (``tpuflow.ops.paged_pool``), because the chip lays a
+  leaf out page-major, as every program here indexes it, only when its
+  minor axis fills whole 128-lane rows (ISSUE 35: with ``(page_size,
+  H, D)`` as the tail every decode block and insert copied the whole
+  pool to another layout and back). Prefill rows and page sets keep the
+  token's own shape; ``serve.pool_pad_fraction`` says what the pad
+  costs. What paging buys over a ``(max_slots, n_ctx)`` row a slot:
 
   * **Admission by token budget.** A request is admitted when its page
     need (``ceil((len + max_new [+ draft slack]) / page_size)``) fits
@@ -156,6 +164,7 @@ import collections
 import dataclasses
 import functools
 import hashlib
+import math
 import os
 import threading
 import time
@@ -177,6 +186,7 @@ from tpuflow.infer.generate import (
 )
 from tpuflow.infer import kv_store as _kvstore
 from tpuflow.infer.speculative import ngram_draft
+from tpuflow.ops import paged_pool as _pool
 from tpuflow.utils import knobs
 
 
@@ -941,50 +951,63 @@ class ServeEngine:
     # ------------------------------------------------------- jitted programs
     def _init_cache(self):
         """Zeroed KV cache with the decode model's exact cache pytree
-        (eval_shape — no compile, no garbage forward): the (n_pages,
-        page_size) pool. Also ``self._token_ranks``: per leaf (by
-        ``keystr`` of its path, which a prefill row's leaves share), how
-        many trailing axes one token holds of a pool leaf ``(...,
-        n_pages, page_size, *token)`` — 2 for K or V of (H, D), 1 for a
-        latent vector, None for a leaf the pool does not hold by pages
-        (the index scalars). The page axis is the one that grows with
-        ``kv_pages``: asked of the model, never guessed from sizes."""
+        (eval_shape — no compile, no garbage forward): the pool, each
+        leaf ``(..., n_pages, page_size, width)`` with ``width`` what a
+        token holds, flattened and padded to whole 128-lane rows
+        (``ops/paged_pool.py``: the chip lays a leaf out page-major, as
+        every program here indexes it, only when its minor axis fills
+        whole 128-lane rows). Also, per leaf (by ``keystr`` of its path,
+        which a prefill row's leaves share), ``self._page_axis``: the
+        axis that counts pages in the pool and is the slot axis of a row
+        ``(..., 1, n_ctx, *token)``, None for a leaf the pool does not
+        hold by pages (the index scalars); and ``self._token_shape``:
+        what a token holds there as a row shapes it (the page set's
+        format). Both are asked of the model, never guessed from sizes:
+        the page axis is the first one in which the model's pool and the
+        model's prefill row differ. The ledger is told once how many
+        numbers a token holds and what they are padded to
+        (``serve.pool_pad_fraction``)."""
 
-        def shapes_at(n_pages):
-            model = self._pmodel.clone(
-                config=dataclasses.replace(
-                    self._pmodel.config, kv_pages=n_pages
-                )
+        def mk(params):
+            _, variables = self._pmodel.apply(
+                {"params": params},
+                jnp.zeros((self.max_slots, 1), jnp.int32),
+                decode=True,
+                mutable=["cache"],
+                slot_index=jnp.zeros((self.max_slots,), jnp.int32),
+                page_table=jnp.zeros(
+                    (self.max_slots, self.pages_per_slot), jnp.int32
+                ),
             )
+            return variables["cache"]
 
-            def mk(params):
-                _, variables = model.apply(
-                    {"params": params},
-                    jnp.zeros((self.max_slots, 1), jnp.int32),
-                    decode=True,
-                    mutable=["cache"],
-                    slot_index=jnp.zeros((self.max_slots,), jnp.int32),
-                    page_table=jnp.zeros(
-                        (self.max_slots, self.pages_per_slot), jnp.int32
-                    ),
-                )
-                return variables["cache"]
-
-            return jax.eval_shape(mk, self.params)
-
-        shapes = shapes_at(self.n_pages)
-        self._token_ranks = {}
-        for (path, s), more in zip(
+        shapes = jax.eval_shape(mk, self.params)
+        self._page_axis, self._token_shape, widths = {}, {}, {}
+        for (path, pool), row in zip(
             jax.tree_util.tree_flatten_with_path(shapes)[0],
-            jax.tree_util.tree_leaves(shapes_at(self.n_pages + 1)),
+            jax.tree_util.tree_leaves(self._row_template()),
         ):
-            grew = [
-                i for i, (a, b) in enumerate(zip(s.shape, more.shape))
-                if a != b
-            ]
-            self._token_ranks[jax.tree_util.keystr(path)] = (
-                s.ndim - grew[0] - 2 if grew else None
+            key = jax.tree_util.keystr(path)
+            axis = next(
+                (i for i, (a, b) in enumerate(zip(pool.shape, row.shape))
+                 if a != b),
+                None,
             )
+            self._page_axis[key] = axis
+            if axis is None:
+                continue
+            if axis != pool.ndim - 3 or pool.shape[axis] != self.n_pages:
+                raise ValueError(
+                    f"cache leaf {key} {pool.shape}: a pool leaf is (..., "
+                    "kv_pages, kv_page_size, width), one vector a token "
+                    "(ops/paged_pool.py)"
+                )
+            self._token_shape[key] = row.shape[axis + 2:]
+            widths[key] = (math.prod(row.shape[axis + 2:]), pool.shape[-1])
+        self.ledger.pool_token_widths = widths
+        obs.gauge(
+            "serve.pool_pad_fraction", round(self.ledger.pool_pad_fraction, 4)
+        )
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes
         )
@@ -1015,25 +1038,30 @@ class ServeEngine:
 
         The pool is updated in place by index, as the decode program
         does it (``Block._paged_attention``): a leaf
-        ``(..., n_pages, page_size, *what a token holds)`` — K or V of
-        (H, D), a latent vector, one block's pool or the layer-stacked
-        one — is flattened over its leading axes to
-        ``(layers * n_pages, page_size, ...)`` and takes ONE scatter of
-        the row's ``layers * pages_per_slot`` pages at
-        ``layer * n_pages + page``. Nothing is read back from the pool
-        and no other page is touched."""
+        ``(..., n_pages, page_size, width)`` — one block's pool or the
+        layer-stacked one; ``width`` is what a token holds (K or V of
+        (H, D), a latent vector) flattened and padded to whole 128-lane
+        rows, the shape the chip keeps page-major — is flattened over
+        its leading axes to ``(layers * n_pages, page_size, width)`` and
+        takes ONE scatter of the row's ``layers * pages_per_slot`` pages
+        at ``layer * n_pages + page``. The row ``(..., 1, n_ctx,
+        *token)`` is flattened (and zero-padded, where the pool is) to
+        that width first: row-sized work. Nothing is read back from the
+        pool and no other page is touched."""
         idx = jnp.where(write_mask, table_row, 0)
 
         def put(path, pool, row):
-            token = self._token_ranks[jax.tree_util.keystr(path)]
-            if token is None:
+            if self._page_axis[jax.tree_util.keystr(path)] is None:
                 return pool  # scalar index leaves pass through
-            tail = pool.shape[pool.ndim - token - 1:]  # (page_size, ...)
-            rows = row.reshape((-1, self.n_ctx) + tail[1:])  # (layers, n_ctx, ...)
-            pages = jnp.roll(rows, -pad, axis=1).reshape(
+            tail = pool.shape[-2:]  # (page_size, width)
+            layers = math.prod(pool.shape[:-3])
+            rows = jnp.roll(
+                row.reshape(layers, self.n_ctx, -1), -pad, axis=1
+            ).astype(pool.dtype)
+            pages = _pool.pad_lanes(rows, tail[1]).reshape(
                 (-1,) + tail
-            ).astype(pool.dtype)  # (layers * pages_per_slot, ps, ...)
-            first_page = jnp.arange(rows.shape[0]) * self.n_pages
+            )  # (layers * pages_per_slot, page_size, width)
+            first_page = jnp.arange(layers) * self.n_pages
             at = (first_page[:, None] + idx[None, :]).reshape(-1)
             return pool.reshape((-1,) + tail).at[at].set(pages).reshape(
                 pool.shape
@@ -1341,28 +1369,28 @@ class ServeEngine:
     def _cache_leaf_items(self, tree):
         """``(path-key, leaf)`` for every leaf of ``tree`` (the pool, or
         a prefill row of the same structure) that the pool holds by
-        pages: ``(..., pages_or_slot, tokens, *token)``, in canonical
-        flatten order — the shared leaf naming that page bundles,
-        shipped sets, and the tier store all key on. How many axes a
-        token holds of each: ``self._token_ranks[key]``."""
+        pages, in canonical flatten order — the shared leaf naming that
+        page bundles, shipped sets, and the tier store all key on.
+        ``self._page_axis[key]`` is the page axis of the pool leaf and
+        the slot axis of the row leaf."""
         out = []
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
             key = jax.tree_util.keystr(path)
-            if self._token_ranks.get(key) is not None:
+            if self._page_axis.get(key) is not None:
                 out.append((key, leaf))
         return out
 
     def _read_page_host(self, pid: int) -> dict[str, np.ndarray]:
         """Pool page ``pid`` as a host-side per-leaf bundle ``(...,
-        page_size, *token)`` — the spill/promotion unit. Eager gathers:
-        no named program, so ``compile_stats()`` never sees this."""
+        page_size, *token)`` — the spill/promotion unit, in the page
+        set's format (a prefill row's token shape: the pool's flattened,
+        padded vector is reshaped and stripped here, on one page). Eager
+        gathers: no named program, so ``compile_stats()`` never sees
+        this."""
         out = {}
         for key, leaf in self._cache_leaf_items(self._cache):
-            out[key] = np.asarray(
-                jnp.take(
-                    leaf, pid, axis=leaf.ndim - self._token_ranks[key] - 2
-                )
-            )
+            page = np.asarray(jnp.take(leaf, pid, axis=self._page_axis[key]))
+            out[key] = _pool.strip_lanes(page, self._token_shape[key])
         return out
 
     def _row_template(self):
@@ -1402,12 +1430,11 @@ class ServeEngine:
             for path, row in jax.tree_util.tree_flatten_with_path(rows)[0]
         )
         for key, _ in self._cache_leaf_items(tmpl):
-            token = self._token_ranks[key]
+            lead = (slice(None),) * self._page_axis[key]
             for j, bundle in pages.items():
                 page = bundle.get(key)
                 if page is not None:
-                    at = (Ellipsis, 0, slice(j * ps, (j + 1) * ps))
-                    flat[key][at + (slice(None),) * token] = page
+                    flat[key][lead + (0, slice(j * ps, (j + 1) * ps))] = page
         return rows
 
     def _restore_pages(
@@ -1469,15 +1496,14 @@ class ServeEngine:
         k_ship = -(-L // ps)
         pages: dict[str, np.ndarray] = {}
         for key, leaf in self._cache_leaf_items(row_cache):
-            token = self._token_ranks[key]
+            axis = self._page_axis[key]
             row = np.asarray(leaf)  # (..., 1, n_ctx, *token)
-            shifted = np.roll(row, -(W - L), axis=row.ndim - token - 1)
-            sq = np.take(shifted, 0, axis=row.ndim - token - 2)
-            lead = sq.shape[: sq.ndim - token - 1]
+            sq = np.take(np.roll(row, -(W - L), axis=axis + 1), 0, axis=axis)
             paged = sq.reshape(
-                lead + (self.pages_per_slot, ps) + sq.shape[sq.ndim - token:]
+                sq.shape[:axis] + (self.pages_per_slot, ps)
+                + sq.shape[axis + 1:]
             )
-            paged = np.moveaxis(paged, len(lead), 0)
+            paged = np.moveaxis(paged, axis, 0)
             pages[key] = np.ascontiguousarray(paged[:k_ship])
         return _kvstore.KVPageSet(
             page_size=ps,

@@ -16,7 +16,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tpuflow.ops import attention
+from tpuflow.ops import attention, paged_pool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,7 +92,7 @@ class GPT2Config:
     # ISSUE 11). kv_pages > 0 switches slot-mode decode calls that pass
     # a ``page_table`` to a POOLED cache: instead of one contiguous
     # (B, n_ctx, H, D) row per slot, the cache is a fixed
-    # (kv_pages, kv_page_size, H, D) pool and each slot's logical row is
+    # (kv_pages, kv_page_size, H * D) pool and each slot's logical row is
     # scattered across the pages its (B, up to n_ctx/kv_page_size) table
     # names. kv_page_size must divide n_ctx. Page 0 is the engine's
     # TRASH page: out-of-range writes and dead slots (zeroed tables)
@@ -315,10 +315,16 @@ class Block(nn.Module):
         """Paged (block-pooled) KV-cache attention — the serving engine's
         per-row cache positions over a page pool (ISSUE 11).
 
-        The cache is ONE (kv_pages, kv_page_size, H, D) pool per layer,
-        shared by every slot; ``page_table`` (B, W) int32, W at most
-        ``n_ctx / page_size``, maps the first ``W * page_size`` logical
-        cache columns of each row onto pool pages, and is threaded
+        The cache is ONE (kv_pages, kv_page_size, H * D) pool per layer,
+        shared by every slot: a token's K (or V) is one vector of all its
+        heads, because the chip lays a leaf out page-major, as every
+        program here indexes it, only when its minor axis fills whole
+        128-lane rows (``ops/paged_pool.py``; with (H, D) = (16, 64) as
+        the tail the page axis went to the lanes and every decode block
+        and insert copied the pool there and back). ``page_table`` (B, W)
+        int32, W at most ``n_ctx / page_size``, maps the first ``W *
+        page_size`` logical cache columns of each row onto pool pages, and
+        is threaded
         through the decode program as DATA — admissions, evictions and
         prefix-page sharing never change a shape, so the engine's
         never-recompile contract extends to page management. The
@@ -329,13 +335,13 @@ class Block(nn.Module):
 
         Threading: the pool is one buffer that is only ever indexed into,
         never sliced, restacked or copied. Without a layer scan each
-        block owns its (kv_pages, page_size, H, D) leaf. Under
+        block owns its (kv_pages, page_size, H * D) leaf. Under
         ``scan_layers`` the leaf is the whole (n_layer, kv_pages,
-        page_size, H, D) stack, CARRIED through the layer loop
+        page_size, H * D) stack, CARRIED through the layer loop
         (``GPT2.__call__``: ``variable_carry``), and ``layer`` — this
         iteration's index, the loop's scanned input — offsets every
         index into the stack flattened over (layer, page): a step
-        writes B*T rows of (H, D) per layer for K and for V (16 x 4 KB
+        writes B*T rows of H * D per layer for K and for V (16 x 4 KB
         at the serving cell's size), whatever the pool holds.
 
         Writes: row b's T new k/v land at logical columns
@@ -349,9 +355,10 @@ class Block(nn.Module):
         reads — so a page freed and re-allocated to a new request can
         never be corrupted by its old slot's frozen garbage write.
 
-        Reads: each row gathers its logical (W * page_size, H, D) view
+        Reads: each row gathers its logical (W * page_size, H * D) view
         through its table (pages ``layer * kv_pages + table[b]``, one
-        gather) and runs masked attention over it — columns
+        gather; the gathered rows, not the pool, are reshaped to (H, D))
+        and runs masked attention over it — columns
         ``[pad_lens[b], slot_index[b] + t]`` only.
         Masked columns may be backed by the trash page or a stale page:
         their scores are the -1e30 constant either way, so the gathered
@@ -367,46 +374,25 @@ class Block(nn.Module):
         cdt = cfg.kv_cache_dtype()
         stack = () if layer is None else (cfg.n_layer,)
         first_page = 0 if layer is None else layer * n_pages
-        ck = self.variable(
-            "cache", "cached_key", jnp.zeros, stack + (n_pages, ps, H, D),
-            cdt,
-        )
-        cv = self.variable(
-            "cache", "cached_value", jnp.zeros, stack + (n_pages, ps, H, D),
-            cdt,
-        )
+        leaf = stack + (n_pages, ps, paged_pool.token_width(H * D))
+        ck = self.variable("cache", "cached_key", jnp.zeros, leaf, cdt)
+        cv = self.variable("cache", "cached_value", jnp.zeros, leaf, cdt)
         # Created (never read/advanced) so the paged cache pytree keeps
         # the structure of a row cache — the engine's page-insert
         # tree_maps the two together.
         self.variable(
             "cache", "cache_index", lambda: jnp.zeros(stack, jnp.int32)
         )
-        pos = slot_index[:, None] + jnp.arange(T)[None, :]  # (B, T) logical
-        page = jnp.take_along_axis(
-            page_table, jnp.clip(pos // ps, 0, page_table.shape[1] - 1),
-            axis=1,
+        pos, flat = paged_pool.token_slots(
+            page_table, slot_index, T, ps, first_page
         )
-        flat = first_page * ps + jnp.where(
-            pos < width, page * ps + pos % ps, 0
-        )
-
-        def scatter(pool, new):
-            body = pool.reshape(-1, H, D)
-            body = body.at[flat.reshape(-1)].set(
-                new.astype(cdt).reshape(B * T, H, D)
-            )
-            return body.reshape(pool.shape)
-
-        def gather(pool):
-            pages = pool.reshape(-1, ps, H, D)[first_page + page_table]
-            return pages.reshape(B, width, H, D)
-
         with jax.named_scope("kv_write"):
-            ck.value = scatter(ck.value, k)
-            cv.value = scatter(cv.value, v)
+            ck.value = paged_pool.write_tokens(ck.value, flat, k)
+            cv.value = paged_pool.write_tokens(cv.value, flat, v)
         with jax.named_scope("kv_read"):
-            k_all = gather(ck.value)
-            v_all = gather(cv.value)
+            pages = first_page + page_table
+            k_all = paged_pool.read_rows(ck.value, pages, (H, D))
+            v_all = paged_pool.read_rows(cv.value, pages, (H, D))
         k_pos = jnp.arange(width)
         valid = k_pos[None, None, None, :] <= pos[:, None, :, None]
         if pad_lens is not None:
@@ -673,7 +659,7 @@ class GPT2(nn.Module):
             call = (x, train, decode, pad_lens, prefill, slot_index,
                     page_table)
             # The paged pool rides the layer loop as its CARRY, whole (a
-            # (n_layer, kv_pages, page_size, H, D) leaf for K and for V),
+            # (n_layer, kv_pages, page_size, H * D) leaf for K and for V),
             # and each iteration indexes into it with its own number, the
             # loop's one scanned input (Block._paged_attention). Scanned
             # in and out by layer instead, every iteration would slice

@@ -21,7 +21,8 @@ softmax in float32; heads joined through ``W_o``. No biases. Rotary on the 64
 interleaved convention), YaRN frequencies (``yarn_inv_freq``); the factor on
 cos and sin is ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
 mscale_all_dim)`` = 1. **The cache holds ``[c_kv after its norm; k_rope after
-rotation]``**, ``kv_lora_rank + qk_rope_head_dim`` numbers a token a layer.
+rotation]``**, ``kv_lora_rank + qk_rope_head_dim`` numbers a token a layer
+(576; the engine's page pool keeps them in 640 lanes, ``_paged``).
 Two attention paths, one result: ``_expanded`` makes ``k_nope, v`` from the
 chunk's latents and attends with full heads (a fresh prefill, a plain
 forward); ``_absorbed`` folds ``W_kvb``'s key half into the query (128 → 512
@@ -76,6 +77,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tpuflow.ops import paged_pool
 from tpuflow.ops.attention import attention
 from tpuflow.ops.grouped_matmul import grouped_dot
 
@@ -289,9 +291,10 @@ class Layer(nn.Module):
     """One layer on the streams ``X`` (B, T, hc_mult, C): latent attention
     and a feed-forward (dense, or routed with a shared expert), each inside
     its hyper-connection. ``cache`` is the whole latent cache, threaded
-    through: a pool (layers, kv_pages, page_size, latent) read and written
-    through ``page_table``, or rows (layers, B, n_ctx, latent) written at
-    ``start``; ``layer`` is this layer's index into it. ``experts`` is
+    through: a pool (layers, kv_pages, page_size, latent padded to whole
+    128-lane rows) read and written through ``page_table``, or rows
+    (layers, B, n_ctx, latent) written at ``start``; ``layer`` is this
+    layer's index into it. ``experts`` is
     (gate_up, down) of every routed layer, which the caller holds."""
 
     config: Xing4Config
@@ -505,24 +508,24 @@ class Layer(nn.Module):
         latent leaf in place of K and V): row b's T new latents land at
         logical columns ``slot_index[b] + t`` through its table, columns
         beyond the table and dead rows in the layer's trash page 0; each
-        row reads the pages its table names and nothing else."""
+        row reads the pages its table names and nothing else. The pool is
+        (layers, kv_pages, page_size, 640): the latent's 576 numbers and
+        64 zero lanes, because the chip lays a leaf out page-major, as it
+        is indexed here, only when its minor axis fills whole 128-lane
+        rows (``ops/paged_pool.py``); the lanes are sliced off the
+        gathered rows, so nothing they hold reaches a product."""
         cfg = self.config
-        b, t = q_nope.shape[:2]
-        ps, n_pages, d = cfg.kv_page_size, cfg.kv_pages, cfg.latent_dim
+        t = q_nope.shape[1]
+        ps, d = cfg.kv_page_size, cfg.latent_dim
         width = page_table.shape[1] * ps
-        first_page = layer * n_pages
-        pos = slot_index[:, None] + jnp.arange(t)[None, :]
-        page = jnp.take_along_axis(
-            page_table, jnp.clip(pos // ps, 0, page_table.shape[1] - 1), axis=1
+        first_page = layer * cfg.kv_pages
+        pos, flat = paged_pool.token_slots(
+            page_table, slot_index, t, ps, first_page
         )
-        flat = first_page * ps + jnp.where(pos < width, page * ps + pos % ps, 0)
         with jax.named_scope("kv_write"):
-            pool = pool.reshape(-1, d).at[flat.reshape(-1)].set(
-                latent.astype(pool.dtype).reshape(b * t, d)
-            ).reshape(pool.shape)
+            pool = paged_pool.write_tokens(pool, flat, latent)
         with jax.named_scope("kv_read"):
-            latents = pool.reshape(-1, ps, d)[first_page + page_table]
-            latents = latents.reshape(b, width, d)
+            latents = paged_pool.read_rows(pool, first_page + page_table, (d,))
         k_pos = jnp.arange(width)
         valid = k_pos[None, None, :] <= pos[:, :, None]
         if pad_lens is not None:
@@ -638,7 +641,8 @@ class Xing4(nn.Module):
         if paged:
             var = self.variable(
                 "cache", "latent", jnp.zeros,
-                (cfg.n_layer, cfg.kv_pages, cfg.kv_page_size, cfg.latent_dim),
+                (cfg.n_layer, cfg.kv_pages, cfg.kv_page_size,
+                 paged_pool.token_width(cfg.latent_dim)),
                 cfg.dtype,
             )
             # Kept beside it so that a pool and a prefill row have one

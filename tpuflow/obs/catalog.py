@@ -414,6 +414,14 @@ CATALOG: dict[str, tuple[str, str]] = {
         "decode_utilization x mean live context / n_ctx it sizes what a "
         "per-row (ragged) read would still save",
     ),
+    "serve.pool_pad_fraction": (
+        "gauge",
+        "share of the page pool's bytes, and of every decode read's, "
+        "that is zero lanes: a pool leaf keeps what a token holds as one "
+        "vector padded to whole 128-lane rows (the shape the chip lays "
+        "out page-major), so 0 for GPT-2's H*D and 0.1 for a 576-number "
+        "latent in 640; set once when the engine allocates the pool",
+    ),
     "serve.masked_row_waste": (
         "gauge",
         "fraction of dispatched batch rows live engine-wide but masked "

@@ -132,6 +132,11 @@ class ServeLedger:
     ):
         self.slo_ttft_s = slo_ttft_s
         self.slo_itl_s = slo_itl_s
+        # The page pool's geometry (set once by the engine; a property of
+        # the model, so no window restarts it): per cache leaf ``(held,
+        # width)``, the numbers a token holds there and the 128-lane-row
+        # width the pool keeps them in.
+        self.pool_token_widths: dict[str, tuple[int, int]] = {}
         self.reset()
 
     def reset(self) -> None:
@@ -297,6 +302,18 @@ class ServeLedger:
         return False
 
     # ----------------------------------------------------------- reports
+    @property
+    def pool_pad_fraction(self) -> float | None:
+        """The share of the pool's bytes (and of every decode read's)
+        that is zero lanes: 0 where a token's numbers fill whole
+        128-lane rows (GPT-2's H * D), 0.1 for a 576-number latent in
+        640. A configuration whose width pads badly shows here."""
+        width = sum(w for _, w in self.pool_token_widths.values())
+        if not width:
+            return None
+        held = sum(h for h, _ in self.pool_token_widths.values())
+        return 1.0 - held / width
+
     def wall_s(self) -> float:
         return time.monotonic() - self._t0
 
@@ -332,6 +349,8 @@ class ServeLedger:
             },
             "decode_utilization": self.decode_utilization,
             "decode_read_fraction": self.decode_read_fraction,
+            "pool_token_widths": dict(self.pool_token_widths),
+            "pool_pad_fraction": self.pool_pad_fraction,
             "model_steps": self._model_steps,
             "step_sum": dict(self.step_sum),
             "step_max": dict(self.step_max),
