@@ -390,7 +390,8 @@ def test_export_import_round_trips_a_page_set(rig):
 
 
 # ------------------------- the pool's shape, and what the chip makes of it
-CELLS = {"gpt2": "serve-medium-chat", "xing4": "serve-xing4-reason"}
+CELLS = {"gpt2": "serve-medium-chat", "xing4": "serve-xing4-reason",
+         "sdar": "serve-sdar-blockgen"}
 
 
 def _param_shapes(model):
@@ -439,15 +440,19 @@ def one_chip():
 @pytest.mark.parametrize(
     "family,program",
     [("gpt2", "decode"), ("gpt2", "insert"), ("gpt2", "verify"),
-     ("xing4", "decode"), ("xing4", "insert")],
+     ("xing4", "decode"), ("xing4", "insert"),
+     ("sdar", "decode"), ("sdar", "insert")],
 )
 def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     one_chip, family, program
 ):
     """Compiled for the chip at the cell's pool shape
-    (``f32[2,897,16,1024]`` twice, ``bf16[2,8193,16,640]``): the pool
-    enters in row-major order, pages major, and no ``copy`` in the
-    program has an operand of its size."""
+    (``f32[2,897,16,1024]`` twice, ``bf16[2,8193,16,640]``,
+    ``bf16[2,8193,16,512]`` twice): the pool enters in row-major order,
+    pages major, and no ``copy`` in the program has an operand of its
+    size. The third family's decode program is the block-diffusion one
+    (``_denoise_fn``): every pass of it, the commit among them, writes a
+    block's keys and values into the pool by index."""
     eng = _cell_engine(family, **({"speculative": 2} if program == "verify" else {}))
     pool = [leaf for _, leaf in eng._cache_leaf_items(eng._cache)]
 
@@ -506,17 +511,23 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
 
 @pytest.mark.parametrize(
     "family,layout",
-    [("gpt2", "blocks"), ("gpt2", "scan"), ("gpt2-96", "blocks"), ("xing4", "scan")],
+    [("gpt2", "blocks"), ("gpt2", "scan"), ("gpt2-96", "blocks"), ("xing4", "scan"),
+     ("sdar", "scan")],
 )
 def test_every_paged_leaf_ends_in_whole_lane_rows(family, layout):
     """On the CPU, the model asked alone: a pool leaf is ``(..., pages,
     page_size, width)``, width what a token holds rounded up to whole
     128-lane rows (3 heads of 32 pad 96 to 128; a latent of 20, 576 at
-    the cell's size, pads to 128, 640 there)."""
+    the cell's size, pads to 128, 640 there; grouped keys and values of
+    32, 512 at the cell's size, pad to 128, not at all there)."""
     if family == "xing4":
         fam = manifest.load_family("xing4")
         m = fam.test_config()["model"]
         model, held = fam.module(m), m["kv_lora_rank"] + m["qk_rope_head_dim"]
+    elif family == "sdar":  # keys and values of 2 groups of 16: 32 in 128
+        fam = manifest.load_family("sdar")
+        m = fam.test_config()["model"]
+        model, held = fam.module(m), m["n_kv_head"] * m["head_dim"]
     else:
         heads = 3 if family == "gpt2-96" else 4
         model = GPT2(GPT2Config.small_test(

@@ -107,6 +107,21 @@ Shape of the engine:
     stay bit-equal to solo ``generate()``. ``submit(speculative=False)``
     opts a request out (it rides the plain single-token block).
 
+- **Generation by diffusion over blocks (ISSUE 37).** ``generation=
+  {"kind": "block_diffusion", ...}`` swaps the decode program for
+  ``_denoise_fn``: a call runs whole blocks of L tokens, each S denoise
+  passes over a row's whole block (a pass unmasks L / S positions, so a
+  row gains 0 to L tokens a pass) and a commit pass that writes the
+  finished block's keys and values. Tokens and forward passes are then
+  two counts: ``decode_block`` is passes a call, ``remaining`` and the
+  harvest count tokens, the ``serve.decode`` span and the ledger carry
+  both (``passes``, ``commit_passes``, ``tokens_per_pass``). Admission
+  caches the prompt's whole blocks and opens the first block with the
+  tokens left over; ``page_size`` is a multiple of L, so prefix pages
+  stay shareable. Speculation, int8, shipped or tiered pages and
+  ``eos_id`` raise ``GenerationUnsupported`` with it. Unset, every
+  program is what it was.
+
 Knobs: ``TPUFLOW_SERVE_SLOTS`` (default 8), ``TPUFLOW_SERVE_PREFILL_CHUNK``
 (default off), ``TPUFLOW_SERVE_BUCKETS`` (comma widths; default a
 power-of-two ladder up to ``n_ctx``), ``TPUFLOW_SERVE_DECODE_BLOCK``
@@ -344,6 +359,65 @@ def resolve_serve_role(role=None) -> str:
             f"role must be prefill|decode|both, got {role!r}"
         )
     return r
+
+
+class GenerationUnsupported(ValueError):
+    """``ServeEngine(generation=...)`` met an option that has no meaning
+    with it yet (speculation, the int8 path, shipped or tiered KV pages,
+    ``eos_id``): raised where the two meet, never served by one-token
+    decode in the option's place."""
+
+
+def resolve_generation(generation, model) -> dict | None:
+    """How the engine generates, validated. None is one-token decode: a
+    step yields one token a live row. ``{"kind": "block_diffusion",
+    "block_length": L, "denoise_steps": S, "unmask": "sequential" |
+    "low_confidence", "mask_id": id}`` generates a block of L tokens at a
+    time: S denoise passes over the whole block, each unmasking L / S of
+    its positions greedily, then a commit pass that writes the finished
+    block's keys and values (``ServeEngine._denoise_fn``). What is left
+    out defaults to the model's (``config.block_length``,
+    ``config.denoise_steps``, ``config.mask_id``); a model whose attention
+    mask is built for another block length is refused."""
+    if generation is None:
+        return None
+    g = dict(generation)
+    kind = g.pop("kind", None)
+    if kind != "block_diffusion":
+        raise ValueError(
+            f"generation kind must be 'block_diffusion', got {kind!r}"
+        )
+    cfg = model.config
+    model_length = getattr(cfg, "block_length", None)
+    out = {
+        "kind": kind,
+        "block_length": int(g.pop("block_length", model_length or 0)),
+        "denoise_steps": int(
+            g.pop("denoise_steps", getattr(cfg, "denoise_steps", 1))
+        ),
+        "unmask": str(g.pop("unmask", "sequential")),
+        "mask_id": int(g.pop("mask_id", getattr(cfg, "mask_id", -1))),
+    }
+    if g:
+        raise ValueError(f"unknown generation option(s) {sorted(g)}")
+    L, S = out["block_length"], out["denoise_steps"]
+    if L < 1 or model_length != L:
+        raise ValueError(
+            f"generation block_length {L} is not the model's "
+            f"({model_length}): its attention mask is block-causal over "
+            "that length"
+        )
+    if S < 1 or L % S:
+        raise ValueError(
+            f"denoise_steps must divide block_length={L}, got {S}"
+        )
+    if out["unmask"] not in ("sequential", "low_confidence"):
+        raise ValueError(
+            f"unmask must be sequential|low_confidence, got {out['unmask']!r}"
+        )
+    if not 0 <= out["mask_id"] < cfg.vocab_size:
+        raise ValueError(f"mask_id {out['mask_id']} is not in the vocabulary")
+    return out
 
 
 class PagePool:
@@ -631,6 +705,10 @@ class ServeRequest:
     t_first: float | None = None
     t_done: float | None = None
     tokens: list[int] = dataclasses.field(default_factory=list)
+    # Block diffusion (ISSUE 37): for each of ``tokens``, the denoise pass
+    # of its block (0 .. denoise_steps - 1) that unmasked it. Empty under
+    # one-token decode.
+    token_passes: list[int] = dataclasses.field(default_factory=list)
     state: str = "queued"  # queued | running | done
     finish_reason: str | None = None
     # Serving observatory (ISSUE 13): the request's lifecycle trace
@@ -729,9 +807,11 @@ class ServeEngine:
         kv_store_dir: str | None = None,
         kv_host_mb: float | None = None,
         kv_disk_dir: str | None = None,
+        generation: dict | None = None,
     ):
         self.model = model
         self.params = params
+        self.generation = resolve_generation(generation, model)
         # Per-request int8 (ISSUE 9): quantize ONCE at construction and
         # keep both numeric paths' params resident — requests pick a
         # path at submit, never a recompile. The quantized tree is a
@@ -790,6 +870,25 @@ class ServeEngine:
             raise ValueError(
                 f"decode_block must be >= 1, got {self.decode_block}"
             )
+        # ``decode_block`` counts forward passes a program call. Under
+        # one-token decode that is also the most tokens a row gains a
+        # call; under block diffusion a block of L tokens takes S denoise
+        # passes and a commit pass, a call runs whole blocks, and the
+        # default is the blocks that yield about as many tokens a row as
+        # the one-token default does.
+        self._advance = self.decode_block  # positions a row can gain a call
+        if self.generation is not None:
+            L = self.generation["block_length"]
+            per_block = self.generation["denoise_steps"] + 1
+            if decode_block is None:
+                self.decode_block = max(self.decode_block // L, 1) * per_block
+            if self.decode_block % per_block:
+                raise ValueError(
+                    f"decode_block={self.decode_block} must be a multiple "
+                    f"of denoise_steps + 1 = {per_block}: a call runs whole "
+                    "blocks"
+                )
+            self._advance = self.decode_block // per_block * L
         self.pad_id = int(pad_id)
         # Serving observatory (ISSUE 13): lifecycle tracing, the
         # engine-time ledger (buckets sum to serve wall by
@@ -838,6 +937,27 @@ class ServeEngine:
         self._qpmodel = None
         self.page_size = resolve_page_size(self.n_ctx, page_size)
         self.pages_per_slot = self.n_ctx // self.page_size
+        if self.generation is not None:
+            for what, on in (
+                ("quant (the int8 decode path)", self.quant_mode is not None),
+                ("speculative decode", bool(self.spec_draft)),
+                ("a KV store (shipped page sets)", self.kv_store is not None),
+            ):
+                if on:
+                    raise GenerationUnsupported(
+                        f"generation={self.generation['kind']!r} with "
+                        f"{what}: not supported yet"
+                    )
+            if self.page_size % self.generation["block_length"]:
+                # A page's contents then depend on tokens up to its own
+                # end alone, which is what `PagePool.prefix_digests` keys
+                # a shared page by; and a request's last block lies
+                # inside the pages its prompt and budget already cover.
+                raise ValueError(
+                    f"page_size={self.page_size} must be a multiple of the "
+                    f"generation's block_length="
+                    f"{self.generation['block_length']}"
+                )
         default_pages = S * self.pages_per_slot + 1
         self.n_pages = (
             int(n_pages) if n_pages is not None
@@ -862,6 +982,11 @@ class ServeEngine:
             kv_disk_dir if kv_disk_dir is not None
             else knobs.raw("TPUFLOW_KV_DISK_DIR")
         )
+        if self.generation is not None and (host_mb > 0 or tier_disk):
+            raise GenerationUnsupported(
+                f"generation={self.generation['kind']!r} with a tiered "
+                "prefix cache: not supported yet"
+            )
         if use_prefix and (host_mb > 0 or tier_disk):
             self._tier = _kvstore.TierCache(
                 host_bytes=int(host_mb * 2**20),
@@ -891,7 +1016,13 @@ class ServeEngine:
         )
         self._queue: collections.deque[ServeRequest] = collections.deque()
         self._slots: list[ServeRequest | None] = [None] * S
-        self._tok = np.zeros((S,), np.int32)
+        # Under block diffusion a slot's current block: ids, -1 where the
+        # position is still masked (the engine's own state: a prompt may
+        # hold the mask id).
+        self._tok = (
+            np.zeros((S,), np.int32) if self.generation is None
+            else np.full((S, self.generation["block_length"]), -1, np.int32)
+        )
         self._lengths = np.zeros((S,), np.int32)
         self._pads = np.zeros((S,), np.int32)
         self._remaining = np.zeros((S,), np.int32)
@@ -914,7 +1045,11 @@ class ServeEngine:
         )
         self._insert = jax.jit(self._page_insert_fn, donate_argnums=(0,))
         self._decode = jax.jit(
-            functools.partial(self._decode_fn, self._pmodel),
+            functools.partial(
+                self._decode_fn if self.generation is None
+                else self._denoise_fn,
+                self._pmodel,
+            ),
             donate_argnums=(1,),
         )
         self._verify = None
@@ -1205,6 +1340,138 @@ class ServeEngine:
         )
         return cache, toks.T, tok, lengths, remaining, live, steps
 
+    @jax.named_scope("serve.decode")
+    def _denoise_fn(self, model, params, cache, tok, lengths, pads,
+                    remaining, live, eos, page_table):
+        """The decode program under ``generation`` (block diffusion): a
+        scan over whole blocks, ``decode_block`` forward passes in all,
+        with the operands of ``_decode_fn`` except that ``tok`` (R, L)
+        holds each row's current block, -1 where a position is masked.
+        ``lengths`` is the block's first column, a multiple of L: every
+        row is at a block's start when a call begins and ends.
+
+        A block is S denoise passes and one commit pass, every row in
+        step. A pass runs the model over the rows' whole blocks (a masked
+        position's input is ``mask_id``), which writes the block's keys and
+        values at its own columns and attends every earlier column and the
+        block itself, in both directions. A denoise pass then unmasks, in
+        each live row, up to L / S of the *eligible* positions, the masked
+        ones inside the row's budget, each with the argmax at its
+        position: ``sequential`` takes the leftmost, ``low_confidence``
+        those whose largest softmax probability is highest. A row whose
+        budget ends inside a block dies there: the block's other positions
+        are never emitted and the block is never committed. The commit
+        pass runs the finished block once more and computes no head: a
+        position's keys and values depend on the other tokens of its
+        block, so what a denoise pass wrote was of a block still partly
+        masked, and what this pass writes is what later blocks read.
+
+        Returns ``_decode_fn``'s tuple and one more: the call's new tokens
+        a row, left-aligned in position order (R, blocks x L), the carries,
+        the model's sown counts over all passes, and for each new token
+        the denoise pass (0 .. S - 1) that unmasked it. Tokens a row is
+        the ``remaining`` delta, as for the verify block."""
+        gen = self.generation
+        L, S = gen["block_length"], gen["denoise_steps"]
+        per_pass = L // S
+        n_ctx, pad_id = self.n_ctx, self.pad_id
+        del eos  # submit() refuses eos_id under generation
+
+        def forward(cache, tok, lengths, head):
+            logits, variables = model.apply(
+                {"params": params, "cache": cache},
+                jnp.where(tok < 0, gen["mask_id"], tok),
+                decode=True,
+                mutable=["cache", "step_sum", "step_max"],
+                pad_lens=pads,
+                slot_index=lengths,
+                page_table=page_table,
+                head=head,
+            )
+            sown = (
+                dict(variables.get("step_sum", {})),
+                dict(variables.get("step_max", {})),
+            )
+            return logits, variables["cache"], sown
+
+        def unmask(logits, tok, when, remaining, live, s):
+            best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            masked = tok < 0
+            eligible = (
+                masked
+                & (jnp.cumsum(masked, axis=1) <= remaining[:, None])
+                & live[:, None]
+            )
+            if gen["unmask"] == "sequential":
+                chosen = eligible & (jnp.cumsum(eligible, axis=1) <= per_pass)
+            else:
+                confidence = jnp.exp(
+                    jnp.max(logits, axis=-1) - jax.nn.logsumexp(logits, axis=-1)
+                )
+                _, at = jax.lax.top_k(
+                    jnp.where(eligible, confidence, -1.0), per_pass
+                )
+                chosen = eligible & jnp.any(
+                    jnp.arange(L)[None, None, :] == at[:, :, None], axis=1
+                )
+            remaining = remaining - jnp.sum(chosen, axis=1)
+            return (
+                jnp.where(chosen, best, tok), jnp.where(chosen, s, when),
+                remaining, live & (remaining > 0),
+            )
+
+        def block(carry, _):
+            cache, tok, lengths, remaining, live = carry
+            given = tok >= 0  # a first block's prompt tokens
+
+            def denoise(carry, s):
+                cache, *state = carry
+                logits, cache, sown = forward(cache, state[0], lengths, True)
+                with jax.named_scope("unmask"):
+                    state = unmask(logits, *state, s)
+                return (cache, *state), sown
+
+            with jax.named_scope("denoise"):
+                (cache, tok, when, remaining, live), sown = jax.lax.scan(
+                    denoise,
+                    (cache, tok, jnp.full(tok.shape, -1, jnp.int32),
+                     remaining, live),
+                    jnp.arange(S),
+                )
+            with jax.named_scope("denoise.commit"):
+                _, cache, committed = forward(cache, tok, lengths, False)
+            new = (tok >= 0) & ~given
+            # A live row's block is full (its budget never bound): it
+            # moves on to an all-masked block, if one fits.
+            lengths = jnp.where(live, lengths + L, lengths)
+            out = (jnp.where(new, tok, pad_id), jnp.where(new, when, -1), new)
+            tok = jnp.where(live[:, None], -1, tok)
+            live = live & (lengths + L <= n_ctx)
+            sown = jax.tree_util.tree_map(
+                lambda a, b: jnp.concatenate([a, b[None]]), sown, committed
+            )
+            return (cache, tok, lengths, remaining, live), (out, sown)
+
+        carry, ((toks, when, new), sown) = jax.lax.scan(
+            block,
+            (cache, tok, lengths, remaining, live),
+            None,
+            length=self.decode_block // (S + 1),
+        )
+        cache, tok, lengths, remaining, live = carry
+        steps = (
+            jax.tree_util.tree_map(jnp.sum, sown[0]),
+            jax.tree_util.tree_map(jnp.max, sown[1]),
+        )
+        # (blocks, R, L) -> (R, blocks x L), the new tokens first, in
+        # position order.
+        flat = lambda a: jnp.swapaxes(a, 0, 1).reshape(a.shape[1], -1)  # noqa: E731
+        order = jnp.argsort(~flat(new), axis=1, stable=True)
+        toks, when = (
+            jnp.take_along_axis(flat(a), order, axis=1) for a in (toks, when)
+        )
+        return cache, toks, tok, lengths, remaining, live, steps, when
+
     # ------------------------------------------------------------ scheduling
     def bucket_for(self, prompt_len: int, max_new_tokens: int) -> int:
         """Smallest bucket width holding the prompt, where the prompt and
@@ -1260,6 +1527,12 @@ class ServeEngine:
         if max_new_tokens < 1:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}"
+            )
+        if self.generation is not None and eos_id is not None:
+            raise GenerationUnsupported(
+                f"generation={self.generation['kind']!r} with eos_id: a "
+                "block is unmasked out of order, so where a request ends "
+                "is its budget's to say (not supported yet)"
             )
         if quantize and self.quant_mode is None:
             raise ValueError(
@@ -1475,6 +1748,13 @@ class ServeEngine:
         page (private to the request — decode writes land there) and
         the first greedy token, so an exact import admits with zero
         prefill."""
+        if self.generation is not None:
+            raise GenerationUnsupported(
+                f"generation={self.generation['kind']!r} with "
+                "prefill_export / ship: a shipped set carries a first "
+                "token and whole-prompt pages, which a block-wise "
+                "admission has neither of (not supported yet)"
+            )
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("prompt must have at least one token")
@@ -1815,9 +2095,14 @@ class ServeEngine:
                     prm, jnp.asarray(padded), pads, chunk=chunk
                 )
                 first = int(np.asarray(tok0)[0])
-            req.t_first = time.monotonic()
-            req.t_last_tick = req.t_first
-            req.tokens.append(first)
+            if self.generation is None:
+                req.t_first = time.monotonic()
+                req.t_last_tick = req.t_first
+                req.tokens.append(first)
+            else:
+                # Every token comes out of a denoise pass: the prefill
+                # fills the cache and its own argmax is nobody's token.
+                first = None
         req.state = "running"
         extra_trace = {}
         if mode != "prefill" or restored:
@@ -1871,9 +2156,18 @@ class ServeEngine:
         self._lengths[slot] = L if mode != "feed" else L - 1
         self._pads[slot] = 0
         self._slots[slot] = req
-        self._tok[slot] = (
-            first if first is not None else int(req.prompt[L - 1])
-        )
+        if self.generation is None:
+            self._tok[slot] = (
+                first if first is not None else int(req.prompt[L - 1])
+            )
+        else:
+            # The prompt's whole blocks are cached (what the insert wrote
+            # beyond them, the first block rewrites before anything reads
+            # it); the tokens left over open the first block, unmasked.
+            left = L % self.generation["block_length"]
+            self._lengths[slot] = L - left
+            self._tok[slot] = -1
+            self._tok[slot, :left] = req.prompt[L - left:]
         self._remaining[slot] = (
             req.max_new_tokens - 1 if first is not None
             else req.max_new_tokens
@@ -1998,13 +2292,14 @@ class ServeEngine:
         """The operand shapes of one group's decode block, from what the
         host holds: the first of ``decode_shapes`` that holds the
         group's live slots and the frontier the block can reach, the
-        longest live row plus ``decode_block`` positions. Returns the
+        longest live row plus the positions a call can add (``decode_block``
+        tokens, or its whole blocks under ``generation``). Returns the
         slots it runs over — the group's live slots first, then dead
         ones, then (only where those run out) other groups' live ones —
         and the pages it reads of each."""
         live = int(mask.sum())
         reach = min(
-            int(self._lengths[mask].max()) + self.decode_block, self.n_ctx
+            int(self._lengths[mask].max()) + self._advance, self.n_ctx
         )
         n_rows, pages = next(
             (r, w) for r, w in self.decode_shapes
@@ -2088,7 +2383,7 @@ class ServeEngine:
                     decode = self._decode_q if quant else self._decode
                     (
                         self._cache, toks, tok, lengths, remaining, live,
-                        steps,
+                        steps, *when,
                     ) = decode(
                         prm, self._cache, tok, lengths, pads, remaining,
                         live, eos, table,
@@ -2119,15 +2414,30 @@ class ServeEngine:
                     (self.max_slots, toks.shape[1]), self.pad_id, toks.dtype
                 )
                 by_slot[rows] = toks
+                passes_by_slot = None
+                if not spec and when:  # block diffusion: each token's pass
+                    passes_by_slot = np.zeros(by_slot.shape, np.int32)
+                    passes_by_slot[rows] = np.asarray(when[0])
             emitted = int((old_remaining - self._remaining).sum())
-            sp.set(tokens=emitted)
+            # Forward passes of the call, and those of them that computed
+            # no head (a block's commit): tokens and passes are two counts
+            # since a pass may yield none or several.
+            passes = 1 if spec else self.decode_block
+            commits = 0 if self.generation is None else (
+                passes // (self.generation["denoise_steps"] + 1)
+            )
+            sp.set(tokens=emitted, passes=passes, commit_passes=commits)
             self.ledger.note_decode_block(
                 self.max_slots, group_live, total_live, spec=spec,
                 drafted=group_live * self.spec_draft if spec else 0,
                 committed=emitted,
                 read_positions=len(rows) * pages * self.page_size,
                 full_positions=self.max_slots * self.n_ctx,
+                passes=passes, commit_passes=commits,
             )
+            rate = self.ledger.tokens_per_pass
+            if rate is not None:
+                obs.gauge("serve.tokens_per_pass", round(rate, 4))
             if spec:
                 self._spec_committed += emitted
                 self._spec_forwards += group_live
@@ -2138,13 +2448,17 @@ class ServeEngine:
                 )
         with obs.span("serve.harvest"):
             self._harvest(
-                mask, by_slot, old_remaining - self._remaining, spec
+                mask, by_slot, old_remaining - self._remaining, spec,
+                passes_by_slot,
             )
         return emitted
 
-    def _harvest(self, mask, toks, emitted_by_row, spec: bool) -> None:
-        """Hand a block's tokens to their requests, note the per-token
-        latencies, and free the slots of requests that ended."""
+    def _harvest(self, mask, toks, emitted_by_row, spec: bool,
+                 passes=None) -> None:
+        """Hand a block's tokens to their requests (and, under block
+        diffusion, the denoise pass that unmasked each: ``passes``), note
+        the per-token latencies, and free the slots of requests that
+        ended."""
         now = time.monotonic()
         led = obs.goodput_live()
         for s, req in enumerate(self._slots):
@@ -2153,6 +2467,8 @@ class ServeEngine:
             n = int(emitted_by_row[s])
             if n:
                 req.tokens.extend(int(t) for t in toks[s, :n])
+                if passes is not None:
+                    req.token_passes.extend(int(t) for t in passes[s, :n])
                 # One ITL observation per tick (tick wall / tokens
                 # committed): the per-token latency the SLO gate,
                 # /metrics percentiles, and the access log all share.
@@ -2318,7 +2634,8 @@ class ServeEngine:
         page_table."""
         zeros = np.zeros((n_rows,), np.int32)
         return [
-            zeros, zeros, zeros, zeros, np.zeros((n_rows,), bool),
+            np.zeros((n_rows,) + self._tok.shape[1:], np.int32),
+            zeros, zeros, zeros, np.zeros((n_rows,), bool),
             np.full((n_rows,), -1, np.int32),
             np.zeros((n_rows, pages), np.int32),
         ]

@@ -126,6 +126,7 @@ REQUIRED_EMITTERS: tuple[tuple[str, str], ...] = (
     ("gauge", "serve.prefill_fraction"),
     ("gauge", "serve.decode_utilization"),
     ("gauge", "serve.decode_read_fraction"),
+    ("gauge", "serve.tokens_per_pass"),
     ("gauge", "serve.pool_pad_fraction"),
     ("gauge", "serve.masked_row_waste"),
     # Disaggregated prefill/decode + tiered KV (ISSUE 19): the ship /

@@ -41,7 +41,11 @@ def get_model(name: str, **kwargs):
             ).items():
                 kwargs.setdefault(k, v)
         return ViT(**kwargs)
+    if name in ("sdar", "sdar_moe"):
+        from tpuflow.models.sdar import Sdar
+
+        return Sdar(**kwargs)
     raise KeyError(
         f"unknown model {name!r}; available: mlp, resnet18, resnet50, "
-        "gpt2, gpt2_medium, vit, vit_tiny, vit_small"
+        "gpt2, gpt2_medium, vit, vit_tiny, vit_small, sdar"
     )

@@ -127,6 +127,7 @@ def _fmt_lat(p: dict | None) -> str:
 _LEDGER_EFFICIENCY = (
     "serve.decode_utilization",
     "serve.decode_read_fraction",
+    "serve.tokens_per_pass",
     "serve.masked_row_waste",
 )
 

@@ -414,6 +414,13 @@ CATALOG: dict[str, tuple[str, str]] = {
         "decode_utilization x mean live context / n_ctx it sizes what a "
         "per-row (ragged) read would still save",
     ),
+    "serve.tokens_per_pass": (
+        "gauge",
+        "tokens emitted over live rows x forward passes of the decode "
+        "calls so far: 1.0 under one-token decode, block_length / "
+        "(denoise_steps + 1) under block diffusion, where a third of the "
+        "passes (at 2 denoise steps) are commits that yield none",
+    ),
     "serve.pool_pad_fraction": (
         "gauge",
         "share of the page pool's bytes, and of every decode read's, "

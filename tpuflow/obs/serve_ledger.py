@@ -153,6 +153,12 @@ class ServeLedger:
         self._block_masked = 0     # rows live overall but masked out
         self._read_positions = 0   # cache positions the blocks gathered
         self._full_positions = 0   # what max_slots x n_ctx reads take
+        # Forward passes and the tokens they yielded (two counts since a
+        # pass of a block-diffusion engine yields none or several a row).
+        self.denoise_passes = 0    # passes that computed a head
+        self.commit_passes = 0     # passes that only wrote keys and values
+        self._pass_rows = 0        # live rows x passes, all calls
+        self._pass_tokens = 0      # tokens those passes emitted
         # What the model sowed of its decode steps (``step_sum`` /
         # ``step_max`` collections: a routed model's experts touched).
         self._model_steps = 0
@@ -195,6 +201,8 @@ class ServeLedger:
         committed: int = 0,
         read_positions: int = 0,
         full_positions: int = 0,
+        passes: int = 0,
+        commit_passes: int = 0,
     ) -> None:
         """One group's decode/verify dispatch: ``batch_rows`` is the
         engine's slot count, ``group_live`` the rows live in THIS
@@ -205,7 +213,14 @@ class ServeLedger:
         committed - group_live, floored at 0). ``read_positions`` is
         what the block's program gathered a layer (its rows x its read
         width) and ``full_positions`` what every slot's whole row would
-        have been (``max_slots x n_ctx``)."""
+        have been (``max_slots x n_ctx``). ``passes`` is the forward
+        passes the call ran, ``commit_passes`` those of them that
+        computed no head (a block-diffusion engine's commits; 0 under
+        one-token decode)."""
+        self.denoise_passes += int(passes) - int(commit_passes)
+        self.commit_passes += int(commit_passes)
+        self._pass_rows += int(group_live) * int(passes)
+        self._pass_tokens += int(committed)
         self._block_rows += int(batch_rows)
         self._block_live += int(group_live)
         self._block_masked += max(int(total_live) - int(group_live), 0)
@@ -232,6 +247,16 @@ class ServeLedger:
         if not self._block_rows:
             return None
         return self._block_live / self._block_rows
+
+    @property
+    def tokens_per_pass(self) -> float | None:
+        """Tokens emitted over live rows x forward passes: 1.0 under
+        one-token decode with every row alive to its call's end, L / (S +
+        1) under block diffusion (4 / 3 with blocks of 4 and 2 denoise
+        passes), above 1 under speculation."""
+        if not self._pass_rows:
+            return None
+        return self._pass_tokens / self._pass_rows
 
     @property
     def decode_read_fraction(self) -> float | None:
@@ -349,6 +374,9 @@ class ServeLedger:
             },
             "decode_utilization": self.decode_utilization,
             "decode_read_fraction": self.decode_read_fraction,
+            "denoise_passes": self.denoise_passes,
+            "commit_passes": self.commit_passes,
+            "tokens_per_pass": self.tokens_per_pass,
             "pool_token_widths": dict(self.pool_token_widths),
             "pool_pad_fraction": self.pool_pad_fraction,
             "model_steps": self._model_steps,
