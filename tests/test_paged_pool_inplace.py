@@ -509,6 +509,60 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
+@pytest.mark.slow
+def test_the_chip_runs_two_attention_kernels_a_layer_and_keeps_o_once(
+    one_chip, monkeypatch
+):
+    """The training cell's step (``gpt2-large``, batch 8 x 1,024, AdamW)
+    compiled for the described chip (ISSUE 38; here because one file alone
+    may load the TPU's library, 14 s): a rematerialised block keeps the
+    attention kernels' o and lse, so the program holds two Mosaic calls,
+    the forward and the one backward, and not the forward a second time;
+    o is stacked once, as ``bf16[36,8,1024,1280]`` rows and never as
+    ``(36, 8, 1024, 20, 64)``, which the chip pads; and
+    ``memory_analysis()`` reads at most 1.6 GB of temporaries over the
+    6.82 GB it read before the stack was kept (8.38: it counts what the
+    forward scan stacks twice, so 0.78 GB of o and lse read as 1.56; the
+    buffer assignment itself grows by 0.78, PERF.md section 6)."""
+    from tpuflow.ops import flash_attention
+    from tpuflow.train import TrainState, make_optimizer, make_train_step
+
+    cell = manifest.load_cell("train-large-steady")
+    cfg, tr = cell["config"], cell["traffic"]
+    # Off the TPU `auto` is XLA's attention and the kernels are interpreted:
+    # ask for the kernels by name and have them lowered for the chip.
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    model = cell["family"].module(dict(cfg["model"], attn_impl="flash"))
+    tx = make_optimizer(**cfg["optimizer"])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    state = jax.eval_shape(
+        lambda: TrainState.create(
+            apply_fn=model.apply, params=_param_shapes(model), tx=tx
+        )
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (int(tr["batch_size"]), int(tr["seq_len"])), jnp.int32
+    )
+    compiled = make_train_step().lower(
+        described(state), described({"x": tokens, "y": tokens}),
+        described(jax.eval_shape(lambda: jax.random.PRNGKey(1))),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    m = cfg["model"]
+    wide = (m["n_layer"], tokens.shape[0], tokens.shape[1], m["n_embd"])
+    heads = wide[:3] + (m["n_head"], m["n_embd"] // m["n_head"])
+    assert "[" + ",".join(map(str, wide)) + "]" in text
+    assert "[" + ",".join(map(str, heads)) + "]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6.82e9 + 1.6e9
+
+
 @pytest.mark.parametrize(
     "family,layout",
     [("gpt2", "blocks"), ("gpt2", "scan"), ("gpt2-96", "blocks"), ("xing4", "scan"),

@@ -440,12 +440,14 @@ def test_comm_attribution_roofline_math(monkeypatch):
 
 # ----------------------------------------------------- remat policy parity
 def _remat_parity(attn_impl: str) -> None:
-    """Loss+grads across full|dots|none on the 2-layer smoke model: the
-    remat selector changes WHERE activations come from (saved vs
-    recomputed), never their values."""
+    """Loss+grads across full|dots|none and ``nothing_saveable`` (what
+    ``full`` was before ISSUE 38: the attention kernel recomputed too) on
+    the 2-layer smoke model: the remat selector changes WHERE activations
+    come from (saved vs recomputed), never their values. The leg's stamp
+    says which named values a block keeps."""
     from tpuflow.models.gpt2 import GPT2, GPT2Config
     from tpuflow.models.losses import cross_entropy_loss
-    from tpuflow.train.gpt import _apply_remat_selector, active_remat_policy
+    from tpuflow.train.gpt import _apply_remat_selector, remat_stamp
 
     base = GPT2Config.small_test(
         dropout=0.0, n_ctx=32, attn_impl=attn_impl, n_embd=64, n_head=2
@@ -455,9 +457,11 @@ def _remat_parity(attn_impl: str) -> None:
     params = GPT2(base).init(jax.random.PRNGKey(0), x)["params"]
 
     results = {}
-    for sel in ("none", "full", "dots"):
+    kept = ("flash_out", "flash_lse")
+    for sel, saves in (("none", ()), ("full", kept), ("dots", kept),
+                       ("nothing_saveable", ())):
         cfg = _apply_remat_selector(base, sel)
-        assert active_remat_policy(cfg) == sel
+        assert remat_stamp(cfg) == {"policy": sel, "saves": saves}
         model = GPT2(cfg)
 
         def loss_fn(p):
@@ -466,7 +470,7 @@ def _remat_parity(attn_impl: str) -> None:
         loss, grads = jax.value_and_grad(loss_fn)(params)
         results[sel] = (float(loss), grads)
     l_none, g_none = results["none"]
-    for sel in ("full", "dots"):
+    for sel in ("full", "dots", "nothing_saveable"):
         l_sel, g_sel = results[sel]
         assert l_sel == pytest.approx(l_none, rel=1e-6)
         jax.tree_util.tree_map(
@@ -505,9 +509,10 @@ def test_remat_policy_parity_loss_and_grads(monkeypatch):
 
 @pytest.mark.slow
 def test_remat_policy_parity_with_flash_kernels():
-    """The flash-attention remat parity (slow tier): 'dots' saves the
-    named flash output, 'none' holds the custom_vjp residuals
-    (outputs + lse) with zero recompute — values identical either way."""
+    """The flash-attention remat parity (slow tier): 'full' and 'dots'
+    keep the kernel's named o and lse, 'nothing_saveable' re-runs the
+    forward kernel for them, 'none' holds the custom_vjp residuals with
+    zero recompute — values identical every way."""
     _remat_parity("flash")
 
 

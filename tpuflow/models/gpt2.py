@@ -36,8 +36,11 @@ class GPT2Config:
     # large-model training on TPU.
     remat: bool = False
     # Selective remat: name of a jax.checkpoint_policies entry controlling
-    # WHICH intermediates the block saves vs recomputes. None = save only
-    # block inputs (max memory savings, most recompute). The TPU-standard
+    # WHICH intermediates the block saves vs recomputes. None = save the
+    # block's input and, where the Pallas attention kernels ran, their o
+    # and lse (``remat_saves``): the recompute then holds no kernel; with
+    # any other attention nothing carries those names and only the input
+    # is kept. 'nothing_saveable' recomputes the kernel too. The TPU-standard
     # middle ground is 'dots_with_no_batch_dims_saveable': matmul outputs
     # (MXU work) are saved, elementwise/softmax (cheap VPU work, the bulk
     # of activation bytes) recompute — most of the memory win at a
@@ -193,6 +196,19 @@ class GPT2Config:
         raise ValueError(
             f"unknown preset {preset!r}; available: test, gpt2, medium"
         )
+
+
+def remat_saves(cfg: GPT2Config) -> tuple[str, ...]:
+    """The named values a rematerialised block keeps beside its input:
+    the Pallas attention kernels' o and lse (named where
+    ``ops/flash_attention.py``'s vjp forward makes them) under policy-less
+    remat and under 'dots'; nothing with remat off or under a
+    ``jax.checkpoint_policies`` name, which decides alone."""
+    from tpuflow.ops.flash_attention import RESIDUAL_NAMES
+
+    if cfg.remat and cfg.remat_policy in (None, "", "dots"):
+        return RESIDUAL_NAMES
+    return ()
 
 
 def _masked_attention(q, k, v, valid, precision=None):
@@ -621,25 +637,20 @@ class GPT2(nn.Module):
         x = pin_batch(x)
         x = nn.Dropout(cfg.dropout, deterministic=not train)(x)
         def remat_wrap(mod):
-            policy = None
+            cp = jax.checkpoint_policies
+            policy = cp.save_only_these_names(*remat_saves(cfg))
             if cfg.remat_policy == "dots":
-                # The ISSUE 10 selector's middle ground: save every MXU
-                # dot output PLUS the named flash-attention output, so
-                # the backward recomputes only cheap elementwise/softmax
-                # work (and, inside a flash custom_vjp, the one fwd
-                # kernel re-run jax's remat can't elide — see the
-                # checkpoint_name note in ops/flash_attention.py; the
-                # zero-recompute mode is remat OFF, selector 'none').
-                cp = jax.checkpoint_policies
+                # The ISSUE 10 selector's middle ground: every MXU dot
+                # output and the attention kernel's residual are saved,
+                # so the backward recomputes only cheap elementwise and
+                # softmax work (the zero-recompute mode is remat OFF,
+                # selector 'none').
                 policy = cp.save_from_both_policies(
-                    cp.dots_with_no_batch_dims_saveable,
-                    cp.save_only_these_names("flash_out"),
+                    cp.dots_with_no_batch_dims_saveable, policy
                 )
             elif cfg.remat_policy:
                 try:
-                    policy = getattr(
-                        jax.checkpoint_policies, cfg.remat_policy
-                    )
+                    policy = getattr(cp, cfg.remat_policy)
                 except AttributeError:
                     raise ValueError(
                         f"unknown remat_policy {cfg.remat_policy!r}; valid "

@@ -84,7 +84,10 @@ CATALOG: dict[str, tuple[str, str]] = {
     "train.remat_policy": (
         "event",
         "resolved remat selector for the leg (none|full|dots|policy "
-        "name; TPUFLOW_REMAT_POLICY beats the config) plus whether the "
+        "name; TPUFLOW_REMAT_POLICY beats the config), `saves`, the named "
+        "values a rematerialised block keeps beside its input (the "
+        "attention kernels' flash_out and flash_lse under full and dots, "
+        "empty otherwise), plus whether the "
         "comm-overlapped accumulation scan is armed — the run's "
         "memory/recompute/overlap trade, auditable from the stream",
     ),

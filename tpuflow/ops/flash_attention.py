@@ -23,10 +23,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+
+# The names on the forward kernel's two outputs (``_flash_fwd``): the
+# residual a rematerialised block keeps, so its recompute holds no kernel
+# (``models/gpt2.py`` ``remat_saves``).
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _interpret() -> bool:
@@ -357,10 +364,14 @@ def _flash_fwd(q, k, v, causal: bool, interpret: bool, *,
         ),
         interpret=interpret,
     )(_rows(q), _rows(k), _rows(v))
-    out = res[0].reshape(B, Tq, H, D)
     if with_lse:
-        return out, res[1].reshape(B, H, Tq)
-    return out
+        # The forward the vjp rule calls: its two outputs are the residual a
+        # rematerialised block keeps (``GPT2``'s ``remat_wrap``), so the
+        # recompute holds no kernel. Named as the kernel writes them, whole
+        # 128-lane rows: a stack of (..., H, D) the chip would pad.
+        o, lse = map(checkpoint_name, res, RESIDUAL_NAMES)
+        return o.reshape(B, Tq, H, D), lse.reshape(B, H, Tq)
+    return res[0].reshape(B, Tq, H, D)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -514,8 +525,9 @@ def _flash(q, k, v, causal):
 def _flash_vjp_fwd(q, k, v, causal):
     o, lse = _flash_fwd(q, k, v, causal, _interpret(), with_lse=True)
     # The residual is the (B, H, Tq) float32 row the backward kernel reads
-    # as it is: 4 bytes a position and head, held for every layer at once
-    # only when nothing is rematerialised.
+    # as it is: 4 bytes a position and head. With o it is what a
+    # rematerialised block keeps by name (``_flash_fwd``); q, k and v it
+    # recomputes.
     return o, (q, k, v, o, lse)
 
 
@@ -576,16 +588,4 @@ def flash_attention(q, k, v, *, causal: bool = True):
                 "for such shapes) or 'xla'"
             )
         return blockwise_attention(q, k, v, causal=causal)
-    out = _flash(q, k, v, causal)
-    from jax.ad_checkpoint import checkpoint_name
-
-    # Named for selective-remat policies (ISSUE 10): the 'dots' policy
-    # saves this output alongside the MXU dot outputs. The lse softmax
-    # residual lives INSIDE the custom_vjp, which jax's remat treats
-    # atomically — a remat'd block re-runs the flash forward for it
-    # regardless of policy (measured: one extra fwd pallas_call in the
-    # remat'd backward jaxpr). Truly saving "flash outputs + lse"
-    # therefore means NOT remat'ing — the TPUFLOW_REMAT_POLICY=none mode,
-    # where the vjp residuals (q, k, v, o, lse) are held from the forward
-    # and the backward runs zero recompute.
-    return checkpoint_name(out, "flash_out")
+    return _flash(q, k, v, causal)

@@ -67,12 +67,18 @@ def _apply_remat_selector(model_cfg, selector: str):
                 custom_vjp residuals (outputs + lse) — the backward runs
                 ZERO recompute. The fastest step when HBM admits it.
     ``dots``  — remat ON with the 'dots' policy: MXU dot outputs and the
-                named flash output saved, cheap elementwise recomputed
-                (the TPU-standard middle ground).
-    ``full``  — remat ON, nothing saved beyond block inputs: minimum
-                memory, maximum recompute (the old full-size default).
+                attention kernels' o and lse saved, cheap elementwise
+                recomputed, no kernel re-run (the TPU-standard middle
+                ground).
+    ``full``  — remat ON, a block keeps its input and, where the Pallas
+                attention kernels ran, their o and lse (2 x (B, T, C) a
+                layer where the input alone was 1; nothing extra under any
+                other attention): the recompute holds every Dense product
+                and no kernel. The full-size default.
     Any other value: treated as a literal jax.checkpoint_policies name
-    (validated by the caller), remat ON.
+    (validated by the caller), remat ON; ``nothing_saveable`` is the
+    block's input alone, the kernel's forward recomputed too (minimum
+    memory).
     """
     if selector == "none":
         return dataclasses.replace(
@@ -97,6 +103,19 @@ def active_remat_policy(model_cfg) -> str:
     if not model_cfg.remat:
         return "none"
     return model_cfg.remat_policy or "full"
+
+
+def remat_stamp(model_cfg) -> dict:
+    """The ``train.remat_policy`` event's account of the leg's remat: the
+    resolved selector and ``saves``, the named values a rematerialised
+    block keeps beside its input (the attention kernels' residual under
+    'full' and 'dots', empty otherwise)."""
+    from tpuflow.models.gpt2 import remat_saves
+
+    return {
+        "policy": active_remat_policy(model_cfg),
+        "saves": remat_saves(model_cfg),
+    }
 
 
 @dataclasses.dataclass
@@ -563,7 +582,7 @@ def _run_fsdp_generation(
         # auditable from the stream alone.
         obs.event(
             "train.remat_policy",
-            policy=active_remat_policy(model_cfg),
+            **remat_stamp(model_cfg),
             comm_overlap=bool(overlap),
             accum_steps=cfg.accum_steps,
         )
@@ -1219,7 +1238,7 @@ def _train_pipeline(
         obs.gauge("train.dispatch_depth", float(window.depth))
         obs.event(
             "train.remat_policy",
-            policy=active_remat_policy(model_cfg),
+            **remat_stamp(model_cfg),
             comm_overlap=False,  # the pipeline schedule microbatches itself
             accum_steps=1,
         )
