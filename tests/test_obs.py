@@ -63,7 +63,8 @@ def test_span_error_annotation(tmp_path):
         with obs.span("flow.step", step="boom"):
             raise RuntimeError("x")
     obs.flush()
-    (ev,) = obs.read_events(_events_file(str(tmp_path / "obs")))
+    start, ev = obs.read_events(_events_file(str(tmp_path / "obs")))
+    assert start["name"] == "obs.start"
     assert ev["error"] == "RuntimeError"
 
 
@@ -109,9 +110,12 @@ def test_merge_tiebreak_and_idempotence(tmp_path):
     first = obs.merge_run_events(run_dir)
     with open(os.path.join(run_dir, "events.jsonl")) as f:
         first_bytes = f.read()
-    # Ties break by proc; same-proc events keep their file order.
-    assert [e["proc"] for e in first] == [0, 0, 1, 1]
-    assert [e.get("seq") for e in first if e["proc"] == 1] == [
+    # Ties break by proc; same-proc events keep their file order. (Each
+    # recorder's own obs.start carries the real clock: it sorts last.)
+    assert [e["name"] for e in first[-2:]] == ["obs.start"] * 2
+    first_at_5 = first[:-2]
+    assert [e["proc"] for e in first_at_5] == [0, 0, 1, 1]
+    assert [e.get("seq") for e in first_at_5 if e["proc"] == 1] == [
         "p1-first", "p1-second",
     ]
     # Idempotent: the merged file at the run root is NOT a fragment, so
@@ -131,13 +135,13 @@ def test_recorder_buffer_bound_counts_drops(tmp_path):
     rec = obs.Recorder(d, proc=0, flush_interval=3600, max_buffered=10)
     for i in range(25):
         rec.record("counter", "train.tokens", value=i)
-    assert rec.dropped == 15
+    assert rec.dropped == 16  # the recorder's own obs.start holds a slot
     rec.close()
     events = obs.read_events(rec.path)
     kept = [e for e in events if e["name"] == "train.tokens"]
-    assert len(kept) == 10
+    assert len(kept) == 9
     (drop,) = [e for e in events if e["name"] == "obs.dropped"]
-    assert drop["value"] == 15
+    assert drop["value"] == 16
     # A second close must not duplicate the accounting event.
     rec.close()
     assert len(
@@ -156,14 +160,14 @@ def test_recorder_failed_flush_counts_lost_batch(tmp_path, monkeypatch):
     os.unlink(rec.path) if os.path.exists(rec.path) else None
     os.makedirs(rec.path)
     rec.flush()
-    assert rec.dropped == 2
+    assert rec.dropped == 3  # the two and the recorder's own obs.start
     os.rmdir(rec.path)  # restore writability for the close-time event
     rec.record("counter", "train.tokens", value=3)
     rec.close()
     events = obs.read_events(rec.path)
     assert [e["value"] for e in events if e["name"] == "train.tokens"] == [3]
     (drop,) = [e for e in events if e["name"] == "obs.dropped"]
-    assert drop["value"] == 2
+    assert drop["value"] == 3
 
 
 def test_merge_tolerates_torn_tail(tmp_path):
@@ -188,7 +192,8 @@ def test_events_buffered_and_flushed_off_hot_path(tmp_path):
         rec.record("counter", "train.tokens", value=i)
     assert not os.path.exists(path) or os.path.getsize(path) == 0
     rec.flush()
-    assert len(obs.read_events(path)) == 100
+    events = obs.read_events(path)
+    assert [e["name"] for e in events[:1]] == ["obs.start"] and len(events) == 101
     rec.close()
 
 

@@ -343,3 +343,86 @@ def test_data_wait_span_beside_the_gauge(tmp_path, mesh8):
     # threaded: one more wait, for the end-of-data marker, as the gauge has
     assert len(waits) == len(gauges) == 3 + 4
     assert all(e["kind"] == "span" and "hit" in e for e in waits)
+
+
+# ------------------------------------------- set-up on the program's clock
+def test_traces_and_lowerings_become_spans_under_the_open_span(tmp_path):
+    from tpuflow import dist
+
+    dist.maybe_enable_compile_cache()  # registers the listener, once
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2.0
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + 1.0
+
+    @jax.jit
+    def unrecorded(x):
+        return jnp.cos(x) - 3.0
+
+    x = jnp.ones((3, 7))
+    unrecorded(x).block_until_ready()  # the recorder off: nothing is kept
+    d = str(tmp_path / "obs")
+    obs.configure(d, proc=0)
+    assert [e["name"] for e in _events(d)] == ["obs.start"]
+    with obs.span("state.init"):
+        outer(x).block_until_ready()
+    spans = _spans(d)
+    (parent,) = [e for e in spans if e["name"] == "state.init"]
+    stages = [e for e in spans if e["name"] in ("trace", "lower", "compile")]
+    assert {e["name"] for e in stages} == {"trace", "lower", "compile"}
+    assert all(e["parent"] == parent["span"] and e["program"] for e in stages)
+    by = {(e["name"], e["program"]): e for e in stages}
+    assert ("lower", "jit(outer)") in by and ("compile", "jit(outer)") in by
+    o, i = by[("trace", "outer")], by[("trace", "inner")]
+    # a jit traced inside another's trace lies inside it
+    assert o["mono"] - 1e-3 <= i["mono"] and i["mono"] + i["dur_s"] <= o["mono"] + o["dur_s"] + 1e-3
+    for e in stages:
+        assert parent["mono"] - 1e-3 <= e["mono"]
+        assert e["mono"] + e["dur_s"] <= parent["mono"] + parent["dur_s"] + 1e-3
+    # the lowering follows the trace of the same program
+    assert by[("lower", "jit(outer)")]["mono"] >= o["mono"] + o["dur_s"] - 1e-3
+    n = len(spans)
+    outer(x).block_until_ready()  # a second call: nothing is traced again
+    assert len(_spans(d)) == n
+
+
+def test_warmup_spans_every_program_and_files_each_duration(model_params, tmp_path):
+    import json
+
+    model, params = model_params
+    d = str(tmp_path / "obs")
+    obs.configure(d, proc=0)
+    eng = ServeEngine(model, params, max_slots=2, paged=True, page_size=8,
+                      buckets=[8, 16], decode_block=2, prefix_cache=True)
+    eng.warmup()
+    spans = _spans(d)
+    (init,) = [e for e in spans if e["name"] == "serve.init"]
+    assert init["slots"] == 2 and init["pages"] == eng.n_pages
+    assert any(e["parent"] == init["span"] for e in spans if e["name"] == "trace")
+    (warm,) = [e for e in spans if e["name"] == "serve.warmup"]
+    programs = [e for e in spans if e["name"] == "serve.warmup.program"]
+    assert all(e["parent"] == warm["span"] for e in programs)
+    got = [(e["program"], e.get("bucket"), e.get("rows"), e.get("pages")) for e in programs]
+    assert got == (
+        [("prefill", w, None, None) for w in eng.buckets]
+        + [("insert", None, None, None)]
+        + [("decode", None, r, p) for _, r, p in eng._decode_variants()]
+        + [("insert", None, None, None), ("reset", None, None, None)]
+    )
+    # each program's trace, lowering and compile nest under its span
+    ids = {e["span"] for e in programs}
+    assert {e["name"] for e in spans if e.get("parent") in ids} >= {"trace", "lower", "compile"}
+    # the warm-up device ledger files each span's duration, and only those
+    with open(os.path.join(d, "programs.json")) as f:
+        ledger = {e["name"]: e["compile_s"] for e in json.load(f)["programs"]}
+    named = [f"prefill@{w}" for w in eng.buckets] + ["insert"] + [
+        f"decode{at}" for at, _, _ in eng._decode_variants()
+    ]
+    assert sorted(ledger) == sorted(named)
+    first = programs[: len(named)]
+    for name, e in zip(named, first):
+        assert ledger[name] == round(e["dur_s"], 4), name

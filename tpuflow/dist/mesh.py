@@ -71,8 +71,11 @@ COMPILE_CACHE_DIR = os.path.join(
 )
 
 
-# JAX's own monitoring events around one backend compile: the duration
-# event wraps compile-or-load; inside it the cache says hit or miss.
+# JAX's own monitoring events: a jaxpr's trace, its lowering to an MLIR
+# module, and one backend compile-or-load, inside which the persistent
+# cache says hit or miss.
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
@@ -87,35 +90,47 @@ def _on_cache_event(event: str, **_kw) -> None:
         _cache_outcome.hit = False
 
 
-def _on_compile_duration(event: str, duration_secs: float, **kw) -> None:
-    """One ``compile`` span per backend compile-or-load, with whether the
-    persistent cache served it (None: the cache was not asked) and the
-    program's name as JAX gives it."""
-    if event != _BACKEND_COMPILE:
+def _on_compile_stage(event: str, duration_secs: float, **kw) -> None:
+    """One span per stage JAX times on the way to an executable: ``trace``
+    (a jaxpr traced: a jitted function, or a primitive's own jit inside
+    it, so traces nest), ``lower`` (a jaxpr to an MLIR module) and
+    ``compile`` (a backend compile, or its load from the persistent cache,
+    with whether the cache served it: None where it was not asked). Each
+    carries the program's name as JAX gives it and is parented to the
+    span open on the thread that did the work."""
+    if event not in (_TRACE, _LOWER, _BACKEND_COMPILE):
         return
-    hit = getattr(_cache_outcome, "hit", None)
-    _cache_outcome.hit = None
+    if event == _BACKEND_COMPILE:
+        hit = getattr(_cache_outcome, "hit", None)
+        _cache_outcome.hit = None
     from tpuflow import obs
     from tpuflow.obs.recorder import ended_span
 
     rec = obs.recorder()
     if rec is None:
         return
-    rec.record(
-        "span", "compile", **ended_span(duration_secs), cache_hit=hit,
-        program=kw.get("fun_name"),
-    )
+    program = kw.get("fun_name")
+    if event == _TRACE:
+        rec.record("span", "trace", **ended_span(duration_secs), program=program)
+    elif event == _LOWER:
+        rec.record("span", "lower", **ended_span(duration_secs), program=program)
+    else:
+        rec.record(
+            "span", "compile", **ended_span(duration_secs), cache_hit=hit,
+            program=program,
+        )
 
 
 def _register_compile_listener() -> None:
-    """Once per process: compiles (and loads from the persistent cache)
-    become spans of the recorder whenever it is on."""
+    """Once per process: traces, lowerings and compiles (and loads from
+    the persistent cache) become spans of the recorder whenever it is
+    on."""
     global _compile_listener_on
     if _compile_listener_on:
         return
     _compile_listener_on = True
     jax.monitoring.register_event_listener(_on_cache_event)
-    jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_stage)
 
 
 def maybe_enable_compile_cache() -> str | None:

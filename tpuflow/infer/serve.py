@@ -820,11 +820,7 @@ class ServeEngine:
         self.quant_mode = resolve_serve_quant(quant)
         self._qmodel = self._qparams = None
         if self.quant_mode is not None:
-            from tpuflow.infer.quant import (
-                QuantizedModel,
-                quant_decision,
-                quantize_model,
-            )
+            from tpuflow.infer.quant import QuantizedModel
 
             if isinstance(model, QuantizedModel):
                 raise ValueError(
@@ -832,17 +828,6 @@ class ServeEngine:
                     "and owns both numeric paths; got an already-quantized "
                     "model — drop the wrapper or drop the quant arg"
                 )
-            dec = quant_decision(params, mode=self.quant_mode)
-            obs.event(
-                "quant.decision",
-                apply=True,  # per-request opt-in: forced, gate advisory
-                mode=dec.mode,
-                weight_mib=round(dec.weight_bytes / 2**20, 1),
-                reason="serve engine per-request int8 (submit(quantize=))",
-            )
-            self._qmodel, self._qparams = quantize_model(
-                model, params, mode=self.quant_mode
-            )
         self.n_ctx = int(model.config.n_ctx)
         self.max_slots = (
             int(max_slots)
@@ -997,23 +982,6 @@ class ServeEngine:
                     * 2**20
                 ),
             )
-        self.pool = PagePool(
-            self.n_pages, self.page_size, prefix_cache=use_prefix,
-            tier_cache=self._tier,
-            page_reader=(
-                self._read_page_host if self._tier is not None else None
-            ),
-        )
-        self._page_table = np.zeros((S, self.pages_per_slot), np.int32)
-        self.decode_shapes = decode_ladder(S, self.n_ctx, self.page_size)
-        self._slot_pages: list[list[int]] = [[] for _ in range(S)]
-        self._pmodel = model.clone(
-            config=dataclasses.replace(
-                model.config,
-                kv_pages=self.n_pages,
-                kv_page_size=self.page_size,
-            )
-        )
         self._queue: collections.deque[ServeRequest] = collections.deque()
         self._slots: list[ServeRequest | None] = [None] * S
         # Under block diffusion a slot's current block: ids, -1 where the
@@ -1037,7 +1005,44 @@ class ServeEngine:
         self._spec_committed = 0
         self._spec_forwards = 0
         self._last_gauges: tuple | None = None
-        self._cache = self._init_cache()
+        # The engine's device work: the int8 weights, the pool and its
+        # ladder, the cache.
+        with obs.span(
+            "serve.init", slots=S, pages=self.n_pages,
+            quant=self.quant_mode or "off",
+        ):
+            if self.quant_mode is not None:
+                from tpuflow.infer.quant import quant_decision, quantize_model
+
+                dec = quant_decision(params, mode=self.quant_mode)
+                obs.event(
+                    "quant.decision",
+                    apply=True,  # per-request opt-in: forced, gate advisory
+                    mode=dec.mode,
+                    weight_mib=round(dec.weight_bytes / 2**20, 1),
+                    reason="serve engine per-request int8 (submit(quantize=))",
+                )
+                self._qmodel, self._qparams = quantize_model(
+                    model, params, mode=self.quant_mode
+                )
+            self.pool = PagePool(
+                self.n_pages, self.page_size, prefix_cache=use_prefix,
+                tier_cache=self._tier,
+                page_reader=(
+                    self._read_page_host if self._tier is not None else None
+                ),
+            )
+            self._page_table = np.zeros((S, self.pages_per_slot), np.int32)
+            self.decode_shapes = decode_ladder(S, self.n_ctx, self.page_size)
+            self._slot_pages: list[list[int]] = [[] for _ in range(S)]
+            self._pmodel = model.clone(
+                config=dataclasses.replace(
+                    model.config,
+                    kv_pages=self.n_pages,
+                    kv_page_size=self.page_size,
+                )
+            )
+            self._cache = self._init_cache()
 
         self._prefill = jax.jit(
             functools.partial(self._prefill_fn, self.model),
@@ -2654,16 +2659,20 @@ class ServeEngine:
         from tpuflow.dist import maybe_enable_compile_cache
 
         maybe_enable_compile_cache()
-        # Per-program compile fences (ISSUE 15): each first execution
-        # below IS that program's trace+compile(-or-cache-load) wall, so
-        # a couple of monotonic reads per program give the device
-        # ledger its warmup-side compile_s entries for free. The AOT
-        # path (collect_program_ledger / prewarm) later enriches the
-        # same names with cost/memory analysis.
-        marks: list[tuple[str, float]] = []
+        # One child span for each program's first run: its trace, lowering
+        # and compile-or-load (the compile listener's spans nest under
+        # it) and its dispatch. The warm-up device ledger (ISSUE 15)
+        # files each span's duration as that program's compile_s.
+        ledger_on = obs.recorder() is not None and knobs.get_bool(
+            "TPUFLOW_DEVICE_LEDGER"
+        )
+        warmed: list[tuple[str, Any]] = []
 
-        def _fence(name: str, t0: float):
-            marks.append((name, time.monotonic() - t0))
+        def program(name: str, entry: str | None = None, **shape):
+            sp = obs.span("serve.warmup.program", program=name, **shape)
+            if ledger_on and entry is not None:
+                warmed.append((entry, sp))
+            return sp
 
         with obs.span(
             "serve.warmup", buckets=len(self.buckets),
@@ -2672,40 +2681,36 @@ class ServeEngine:
             row_cache = None
             for w in self.buckets:
                 chunk = normalize_prefill_chunk(self.prefill_chunk, w)
-                t0 = time.monotonic()
-                _, row_cache = self._prefill(
-                    self.params,
-                    jnp.zeros((1, w), jnp.int32),
-                    prompt_lens_to_pad_lens([w], 1, w),
-                    chunk=chunk,
-                )
-                _fence(f"prefill@{w}", t0)
-                if self.quant_mode is not None:
-                    # The int8 prefill ladder compiles beside the fp one
-                    # — a quantize=True admission must be a cache hit.
-                    t0 = time.monotonic()
-                    _, row_cache = self._prefill_q(
-                        self._qparams,
+                with program("prefill", f"prefill@{w}", bucket=w):
+                    _, row_cache = self._prefill(
+                        self.params,
                         jnp.zeros((1, w), jnp.int32),
                         prompt_lens_to_pad_lens([w], 1, w),
                         chunk=chunk,
                     )
-                    _fence(f"prefill_q@{w}", t0)
+                if self.quant_mode is not None:
+                    # The int8 prefill ladder compiles beside the fp one
+                    # — a quantize=True admission must be a cache hit.
+                    with program("prefill_q", f"prefill_q@{w}", bucket=w):
+                        _, row_cache = self._prefill_q(
+                            self._qparams,
+                            jnp.zeros((1, w), jnp.int32),
+                            prompt_lens_to_pad_lens([w], 1, w),
+                            chunk=chunk,
+                        )
             if row_cache is not None:
                 # First insert: the fresh (uncommitted) init cache.
-                t0 = time.monotonic()
-                self._cache = self._insert(
-                    self._cache, row_cache, *self._insert_warm_args()
-                )
-                _fence("insert", t0)
+                with program("insert", "insert"):
+                    self._cache = self._insert(
+                        self._cache, row_cache, *self._insert_warm_args()
+                    )
             for at, n_rows, pages in self._decode_variants():
-                t0 = time.monotonic()
-                out = self._decode(
-                    self.params, self._cache,
-                    *self._decode_warm_args(n_rows, pages),
-                )
-                self._cache = out[0]
-                _fence(f"decode{at}", t0)
+                with program("decode", f"decode{at}", rows=n_rows, pages=pages):
+                    out = self._decode(
+                        self.params, self._cache,
+                        *self._decode_warm_args(n_rows, pages),
+                    )
+                    self._cache = out[0]
             if self.spec_draft:
                 # The verify block (and below, its int8 twin): dead-slot
                 # drafts of zeros exercise the exact (S, K+1) signature
@@ -2713,45 +2718,51 @@ class ServeEngine:
                 zdraft = jnp.zeros(
                     (self.max_slots, self.spec_draft), jnp.int32
                 )
-                t0 = time.monotonic()
-                out = self._verify(
-                    self.params, self._cache,
-                    jnp.asarray(self._page_table), self._tok, zdraft,
-                    self._lengths, self._pads, self._remaining,
-                    self._live, self._eos,
-                )
-                self._cache = out[0]
-                _fence("verify", t0)
-            if self.quant_mode is not None:
-                # The int8 decode block on the decode-committed cache —
-                # the exact signature the mixed-traffic scheduler replays.
-                for at, n_rows, pages in self._decode_variants():
-                    t0 = time.monotonic()
-                    out = self._decode_q(
-                        self._qparams, self._cache,
-                        *self._decode_warm_args(n_rows, pages),
-                    )
-                    self._cache = out[0]
-                    _fence(f"decode_q{at}", t0)
-                if self.spec_draft:
-                    t0 = time.monotonic()
-                    out = self._verify_q(
-                        self._qparams, self._cache,
+                with program(
+                    "verify", "verify",
+                    rows=self.max_slots, pages=self.pages_per_slot,
+                ):
+                    out = self._verify(
+                        self.params, self._cache,
                         jnp.asarray(self._page_table), self._tok, zdraft,
                         self._lengths, self._pads, self._remaining,
                         self._live, self._eos,
                     )
                     self._cache = out[0]
-                    _fence("verify_q", t0)
+            if self.quant_mode is not None:
+                # The int8 decode block on the decode-committed cache —
+                # the exact signature the mixed-traffic scheduler replays.
+                for at, n_rows, pages in self._decode_variants():
+                    with program(
+                        "decode_q", f"decode_q{at}", rows=n_rows, pages=pages
+                    ):
+                        out = self._decode_q(
+                            self._qparams, self._cache,
+                            *self._decode_warm_args(n_rows, pages),
+                        )
+                        self._cache = out[0]
+                if self.spec_draft:
+                    with program(
+                        "verify_q", "verify_q",
+                        rows=self.max_slots, pages=self.pages_per_slot,
+                    ):
+                        out = self._verify_q(
+                            self._qparams, self._cache,
+                            jnp.asarray(self._page_table), self._tok, zdraft,
+                            self._lengths, self._pads, self._remaining,
+                            self._live, self._eos,
+                        )
+                        self._cache = out[0]
             if row_cache is not None:
                 # Second insert: the steady-state signature — a cache
                 # COMMITTED by the decode program (with sharded params
                 # the jit key differs from the fresh-zeros variant; both
                 # must be warm or the first post-decode admission would
                 # recompile, breaking the never-recompile contract).
-                self._cache = self._insert(
-                    self._cache, row_cache, *self._insert_warm_args()
-                )
+                with program("insert"):
+                    self._cache = self._insert(
+                        self._cache, row_cache, *self._insert_warm_args()
+                    )
             # Warmup wrote garbage k/v into slot 0's columns; every query
             # of a future occupant is masked to its own [pad, length]
             # window and the insert overwrites the row, but start zeroed
@@ -2759,23 +2770,21 @@ class ServeEngine:
             # fresh zeros tree): the result stays committed exactly like
             # every later decode/insert output, so the program signatures
             # warmed above are the ones the serving loop replays.
-            self._cache = jax.tree_util.tree_map(
-                lambda x: x * 0, self._cache
-            )
-            jax.block_until_ready(self._cache)
+            with program("reset"):
+                self._cache = jax.tree_util.tree_map(
+                    lambda x: x * 0, self._cache
+                )
+                jax.block_until_ready(self._cache)
             stats = self.compile_stats()
             sp.set(**stats)
-        if obs.recorder() is not None and knobs.get_bool(
-            "TPUFLOW_DEVICE_LEDGER"
-        ):
-            # Warmup-side device ledger (ISSUE 15): per-program compile
-            # wall into programs.json — a few buffered events and one
-            # small JSON write, nothing on the serving hot path.
+        if warmed:
+            # A few buffered events and one small JSON write, nothing on
+            # the serving hot path.
             try:
                 ledger = _device.ProgramLedger(source="warmup")
-                for name, dt in marks:
+                for entry, wsp in warmed:
                     ledger.note_entry(
-                        {"name": name, "compile_s": round(dt, 4)}
+                        {"name": entry, "compile_s": round(wsp.dur_s, 4)}
                     )
                 ledger.write()
             except Exception as e:

@@ -118,6 +118,12 @@ REQUIRED_EMITTERS: tuple[tuple[str, str], ...] = (
     ("span", "data.wait"),
     ("span", "compile"),
     ("span", "state.init"),
+    # Set-up on the program's clock (ISSUE 39).
+    ("span", "trace"),
+    ("span", "lower"),
+    ("span", "serve.init"),
+    ("span", "serve.warmup.program"),
+    ("event", "obs.start"),
     ("event", "serve.trace"),
     ("event", "serve.slo_violation"),
     ("counter", "serve.slo_violations"),

@@ -189,6 +189,18 @@ CATALOG: dict[str, tuple[str, str]] = {
         "create_sharded_state: trace, compile-or-load and dispatch of "
         "the born-sharded initializer (execution not awaited)",
     ),
+    # Set-up on the program's clock (ISSUE 39): JAX's trace and lowering
+    # from the same listener, so every compile stage is a span.
+    "trace": (
+        "span",
+        "one jaxpr traced (program = the function's name; a jit traced "
+        "inside another's trace nests in it)",
+    ),
+    "lower": (
+        "span",
+        "one jaxpr lowered to an MLIR module (program = the name JAX "
+        "gives it)",
+    ),
     # --------------------------------------------------------------- infer
     "infer.predict": ("span", "BatchPredictor forward over one batch"),
     "infer.generate": ("span", "one generate() call; tokens + tokens/s"),
@@ -200,11 +212,24 @@ CATALOG: dict[str, tuple[str, str]] = {
     # Continuous-batching serving engine (ISSUE 8): request-level
     # telemetry from tpuflow.infer.serve, also mirrored live on the
     # /metrics exporter via the process ledger's serve_* snapshot keys.
+    "serve.init": (
+        "span",
+        "ServeEngine construction's device work: the int8 weights where "
+        "armed, the page pool and the decode ladder, the zeroed cache",
+    ),
     "serve.warmup": (
         "span",
         "AOT warm pass at server start: decode program + insert + every "
         "prefill bucket compiled-or-cache-loaded once (carries the jit "
         "cache sizes — the never-recompile baseline)",
+    ),
+    "serve.warmup.program": (
+        "span",
+        "one program's first run inside serve.warmup: trace, lowering, "
+        "compile-or-load and dispatch (program = prefill, prefill_q, "
+        "insert, decode, decode_q, verify, verify_q or reset; bucket on a "
+        "prefill, rows and pages on a decode or verify block); its "
+        "duration is the warm-up device ledger's compile_s",
     ),
     "serve.prefill": (
         "span",
@@ -735,6 +760,11 @@ CATALOG: dict[str, tuple[str, str]] = {
         "ledger)",
     ),
     # ----------------------------------------------------------------- obs
+    "obs.start": (
+        "event",
+        "a recorder began: the first event it writes, where what a "
+        "reader of set-up can see starts on the monotonic clock",
+    ),
     "obs.dropped": (
         "event",
         "telemetry events lost by this recorder (buffer overflow or a "

@@ -37,7 +37,9 @@ the ``/host:`` plane of the same ``.xplane.pb`` as the device
 operations, so an idle gap of the device can be put down to what the
 program was doing. ``mono`` puts spans and point events (a request's
 first token, its completion) on one clock; ``request=`` is the attribute
-that ties a span to one serving request.
+that ties a span to one serving request. A recorder's first event,
+``obs.start``, marks where its clock begins; a closed span keeps its
+``dur_s`` for a caller that files it elsewhere too.
 
 The ``launch`` stamp (a dedicated key — several flow events already
 carry their own ``attempt`` attribute, which must not be confused with
@@ -131,6 +133,7 @@ def ended_span(dur_s: float) -> dict:
 class _Span:
     __slots__ = (
         "_rec", "_name", "_attrs", "_t0", "_ts", "_id", "_parent", "_ann",
+        "dur_s",
     )
 
     def __init__(self, rec: "Recorder", name: str, attrs: dict):
@@ -156,7 +159,7 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, *exc):
-        dur = time.monotonic() - self._t0
+        self.dur_s = dur = time.monotonic() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, *exc)
         stack = _open_spans()
@@ -243,6 +246,10 @@ class Recorder:
             target=self._flush_loop, daemon=True, name="tpuflow-obs-flush"
         )
         self._thread.start()
+        # Where this recorder's clock starts: what a reader of set-up can
+        # see begins here (the benchmark constructs it once the chip is
+        # reached).
+        self.record("event", "obs.start")
 
     # ------------------------------------------------------------- record
     def record(self, kind: str, name: str, *, ts: float | None = None,
