@@ -391,7 +391,7 @@ def test_export_import_round_trips_a_page_set(rig):
 
 # ------------------------- the pool's shape, and what the chip makes of it
 CELLS = {"gpt2": "serve-medium-chat", "xing4": "serve-xing4-reason",
-         "sdar": "serve-sdar-blockgen"}
+         "sdar": "serve-sdar-blockgen", "qwen3_next": "serve-qwen3next-hybrid"}
 
 
 def _param_shapes(model):
@@ -412,6 +412,8 @@ def _cell_engine(family, **serve):
     m = dict(cfg["model"], n_layer=2)
     if "first_k_dense" in m:
         m["first_k_dense"] = 1  # one dense layer and one routed
+    if "full_attention_interval" in m:  # one whole period
+        m["n_layer"] = m["full_attention_interval"]
     model = fam.module(m)
     return ServeEngine(
         model, _param_shapes(model), buckets=list(cell["traffic"]["buckets"])[:1],
@@ -441,7 +443,8 @@ def one_chip():
     "family,program",
     [("gpt2", "decode"), ("gpt2", "insert"), ("gpt2", "verify"),
      ("xing4", "decode"), ("xing4", "insert"),
-     ("sdar", "decode"), ("sdar", "insert")],
+     ("sdar", "decode"), ("sdar", "insert"),
+     ("qwen3_next", "decode"), ("qwen3_next", "insert")],
 )
 def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     one_chip, family, program
@@ -452,9 +455,13 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     pages major, and no ``copy`` in the program has an operand of its
     size. The third family's decode program is the block-diffusion one
     (``_denoise_fn``): every pass of it, the commit among them, writes a
-    block's keys and values into the pool by index."""
+    block's keys and values into the pool by index. The fourth holds state
+    per slot beside the pool (``f32[3,65,32,128,128]``,
+    ``f32[3,65,24576]`` for one period): those leaves too enter row-major
+    and are never copied."""
     eng = _cell_engine(family, **({"speculative": 2} if program == "verify" else {}))
     pool = [leaf for _, leaf in eng._cache_leaf_items(eng._cache)]
+    state = [eng._cache[k] for k in eng.slot_state]
 
     def described(tree):
         return jax.tree_util.tree_map(
@@ -484,7 +491,7 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     compiled = lowered.compile()
     text = compiled.as_text()
     entry = re.search(r"entry_computation_layout=\{(.*)\}\n", text).group(1)
-    for leaf in pool:
+    for leaf in pool + state:
         assert leaf.shape[-1] % paged_pool.LANES == 0
         dims = ",".join(map(str, leaf.shape))
         layouts = set(re.findall(r"\[" + dims + r"\]\{([\d,]*)", entry))
@@ -494,11 +501,13 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
             "major, so every program that indexes it copies it"
         )
     n_pool = min(math.prod(leaf.shape) for leaf in pool)
+    held = {tuple(leaf.shape) for leaf in state}
     copies = [
         line.strip()[:200]
         for line in text.splitlines()
         if (m := re.match(r"\s*(?:ROOT )?%?copy[\w.\-]* = \w+\[([\d,]+)\]", line))
-        and math.prod(map(int, m.group(1).split(","))) >= n_pool
+        and (math.prod(dims := tuple(map(int, m.group(1).split(",")))) >= n_pool
+             or dims in held)
     ]
     assert not copies, copies
     # A copy of the pool under any other name would need room for it

@@ -21,8 +21,9 @@ from benchmark.tests.test_sdar_family import CELL  # noqa: E402
 
 def test_the_cell_lists_the_accepted_readers_that_apply_and_its_own_two(cell):  # noqa: F811
     """The accepted file's test of this name, with the cell's own two
-    metrics found by name where it took them from the end of `per_layer`:
-    the manifest puts each later PR's metrics after them (PERF.md §7 (4))."""
+    metrics, the cell and its configuration found by name where it took
+    them from the ends of their lists: the manifest puts each later PR's
+    after them (PERF.md §7 (4))."""
     names = [m["name"] for m in cell["per_layer"]]
     own = ["tokens_per_pass.serve", "commit_pass_share.serve"]
     assert set(own) <= set(names)
@@ -33,7 +34,9 @@ def test_the_cell_lists_the_accepted_readers_that_apply_and_its_own_two(cell):  
     for m in man["per_layer"]:
         if m["name"] in own:
             assert m["workloads"] == [CELL] and m["moves"] == "tpot_p90_ms"
-    assert man["workloads"][-1]["name"] == CELL and man["configs"][-1]["name"] == cell["config_name"]
+    # Found by name: a later PR's cell and configuration come after them.
+    assert CELL in [w["name"] for w in man["workloads"]]
+    assert cell["config_name"] in [c["name"] for c in man["configs"]]
     with open(os.path.join(manifest.BENCH_DIR, "limits", CELL + ".json")) as f:
         assert set(json.load(f)["limits"]) == {
             "widest_logit_gap", "requests_failed", "compiled_in_window"}

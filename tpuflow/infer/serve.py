@@ -812,6 +812,10 @@ class ServeEngine:
         self.model = model
         self.params = params
         self.generation = resolve_generation(generation, model)
+        # Cache leaves the model holds per slot, by name (``(..., max_slots
+        # + 1, *state)`` in the engine's cache, the last row a trash row):
+        # asked of the model, never guessed from shapes.
+        self.slot_state = tuple(getattr(model, "slot_state", ()))
         # Per-request int8 (ISSUE 9): quantize ONCE at construction and
         # keep both numeric paths' params resident — requests pick a
         # path at submit, never a recompile. The quantized tree is a
@@ -922,17 +926,43 @@ class ServeEngine:
         self._qpmodel = None
         self.page_size = resolve_page_size(self.n_ctx, page_size)
         self.pages_per_slot = self.n_ctx // self.page_size
+        # Tiered prefix cache: both tiers default OFF (the untiered
+        # pool then works alone).
+        host_mb = (
+            float(kv_host_mb) if kv_host_mb is not None
+            else float(knobs.get_float("TPUFLOW_KV_HOST_MB"))
+        )
+        tier_disk = (
+            kv_disk_dir if kv_disk_dir is not None
+            else knobs.raw("TPUFLOW_KV_DISK_DIR")
+        )
+        # What has no meaning yet beside a way of generating, or beside
+        # state a request carries from token to token (a shipped, fed,
+        # tiered or speculated admission skips or replays tokens that the
+        # state has absorbed, or would have to): raised where the two
+        # meet, never served in the option's place.
+        holders = []
+        if self.slot_state:
+            holders.append(
+                "a model that holds per-slot state "
+                f"({', '.join(self.slot_state)})"
+            )
         if self.generation is not None:
+            holders.append(f"generation={self.generation['kind']!r}")
+        for who in holders:
             for what, on in (
                 ("quant (the int8 decode path)", self.quant_mode is not None),
                 ("speculative decode", bool(self.spec_draft)),
                 ("a KV store (shipped page sets)", self.kv_store is not None),
+                ("a tiered prefix cache", host_mb > 0 or bool(tier_disk)),
+                ("block-diffusion generation",
+                 bool(self.slot_state) and self.generation is not None),
             ):
                 if on:
                     raise GenerationUnsupported(
-                        f"generation={self.generation['kind']!r} with "
-                        f"{what}: not supported yet"
+                        f"{who} with {what}: not supported yet"
                     )
+        if self.generation is not None:
             if self.page_size % self.generation["block_length"]:
                 # A page's contents then depend on tokens up to its own
                 # end alone, which is what `PagePool.prefix_digests` keys
@@ -957,21 +987,6 @@ class ServeEngine:
             _env_flag("TPUFLOW_SERVE_PREFIX_CACHE", True)
             if prefix_cache is None else bool(prefix_cache)
         )
-        # Tiered prefix cache (ISSUE 19): both tiers default OFF —
-        # the untiered pool is byte-identical to PR 11.
-        host_mb = (
-            float(kv_host_mb) if kv_host_mb is not None
-            else float(knobs.get_float("TPUFLOW_KV_HOST_MB"))
-        )
-        tier_disk = (
-            kv_disk_dir if kv_disk_dir is not None
-            else knobs.raw("TPUFLOW_KV_DISK_DIR")
-        )
-        if self.generation is not None and (host_mb > 0 or tier_disk):
-            raise GenerationUnsupported(
-                f"generation={self.generation['kind']!r} with a tiered "
-                "prefix cache: not supported yet"
-            )
         if use_prefix and (host_mb > 0 or tier_disk):
             self._tier = _kvstore.TierCache(
                 host_bytes=int(host_mb * 2**20),
@@ -1035,11 +1050,13 @@ class ServeEngine:
             self._page_table = np.zeros((S, self.pages_per_slot), np.int32)
             self.decode_shapes = decode_ladder(S, self.n_ctx, self.page_size)
             self._slot_pages: list[list[int]] = [[] for _ in range(S)]
+            rows = {"state_slots": S + 1} if self.slot_state else {}
             self._pmodel = model.clone(
                 config=dataclasses.replace(
                     model.config,
                     kv_pages=self.n_pages,
                     kv_page_size=self.page_size,
+                    **rows,
                 )
             )
             self._cache = self._init_cache()
@@ -1106,7 +1123,15 @@ class ServeEngine:
         the page axis is the first one in which the model's pool and the
         model's prefill row differ. The ledger is told once how many
         numbers a token holds and what they are padded to
-        (``serve.pool_pad_fraction``)."""
+        (``serve.pool_pad_fraction``).
+
+        A leaf the model names in ``slot_state`` is held per slot, not
+        by pages: ``self._slot_axis`` keeps its slot axis (again the
+        first in which pool and row differ: ``max_slots + 1`` rows, the
+        last a trash row, against the prefill row's one), the admission
+        insert writes the prefill's final state into the slot's row, and
+        the decode program reads and writes the rows of its live slots
+        by index (``serve.slot_state_bytes`` says what they hold)."""
 
         def mk(params):
             _, variables = self._pmodel.apply(
@@ -1123,6 +1148,9 @@ class ServeEngine:
 
         shapes = jax.eval_shape(mk, self.params)
         self._page_axis, self._token_shape, widths = {}, {}, {}
+        self._slot_axis: dict[str, int] = {}
+        held = {f"[{name!r}]" for name in self.slot_state}
+        state_bytes = 0
         for (path, pool), row in zip(
             jax.tree_util.tree_flatten_with_path(shapes)[0],
             jax.tree_util.tree_leaves(self._row_template()),
@@ -1133,6 +1161,17 @@ class ServeEngine:
                  if a != b),
                 None,
             )
+            if key in held:
+                if axis is None or pool.shape[axis] != self.max_slots + 1:
+                    raise ValueError(
+                        f"slot state {key} {pool.shape}: a per-slot leaf is "
+                        f"(..., max_slots + 1, *state), the prefill row's "
+                        f"{row.shape} with one row"
+                    )
+                self._slot_axis[key] = axis
+                held.discard(key)
+                state_bytes += pool.size * pool.dtype.itemsize
+                axis = None  # not a page pool
             self._page_axis[key] = axis
             if axis is None:
                 continue
@@ -1144,10 +1183,15 @@ class ServeEngine:
                 )
             self._token_shape[key] = row.shape[axis + 2:]
             widths[key] = (math.prod(row.shape[axis + 2:]), pool.shape[-1])
+        if held:
+            raise ValueError(f"the model's slot state {sorted(held)} is not in its cache")
         self.ledger.pool_token_widths = widths
         obs.gauge(
             "serve.pool_pad_fraction", round(self.ledger.pool_pad_fraction, 4)
         )
+        if self.slot_state:
+            self.ledger.slot_state_bytes = state_bytes
+            obs.gauge("serve.slot_state_bytes", state_bytes)
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes
         )
@@ -1165,7 +1209,8 @@ class ServeEngine:
         return tok0, cache
 
     @jax.named_scope("serve.insert")
-    def _page_insert_fn(self, cache, row_cache, table_row, pad, write_mask):
+    def _page_insert_fn(self, cache, row_cache, table_row, pad, write_mask,
+                        slot=None):
         """The admission insert: strip the (1, n_ctx) prefill row's
         LEFT padding (roll by ``pad`` — the real prompt kv moves to
         logical columns [0, len), making cache content pad-invariant,
@@ -1187,11 +1232,22 @@ class ServeEngine:
         at ``layer * n_pages + page``. The row ``(..., 1, n_ctx,
         *token)`` is flattened (and zero-padded, where the pool is) to
         that width first: row-sized work. Nothing is read back from the
-        pool and no other page is touched."""
+        pool and no other page is touched.
+
+        ``slot`` (a model with slot state): the row of the per-slot
+        leaves that takes the prefill's final state, whole, on every
+        admission, a prefix-cache hit among them (the prefill still runs
+        over the whole prompt, so that state is fresh): a reused slot
+        never starts from its last occupant's."""
         idx = jnp.where(write_mask, table_row, 0)
 
         def put(path, pool, row):
-            if self._page_axis[jax.tree_util.keystr(path)] is None:
+            key = jax.tree_util.keystr(path)
+            if key in self._slot_axis:
+                return jax.lax.dynamic_update_slice_in_dim(
+                    pool, row.astype(pool.dtype), slot, self._slot_axis[key]
+                )
+            if self._page_axis[key] is None:
                 return pool  # scalar index leaves pass through
             tail = pool.shape[-2:]  # (page_size, width)
             layers = math.prod(pool.shape[:-3])
@@ -1279,7 +1335,7 @@ class ServeEngine:
 
     @jax.named_scope("serve.decode")
     def _decode_fn(self, model, params, cache, tok, lengths, pads,
-                   remaining, live, eos, page_table):
+                   remaining, live, eos, page_table, slots=None):
         """THE persistent decode program: ``decode_block`` single-token
         steps over the R rows it is given, per-row freezing inside the
         scan. One host sync per block. The operands are (R,) arrays and
@@ -1297,12 +1353,21 @@ class ServeEngine:
         What the model sows of a step into its ``step_sum`` and
         ``step_max`` collections (scalars: the experts a routed layer
         touched, say; GPT-2 sows nothing) comes back as the last result,
-        summed and taken the largest of over the block's steps."""
+        summed and taken the largest of over the block's steps.
+
+        ``slots`` (R,), a model with slot state: the slot of each row.
+        A step hands the model its live rows' slots and the trash row
+        (``max_slots``) for every other row, so a dead or padding row
+        never touches a live slot's state; the state leaves are carried
+        through both scans and updated by index."""
         n_ctx = self.n_ctx
         pad_id = self.pad_id
 
         def one(carry, _):
             cache, tok, lengths, remaining, live = carry
+            rows = {} if slots is None else {
+                "state_slots": jnp.where(live, slots, self.max_slots)
+            }
             logits, variables = model.apply(
                 {"params": params, "cache": cache},
                 tok[:, None],
@@ -1311,6 +1376,7 @@ class ServeEngine:
                 pad_lens=pads,
                 slot_index=lengths,
                 page_table=page_table,
+                **rows,
             )
             with jax.named_scope("sample"):
                 nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
@@ -1738,6 +1804,7 @@ class ServeEngine:
             self._cache = self._insert(
                 self._cache, row, jnp.asarray(table_row),
                 jnp.int32(0), jnp.asarray(write_mask),
+                *self._slot_operand(self.max_slots),
             )
 
     def prefill_export(
@@ -1759,6 +1826,11 @@ class ServeEngine:
                 "prefill_export / ship: a shipped set carries a first "
                 "token and whole-prompt pages, which a block-wise "
                 "admission has neither of (not supported yet)"
+            )
+        if self.slot_state:
+            raise GenerationUnsupported(
+                "a model that holds per-slot state with prefill_export / "
+                "ship: a page set carries no state (not supported yet)"
             )
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
@@ -2155,6 +2227,7 @@ class ServeEngine:
                 self._cache = self._insert(
                     self._cache, row_cache, jnp.asarray(table_row),
                     jnp.int32(W - L), jnp.asarray(write_mask),
+                    *self._slot_operand(slot),
                 )
         self._page_table[slot] = table_row
         self._slot_pages[slot] = list(page_ids)
@@ -2391,7 +2464,7 @@ class ServeEngine:
                         steps, *when,
                     ) = decode(
                         prm, self._cache, tok, lengths, pads, remaining,
-                        live, eos, table,
+                        live, eos, table, *self._slot_operand(rows),
                     )
             # The host copy of the block's tokens IS the fence.
             with obs.span("serve.decode.fence"):
@@ -2614,14 +2687,25 @@ class ServeEngine:
         return [r.result() for r in reqs]
 
     # ---------------------------------------------------------------- warmup
+    def _slot_operand(self, slots) -> tuple:
+        """The insert's and the decode program's last operand, a model
+        with slot state's alone: the slot (or the rows' slots) whose state
+        rows the program writes. Other models' programs keep the
+        signature they had."""
+        if not self.slot_state:
+            return ()
+        return (jnp.asarray(slots, jnp.int32),)
+
     def _insert_warm_args(self):
         """The insert call's non-cache operands for a warmup/AOT pass:
         one full table of trash-routed pages (table zeros + mask all-on
-        exercises the real scatter against the reserved page)."""
+        exercises the real scatter against the reserved page), and the
+        trash state row."""
         return (
             jnp.zeros((self.pages_per_slot,), jnp.int32),
             jnp.int32(0),
             jnp.ones((self.pages_per_slot,), bool),
+            *self._slot_operand(self.max_slots),
         )
 
     def _decode_variants(self) -> list[tuple[str, int, int]]:
@@ -2636,13 +2720,15 @@ class ServeEngine:
     def _decode_warm_args(self, n_rows: int, pages: int):
         """Dead-row operands for one decode warmup execution at one shape
         of the ladder: tok, lengths, pads, remaining, live, eos,
-        page_table."""
+        page_table (and, a model with slot state, the trash row's
+        slots)."""
         zeros = np.zeros((n_rows,), np.int32)
         return [
             np.zeros((n_rows,) + self._tok.shape[1:], np.int32),
             zeros, zeros, zeros, np.zeros((n_rows,), bool),
             np.full((n_rows,), -1, np.int32),
             np.zeros((n_rows, pages), np.int32),
+            *self._slot_operand(np.full((n_rows,), self.max_slots)),
         ]
 
     def warmup(self) -> dict[str, int]:

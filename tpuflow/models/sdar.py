@@ -139,6 +139,112 @@ def block_causal(q_pos, k_pos, block_length: int):
     )
 
 
+def grouped_attention(q, keys, values, q_pos, k_pos, pad_lens, cfg,
+                      k_col=None):
+    """Block-causal attention of ``q`` (B, Tq, H, D) over ``keys`` /
+    ``values`` (B, N, kv_width) at positions ``k_pos`` (B, N) or (N,), for a
+    config with ``dtype``, ``n_kv_head``, ``head_dim`` and ``block_length``
+    (1 is plain causal attention). ``k_col`` (N,), where given, are the
+    keys' cache columns, of which the first ``pad_lens`` of a row are left
+    padding."""
+    dt, g, d = cfg.dtype, cfg.n_kv_head, cfg.head_dim
+    b, t, h, _ = q.shape
+    n = keys.shape[1]
+    with jax.named_scope("attn_core"):
+        visible = block_causal(
+            q_pos, jnp.broadcast_to(k_pos, (b, n)), cfg.block_length
+        )
+        if pad_lens is not None:
+            col = jnp.arange(n) if k_col is None else k_col
+            visible = visible & (col[None, None, :] >= pad_lens[:, None, None])
+        # Group by group, on 128-lane slices of the keys and values as
+        # they lie (a token's groups side by side in one vector): one
+        # product over (B, N, g, D) would first move g ahead of N, a
+        # copy of everything the pass gathered.
+        qg = q.reshape(b, t, g, h // g, d)
+        out = []
+        for i in range(g):
+            lanes = slice(i * d, (i + 1) * d)
+            s = jnp.einsum(
+                "btrd,bnd->brtn", qg[:, :, i], keys[..., lanes].astype(dt),
+                preferred_element_type=jnp.float32,
+            ) * d ** -0.5
+            p = jax.nn.softmax(
+                jnp.where(visible[:, None], s, -1e30), axis=-1
+            ).astype(dt)
+            out.append(jnp.einsum(
+                "brtn,bnd->btrd", p, values[..., lanes].astype(dt),
+                preferred_element_type=jnp.float32,
+            ).astype(dt))
+        return jnp.stack(out, axis=2).reshape(b, t, h, d)
+
+
+def paged_attention(q, k, v, pools, layer, positions, pad_lens, slot_index,
+                    page_table, cfg):
+    """The serving engine's page pool (GPT-2's ``_paged_attention``): row
+    b's T new keys and values land at logical columns ``slot_index[b] + t``
+    through its table, columns beyond the table and dead rows in the
+    layer's trash page 0; each row then reads the pages its table names,
+    its own block's among them, and nothing else. Both pools are (layers,
+    kv_pages, page_size, kv_width), a whole number of 128-lane rows a token
+    (``ops/paged_pool.py``)."""
+    ps = cfg.kv_page_size
+    first_page = layer * cfg.kv_pages
+    _, flat = paged_pool.token_slots(
+        page_table, slot_index, q.shape[1], ps, first_page
+    )
+    with jax.named_scope("kv_write"):
+        pools = tuple(
+            paged_pool.write_tokens(pool, flat, new)
+            for pool, new in zip(pools, (k, v))
+        )
+    with jax.named_scope("kv_read"):
+        keys, values = (
+            paged_pool.read_rows(pool, first_page + page_table, (cfg.kv_width,))
+            for pool in pools
+        )
+    cols = jnp.arange(page_table.shape[1] * ps)
+    pads = 0 if pad_lens is None else pad_lens[:, None]
+    return grouped_attention(
+        q, keys, values, positions, cols[None, :] - pads, pad_lens, cfg
+    ), pools
+
+
+def row_attention(q, k, v, rows, layer, positions, pad_lens, start, cfg):
+    """Contiguous rows (layers, B, n_ctx, kv_width), the admission
+    prefill's: the chunk's keys and values written at ``start``; a fresh
+    multi-token chunk attends over itself, anything else over the rows."""
+    t = q.shape[1]
+    with jax.named_scope("kv_write"):
+        rows = tuple(
+            lax.dynamic_update_slice(
+                row, new.astype(row.dtype)[None], (layer, 0, start, 0)
+            )
+            for row, new in zip(rows, (k, v))
+        )
+    pads = 0 if pad_lens is None else pad_lens[:, None]
+
+    def over_rows():
+        with jax.named_scope("kv_read"):
+            keys, values = (
+                lax.dynamic_index_in_dim(row, layer, 0, keepdims=False)
+                for row in rows
+            )
+        cols = jnp.arange(cfg.n_ctx)
+        visible_cols = jnp.where(cols < start + t, cols, 2 * cfg.n_ctx)
+        return grouped_attention(
+            q, keys, values, positions, visible_cols[None, :] - pads,
+            pad_lens, cfg, k_col=cols,
+        )
+
+    def fresh():
+        return grouped_attention(q, k, v, positions, positions, pad_lens, cfg)
+
+    if t > 1:
+        return lax.cond(start == 0, fresh, over_rows), rows
+    return over_rows(), rows
+
+
 class Layer(nn.Module):
     """One layer on the stream ``x`` (B, T, C) float32. ``cache`` is the
     whole (keys, values) cache threaded through: pools (layers, kv_pages,
@@ -205,128 +311,21 @@ class Layer(nn.Module):
             ).reshape(b, t, g * d)
             v = dot(x, self._p("v", (c, g * d)), dt)
         if not decode:
-            a = self._core(q, k, v, positions, positions, pad_lens)
+            a = grouped_attention(q, k, v, positions, positions, pad_lens, cfg)
         elif page_table is not None:
-            a, cache = self._paged(
+            a, cache = paged_attention(
                 q, k, v, cache, layer, positions, pad_lens, slot_index,
-                page_table,
+                page_table, cfg,
             )
         else:
-            a, cache = self._rows(
-                q, k, v, cache, layer, positions, pad_lens, start
+            a, cache = row_attention(
+                q, k, v, cache, layer, positions, pad_lens, start, cfg
             )
         with jax.named_scope("attn_out"):
             return dot(
                 a.reshape(b, t, h * d), self._p("o", (h * d, c), out_init),
                 dt, jnp.float32,
             ), cache
-
-    def _core(self, q, keys, values, q_pos, k_pos, pad_lens, k_col=None):
-        """Block-causal attention of ``q`` (B, Tq, H, D) over ``keys`` /
-        ``values`` (B, N, kv_width) at positions ``k_pos`` (B, N) or (N,).
-        ``k_col`` (N,), where given, are the keys' cache columns, of which
-        the first ``pad_lens`` of a row are left padding."""
-        cfg = self.config
-        dt, g, d = cfg.dtype, cfg.n_kv_head, cfg.head_dim
-        b, t, h, _ = q.shape
-        n = keys.shape[1]
-        with jax.named_scope("attn_core"):
-            visible = block_causal(
-                q_pos, jnp.broadcast_to(k_pos, (b, n)), cfg.block_length
-            )
-            if pad_lens is not None:
-                col = jnp.arange(n) if k_col is None else k_col
-                visible = visible & (col[None, None, :] >= pad_lens[:, None, None])
-            # Group by group, on 128-lane slices of the keys and values as
-            # they lie (a token's groups side by side in one vector): one
-            # product over (B, N, g, D) would first move g ahead of N, a
-            # copy of everything the pass gathered.
-            qg = q.reshape(b, t, g, h // g, d)
-            out = []
-            for i in range(g):
-                lanes = slice(i * d, (i + 1) * d)
-                s = jnp.einsum(
-                    "btrd,bnd->brtn", qg[:, :, i], keys[..., lanes].astype(dt),
-                    preferred_element_type=jnp.float32,
-                ) * d ** -0.5
-                p = jax.nn.softmax(
-                    jnp.where(visible[:, None], s, -1e30), axis=-1
-                ).astype(dt)
-                out.append(jnp.einsum(
-                    "brtn,bnd->btrd", p, values[..., lanes].astype(dt),
-                    preferred_element_type=jnp.float32,
-                ).astype(dt))
-            return jnp.stack(out, axis=2).reshape(b, t, h, d)
-
-    def _paged(self, q, k, v, pools, layer, positions, pad_lens, slot_index,
-               page_table):
-        """The serving engine's page pool (GPT-2's ``_paged_attention``):
-        row b's T new keys and values land at logical columns
-        ``slot_index[b] + t`` through its table, columns beyond the table
-        and dead rows in the layer's trash page 0; each row then reads the
-        pages its table names, its own block's among them, and nothing
-        else. Both pools are (layers, kv_pages, page_size, kv_width), a
-        whole number of 128-lane rows a token (``ops/paged_pool.py``)."""
-        cfg = self.config
-        ps = cfg.kv_page_size
-        first_page = layer * cfg.kv_pages
-        _, flat = paged_pool.token_slots(
-            page_table, slot_index, q.shape[1], ps, first_page
-        )
-        with jax.named_scope("kv_write"):
-            pools = tuple(
-                paged_pool.write_tokens(pool, flat, new)
-                for pool, new in zip(pools, (k, v))
-            )
-        with jax.named_scope("kv_read"):
-            keys, values = (
-                paged_pool.read_rows(
-                    pool, first_page + page_table, (cfg.kv_width,)
-                )
-                for pool in pools
-            )
-        cols = jnp.arange(page_table.shape[1] * ps)
-        pads = 0 if pad_lens is None else pad_lens[:, None]
-        return self._core(
-            q, keys, values, positions, cols[None, :] - pads, pad_lens
-        ), pools
-
-    def _rows(self, q, k, v, rows, layer, positions, pad_lens, start):
-        """Contiguous rows (layers, B, n_ctx, kv_width), the admission
-        prefill's: the chunk's keys and values written at ``start``; a
-        fresh multi-token chunk attends over itself, anything else over
-        the rows."""
-        cfg = self.config
-        t = q.shape[1]
-        with jax.named_scope("kv_write"):
-            rows = tuple(
-                lax.dynamic_update_slice(
-                    row, new.astype(row.dtype)[None], (layer, 0, start, 0)
-                )
-                for row, new in zip(rows, (k, v))
-            )
-        pads = 0 if pad_lens is None else pad_lens[:, None]
-
-        def over_rows():
-            with jax.named_scope("kv_read"):
-                keys, values = (
-                    lax.dynamic_index_in_dim(row, layer, 0, keepdims=False)
-                    for row in rows
-                )
-            cols = jnp.arange(cfg.n_ctx)
-            visible_cols = jnp.where(cols < start + t, cols, 2 * cfg.n_ctx)
-            return self._core(
-                q, keys, values, positions, visible_cols[None, :] - pads,
-                pad_lens, k_col=cols,
-            )
-
-        def fresh():
-            return self._core(q, k, v, positions, positions, pad_lens)
-
-        if t > 1:
-            return lax.cond(start == 0, fresh, over_rows), rows
-        return over_rows(), rows
-
 
 class _ScanLayer(nn.Module):
     """Scan-body adapter: carry (x, cache), scanned input the layer's index,

@@ -256,16 +256,25 @@ def route(x, w_router, bias, cfg: Xing4Config):
     return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
-def routed_experts(x, idx, w, valid, w_gate_up, w_down, dtype, layer=0):
+def routed_experts(x, idx, w, valid, w_gate_up, w_down, dtype, layer=0,
+                   lo=None):
     """``Σ_e w_e · FFN_e(x)`` over the chosen experts of tokens ``x`` (N, C)
     with ``valid`` (N,) marking the live ones: the pairs sorted by expert
     through two grouped products. ``w_gate_up`` (L, E, C, 2F) and
     ``w_down`` (L, E, F, C) hold every routed layer's experts, ``layer``
-    says which of them are this call's. Returns (y (N, C) float32, tokens
-    each expert got (E,) int32)."""
+    says which of them are this call's. With ``lo``, the E experts held
+    here are the router's ``[lo, lo + E)`` (expert parallelism: the chip's
+    share), and a pair whose expert lies outside goes to no group, so
+    nothing of it is read or added; without, every expert the router names
+    is held. Returns (y (N, C) float32, tokens each held expert got (E,)
+    int32)."""
     n, k = idx.shape
     n_layers, n_experts = w_gate_up.shape[:2]
-    expert = jnp.where(valid[:, None], idx, n_experts).reshape(-1)
+    held = valid[:, None]
+    if lo is not None:
+        idx = idx - lo
+        held = held & (idx >= 0) & (idx < n_experts)
+    expert = jnp.where(held, idx, n_experts).reshape(-1)
     order = jnp.argsort(expert, stable=True)  # dead pairs sort last
     sizes = jnp.bincount(expert, length=n_experts + 1)[:n_experts].astype(
         jnp.int32
@@ -283,7 +292,7 @@ def routed_experts(x, idx, w, valid, w_gate_up, w_down, dtype, layer=0):
     # Rows past the last group hold nothing the products define.
     live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
     y = jnp.where(live, y, 0.0)[jnp.argsort(order)].reshape(n, k, -1)
-    return jnp.einsum("nkc,nk->nc", y, jnp.where(valid[:, None], w, 0.0)), sizes
+    return jnp.einsum("nkc,nk->nc", y, jnp.where(held, w, 0.0)), sizes
 
 
 # ------------------------------------------------------------------ a layer

@@ -252,8 +252,10 @@ CATALOG: dict[str, tuple[str, str]] = {
         "largest over the block (a routed model: experts_touched, the "
         "distinct experts a live row chose over steps and routed layers; "
         "expert_max_load, the most tokens one expert got in a step over "
-        "the mean) — ServeLedger's snapshot keeps both (step_sum, "
-        "step_max, model_steps)",
+        "the mean; a model with slot state: state_rows, the live rows "
+        "whose state a step moved on, summed over the block's steps) — "
+        "ServeLedger's snapshot keeps both (step_sum, step_max, "
+        "model_steps)",
     ),
     "serve.decode.dispatch": (
         "span",
@@ -462,6 +464,13 @@ CATALOG: dict[str, tuple[str, str]] = {
         "vector padded to whole 128-lane rows (the shape the chip lays "
         "out page-major), so 0 for GPT-2's H*D and 0.1 for a 576-number "
         "latent in 640; set once when the engine allocates the pool",
+    ),
+    "serve.slot_state_bytes": (
+        "gauge",
+        "bytes the engine holds per slot beside the page pool, over every "
+        "slot and the trash row (a model with recurrent state: each Gated "
+        "DeltaNet layer's S and conv state, float32); set once when the "
+        "engine allocates its cache",
     ),
     "serve.masked_row_waste": (
         "gauge",

@@ -137,6 +137,10 @@ class ServeLedger:
         # width)``, the numbers a token holds there and the 128-lane-row
         # width the pool keeps them in.
         self.pool_token_widths: dict[str, tuple[int, int]] = {}
+        # What the engine holds per slot beside the pool (a model with
+        # recurrent state): bytes of every slot-state leaf, trash row
+        # included; None for a model without.
+        self.slot_state_bytes: int | None = None
         self.reset()
 
     def reset(self) -> None:
@@ -379,6 +383,7 @@ class ServeLedger:
             "tokens_per_pass": self.tokens_per_pass,
             "pool_token_widths": dict(self.pool_token_widths),
             "pool_pad_fraction": self.pool_pad_fraction,
+            "slot_state_bytes": self.slot_state_bytes,
             "model_steps": self._model_steps,
             "step_sum": dict(self.step_sum),
             "step_max": dict(self.step_max),
