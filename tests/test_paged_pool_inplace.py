@@ -403,17 +403,20 @@ def _param_shapes(model):
     )
 
 
-def _cell_engine(family, **serve):
+def _cell_engine(family, *, whole=False, **serve):
     """The cell's engine at its own widths, slots and pool, cut to two
-    layers (the layer axis is the pool's major one) and holding shapes
-    for weights: it is traced and lowered, never run."""
+    layers (the layer axis is the pool's major one), or at the cell's own
+    depth where ``whole``, and holding shapes for weights: it is traced
+    and lowered, never run."""
     cell = manifest.load_cell(CELLS[family])
     cfg, fam = cell["config"], cell["family"]
-    m = dict(cfg["model"], n_layer=2)
-    if "first_k_dense" in m:
-        m["first_k_dense"] = 1  # one dense layer and one routed
-    if "full_attention_interval" in m:  # one whole period
-        m["n_layer"] = m["full_attention_interval"]
+    m = dict(cfg["model"])
+    if not whole:
+        m["n_layer"] = 2
+        if "first_k_dense" in m:
+            m["first_k_dense"] = 1  # one dense layer and one routed
+        if "full_attention_interval" in m:  # one whole period
+            m["n_layer"] = m["full_attention_interval"]
     model = fam.module(m)
     return ServeEngine(
         model, _param_shapes(model), buckets=list(cell["traffic"]["buckets"])[:1],
@@ -516,6 +519,93 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     if program != "verify":
         pool_bytes = min(leaf.size * leaf.dtype.itemsize for leaf in pool)
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+def _buffers(text):
+    """Every instruction of a compiled program that yields a buffer (those
+    of the computations that no fusion calls): (name, op, its arrays as
+    (dtype, dims), its operands' arrays)."""
+    comps, cur, fused = {}, None, set()
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line.strip())
+            if re.search(r"\bfusion\(", line):
+                fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    arrays = lambda s: [  # noqa: E731
+        (dt, tuple(int(d) for d in dims.split(",") if d))
+        for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", s)
+    ]
+    out = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        held = {}
+        for line in lines:
+            m = re.match(r"(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)", line)
+            if not m:
+                continue
+            held[m.group(1)] = arrays(m.group(2))
+            operands = re.findall(r"%([\w.\-]+)", m.group(4))
+            out.append((m.group(1), m.group(3), held[m.group(1)],
+                        [a for o in operands for a in held.get(o, [])]))
+    return out
+
+
+def test_the_chip_reads_each_gated_deltanet_layer_where_it_lies(one_chip):
+    """``serve-qwen3next-hybrid``'s decode block at the cell's own depth,
+    two whole periods (a loop of one trip may hide a slab), compiled for
+    the described chip at the narrowest rung: the Gated DeltaNet layers'
+    products read their weights out of the stacked leaves
+    ``(periods, 3, ...)`` where they lie. No instruction but a parameter,
+    a bitcast or a get-tuple-element yields a buffer of a period's leaf
+    (``bf16[3,2048,12288]``, ``bf16[3,4096,2048]``, ...), and none yields
+    one of a layer's leaf of 1 MB or more, with or without a leading 1, out
+    of a stack or a period of them: per-layer copies would move the same
+    bytes. When each period's slice of the stack was an operand of the
+    period's inner loop, the chip made it a buffer of its own every step
+    (``bf16[3,2048,12288]``, 151 MB) and ``memory_analysis()`` read
+    314,399,744 bytes of temporaries; 82,761,728 since."""
+    eng = _cell_engine("qwen3_next", whole=True)
+    cfg = eng.model.config
+    assert cfg.n_period == 2
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    compiled = eng._decode.lower(
+        described(eng.params), described(eng._cache),
+        *described(eng._decode_warm_args(*eng.decode_shapes[0])),
+    ).compile()
+    gdn = jax.tree_util.tree_leaves(eng.params["periods"]["gdn"]["layer"])
+    hlo = {"bfloat16": "bf16", "float32": "f32"}
+    stacks = {(hlo[a.dtype.name], a.shape) for a in gdn}
+    periods = {(hlo[a.dtype.name], a.shape[1:]) for a in gdn}
+    assert ("bf16", (3, 2048, 12288)) in periods
+    layers = set()
+    for a in gdn:
+        if math.prod(a.shape[2:]) * a.dtype.itemsize >= 2**20:
+            layers |= {(hlo[a.dtype.name], a.shape[2:]),
+                       (hlo[a.dtype.name], (1,) + a.shape[2:])}
+    assert ("bf16", (4096, 2048)) in layers
+    slabs, copies = [], []
+    for name, op, out, operands in _buffers(compiled.as_text()):
+        if op in ("parameter", "bitcast", "get-tuple-element"):
+            continue
+        if set(out) & periods:
+            slabs.append((name, op, out))
+        if set(out) & layers and set(operands) & (stacks | periods):
+            copies.append((name, op, out))
+    assert not slabs, slabs
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes <= 314_399_744 - 150e6
 
 
 @pytest.mark.slow

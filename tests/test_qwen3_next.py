@@ -142,6 +142,26 @@ def test_left_padding_leaves_the_state_as_the_prompt_alone_makes_it(t, pads):
 
 
 # ------------------------------------------------ (b) the module's forward
+def test_init_declares_the_tree_the_family_builds():
+    """``Qwen3Next.init`` declares the family's tree path for path, shape
+    for shape and dtype for dtype: the Gated DeltaNet layers' leaves
+    stacked ``(periods, 3, ...)`` under ``periods/gdn/layer``, the attention
+    layers' ``(periods, ...)`` under ``periods/attn``, whatever loops read
+    them. Each layer has a draw of its own."""
+    model = FAM.module(M)
+    declared = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    built = jax.eval_shape(lambda k: FAM.make_params(M, k), seed_key(SEED))
+
+    def leaves(tree):
+        return {jax.tree_util.keystr(path): (leaf.shape, leaf.dtype)
+                for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+    assert leaves(declared) == leaves(built)
+    qkvz = declared["periods"]["gdn"]["layer"]["in_qkvz"]
+    assert qkvz.shape[:2] == (M["n_layer"] // M["full_attention_interval"], 3)
+    assert not np.allclose(qkvz[0, 0], qkvz[0, 1]) and not np.allclose(qkvz[0, 0], qkvz[1, 0])
+
+
 def test_forward_equals_the_reference(params):
     rng = np.random.default_rng(1)
     toks = rng.integers(0, 256, size=(2, 41)).astype(np.int32)

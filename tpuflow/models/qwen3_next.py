@@ -56,6 +56,20 @@ a pair whose expert lies elsewhere adds nothing here
 (``models/xing4.py`` ``routed_experts``). One shared expert of width 512,
 scaled by ``sigmoid(w_sg · ĥ)`` (float32). Final norm, untied head.
 
+**The layer stack.** A loop over the ``n_period`` periods; in each, a loop
+over its three Gated DeltaNet layers, then its attention layer. Weights are
+declared stacked by kind: the Gated DeltaNet layers' under
+``periods/gdn/layer`` at ``(n_period, 3, ...)``, the attention layers' under
+``periods/attn`` at ``(n_period, ...)``, every layer's routed experts in two
+leaves of their own. Both loops close over the whole stacks, and a layer
+reads its weights out of them by index (``[period, j]``, ``[period]``,
+``layer``) inside the loop that runs it, where the chip fuses the read into
+the product. A period's slice of a stack is never a loop's operand (a
+scanned input, as flax's ``variable_axes`` makes it): the compiler makes
+such an operand a buffer of its own, and each decode step would copy every
+period's 151 MB of ``in_qkvz``, reading and writing it before the products
+read it once more.
+
 Precision: weights at rest and in the products in ``dtype`` (bfloat16) with
 float32 accumulation; the router, the residual stream, the norms'
 statistics, softmax, logits, g, β, the state S and the conv state in
@@ -191,24 +205,32 @@ def _put_state_rows(leaf, new, layer, slots):
 
 class _Block(nn.Module):
     """What both kinds of layer share: the routed feed-forward with its
-    shared expert, and their parameters' declaration."""
+    shared expert, and their parameters' declaration. Every leaf is
+    declared with ``stack`` in front, the layers of this kind stacked
+    (``(n_period,)`` or ``(n_period, 3)``), and a call reads its own layer
+    out of it by index ``at``, inside the loop that runs the layer."""
 
     config: Qwen3NextConfig
+    stack: tuple[int, ...]
 
-    def _p(self, name, shape, init=None, dtype=None):
+    def _p(self, name, shape, at, init=None, dtype=None):
         init = init or nn.initializers.normal(0.02)
-        return self.param(name, init, shape, dtype or self.config.dtype)
+        leaf = self.param(name, init, self.stack + tuple(shape),
+                          dtype or self.config.dtype)
+        for i in at:
+            leaf = lax.dynamic_index_in_dim(leaf, i, 0, keepdims=False)
+        return leaf
 
-    def _norm(self, name, x):
-        w = self._p(name, (x.shape[-1],), nn.initializers.zeros)
+    def _norm(self, name, x, at):
+        w = self._p(name, (x.shape[-1],), at, nn.initializers.zeros)
         return centred_norm(x, w, self.config.rms_norm_eps)
 
-    def _moe(self, x, valid, experts, layer):
+    def _moe(self, x, at, valid, experts, layer):
         cfg = self.config
         b, t, c = x.shape
         flat = x.reshape(b * t, c)
         with jax.named_scope("router"):
-            idx, w = route(flat, self._p("router", (c, cfg.n_experts)), cfg)
+            idx, w = route(flat, self._p("router", (c, cfg.n_experts), at), cfg)
         with jax.named_scope("moe_experts"):
             y, sizes = routed_experts(
                 flat, idx, w, valid.reshape(-1), *experts, cfg.dtype, layer,
@@ -217,12 +239,12 @@ class _Block(nn.Module):
         with jax.named_scope("moe_shared"):
             f = cfg.shared_expert_intermediate_size
             shared = gated_ffn(
-                flat, self._p("shared_gate_up", (c, 2 * f)),
-                self._p("shared_down", (f, c)), cfg.dtype,
+                flat, self._p("shared_gate_up", (c, 2 * f), at),
+                self._p("shared_down", (f, c), at), cfg.dtype,
             )
             gate = jax.nn.sigmoid(jnp.einsum(
                 "nc,co->no", flat.astype(jnp.float32),
-                self._p("shared_gate", (c, 1)).astype(jnp.float32),
+                self._p("shared_gate", (c, 1), at).astype(jnp.float32),
                 precision=HIGHEST,
             ))
             y = y + gate * shared
@@ -232,18 +254,21 @@ class _Block(nn.Module):
 class GatedDeltaLayer(_Block):
     """A Gated DeltaNet layer and its feed-forward on the stream ``x`` (B,
     T, C) float32. ``cache`` is (keys, values, conv, state), threaded
-    through; ``gdn`` indexes this layer in the last two, ``layer`` in
-    ``experts``. ``slots`` (B,): each row's state row in the engine's
-    leaves, None where the leaves are the call's own rows."""
+    through; ``at`` (period, j) indexes this layer in its stacked leaves,
+    ``gdn`` in the last two of the cache, ``layer`` in ``experts``.
+    ``slots`` (B,): each row's state row in the engine's leaves, None where
+    the leaves are the call's own rows."""
 
     @nn.compact
-    def __call__(self, x, cache, gdn, layer, valid, experts, slots):
-        h, cache = self._mixer(self._norm("attn_norm", x), cache, gdn, valid, slots)
+    def __call__(self, x, cache, at, gdn, layer, valid, experts, slots):
+        h, cache = self._mixer(
+            self._norm("attn_norm", x, at), cache, at, gdn, valid, slots
+        )
         h = x + h
-        y, sizes = self._moe(self._norm("mlp_norm", h), valid, experts, layer)
+        y, sizes = self._moe(self._norm("mlp_norm", h, at), at, valid, experts, layer)
         return h + y, cache, sizes
 
-    def _mixer(self, x, cache, gdn, valid, slots):
+    def _mixer(self, x, cache, at, gdn, valid, slots):
         cfg = self.config
         dt = cfg.dtype
         b, t, c = x.shape
@@ -252,14 +277,14 @@ class GatedDeltaLayer(_Block):
         n_conv, width = cfg.conv_dim, cfg.linear_conv_kernel_dim
         keys, values, conv_leaf, state_leaf = cache
         with jax.named_scope("gdn_in"):
-            qkvz = dot(x, self._p("in_qkvz", (c, n_conv + hv * dv)), dt)
-            ba = dot(x, self._p("in_ba", (c, 2 * hv)), dt, jnp.float32)
+            qkvz = dot(x, self._p("in_qkvz", (c, n_conv + hv * dv), at), dt)
+            ba = dot(x, self._p("in_ba", (c, 2 * hv), at), dt, jnp.float32)
             mixed, z = qkvz[..., :n_conv], qkvz[..., n_conv:]
         with jax.named_scope("gdn_conv"):
             mixed = jnp.where(valid[..., None], mixed.astype(jnp.float32), 0.0)
             prev = _state_rows(conv_leaf, gdn, slots).reshape(b, width - 1, n_conv)
             window = jnp.concatenate([prev, mixed], axis=1)  # (B, width - 1 + T, channels)
-            w = self._p("conv", (width, n_conv)).astype(jnp.float32)
+            w = self._p("conv", (width, n_conv), at).astype(jnp.float32)
             out = sum(w[j] * window[:, j:j + t] for j in range(width))
             conv_leaf = _put_state_rows(
                 conv_leaf, window[:, t:].reshape(b, -1), gdn, slots
@@ -270,8 +295,8 @@ class GatedDeltaLayer(_Block):
             k = l2_normalize(mixed[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk))
             v = mixed[..., 2 * hk * dk:].reshape(b, t, hv, dv)
             q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
-            a_log = self._p("A_log", (hv,), nn.initializers.zeros, jnp.float32)
-            dt_bias = self._p("dt_bias", (hv,), nn.initializers.ones, jnp.float32)
+            a_log = self._p("A_log", (hv,), at, nn.initializers.zeros, jnp.float32)
+            dt_bias = self._p("dt_bias", (hv,), at, nn.initializers.ones, jnp.float32)
             beta = jnp.where(valid[..., None], jax.nn.sigmoid(ba[..., :hv]), 0.0)
             g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
                 ba[..., hv:] + dt_bias.astype(jnp.float32)
@@ -285,28 +310,30 @@ class GatedDeltaLayer(_Block):
                 o, s = gated_delta.chunked(q, k, v, g, beta, s, cfg.gdn_chunk)
             state_leaf = _put_state_rows(state_leaf, s, gdn, slots)
         with jax.named_scope("gdn_out"):
-            o = rms_norm(o, self._p("out_norm", (dv,), nn.initializers.ones),
+            o = rms_norm(o, self._p("out_norm", (dv,), at, nn.initializers.ones),
                          cfg.rms_norm_eps)
             o = o * jax.nn.silu(z.reshape(b, t, hv, dv).astype(jnp.float32))
             out_init = nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer))
-            y = dot(o.reshape(b, t, hv * dv), self._p("out", (hv * dv, c), out_init),
+            y = dot(o.reshape(b, t, hv * dv), self._p("out", (hv * dv, c), at, out_init),
                     dt, jnp.float32)
         return y, (keys, values, conv_leaf, state_leaf)
 
 
 class GatedAttentionLayer(_Block):
-    """Gated full attention and its feed-forward; ``attn`` indexes this
-    layer in the key/value cache. The cache's paths are SDAR's."""
+    """Gated full attention and its feed-forward; ``attn`` (the period)
+    indexes this layer in its stacked leaves and in the key/value cache.
+    The cache's paths are SDAR's."""
 
     @nn.compact
     def __call__(self, x, cache, attn, layer, positions, valid, experts, *,
                  decode, pad_lens, slot_index, page_table, start):
+        at = (attn,)
         h, cache = self._attention(
-            self._norm("attn_norm", x), cache, attn, positions, decode,
+            self._norm("attn_norm", x, at), cache, attn, positions, decode,
             pad_lens, slot_index, page_table, start,
         )
         h = x + h
-        y, sizes = self._moe(self._norm("mlp_norm", h), valid, experts, layer)
+        y, sizes = self._moe(self._norm("mlp_norm", h, at), at, valid, experts, layer)
         return h + y, cache, sizes
 
     def _attention(self, x, cache, attn, positions, decode, pad_lens,
@@ -316,20 +343,21 @@ class GatedAttentionLayer(_Block):
         b, t, c = x.shape
         h, g, d = cfg.n_head, cfg.n_kv_head, cfg.head_dim
         kv, rest = cache[:2], cache[2:]
+        at = (attn,)
         with jax.named_scope("attn_q"):
-            qg = dot(x, self._p("q", (c, h * 2 * d)), dt).reshape(b, t, h, 2 * d)
+            qg = dot(x, self._p("q", (c, h * 2 * d), at), dt).reshape(b, t, h, 2 * d)
             gate = qg[..., d:]
             q = rotate_partial(
-                centred_norm(qg[..., :d], self._p("q_norm", (d,), nn.initializers.zeros), eps),
+                centred_norm(qg[..., :d], self._p("q_norm", (d,), at, nn.initializers.zeros), eps),
                 positions, cfg,
             )
         with jax.named_scope("kv_write"):
-            k = dot(x, self._p("k", (c, g * d)), dt).reshape(b, t, g, d)
+            k = dot(x, self._p("k", (c, g * d), at), dt).reshape(b, t, g, d)
             k = rotate_partial(
-                centred_norm(k, self._p("k_norm", (d,), nn.initializers.zeros), eps),
+                centred_norm(k, self._p("k_norm", (d,), at, nn.initializers.zeros), eps),
                 positions, cfg,
             ).reshape(b, t, g * d)
-            v = dot(x, self._p("v", (c, g * d)), dt)
+            v = dot(x, self._p("v", (c, g * d), at), dt)
         if not decode:
             a = grouped_attention(q, k, v, positions, positions, pad_lens, cfg)
         elif page_table is not None:
@@ -342,30 +370,47 @@ class GatedAttentionLayer(_Block):
             a = (a.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
         with jax.named_scope("attn_out"):
             out_init = nn.initializers.normal(0.02 / math.sqrt(2 * cfg.n_layer))
-            y = dot(a.reshape(b, t, h * d), self._p("o", (h * d, c), out_init),
+            y = dot(a.reshape(b, t, h * d), self._p("o", (h * d, c), at, out_init),
                     dt, jnp.float32)
         return y, kv + rest
 
 
 class _GdnScan(nn.Module):
     """Scan-body adapter over a period's Gated DeltaNet layers: carry (x,
-    cache), scanned inputs their indices."""
+    cache), scanned input ``j``, the layer's place in the period."""
 
     config: Qwen3NextConfig
 
     @nn.compact
-    def __call__(self, carry, gdn, layer, valid, experts, slots):
+    def __call__(self, carry, j, period, valid, experts, slots):
+        cfg = self.config
+        n = cfg.full_attention_interval - 1
         x, cache = carry
-        x, cache, sizes = GatedDeltaLayer(self.config, name="layer")(
-            x, cache, gdn, layer, valid, experts, slots
+        x, cache, sizes = GatedDeltaLayer(cfg, (cfg.n_period, n), name="layer")(
+            x, cache, (period, j), period * n + j, period * (n + 1) + j,
+            valid, experts, slots,
         )
         return (x, cache), sizes
 
 
+def _scan(module, ctx: nn.Module, **kw):
+    """``nn.scan`` of a body whose parameters the loop closes over whole
+    (the body declares them stacked and indexes its own layer). Flax's
+    check that they are loop invariants traces the body a second time: it
+    runs where the parameters are made, not where they are read."""
+    return nn.scan(
+        module, variable_broadcast="params", split_rngs={"params": False},
+        check_constancy_invariants=ctx.is_initializing(), **kw,
+    )
+
+
 class _Period(nn.Module):
     """One period: ``full_attention_interval - 1`` Gated DeltaNet layers (a
-    scan of their own) and one gated attention layer. Scan-body adapter:
-    carry (x, cache), scanned input the period's index."""
+    loop of their own, which closes over their whole stacked leaves and
+    reads each layer's at ``[period, j]``: the period's slice as the loop's
+    operand would be copied every step, module docstring) and one gated
+    attention layer. Scan-body adapter: carry (x, cache), scanned input the
+    period's index."""
 
     config: Qwen3NextConfig
 
@@ -374,16 +419,11 @@ class _Period(nn.Module):
                  pad_lens, slot_index, page_table, start, slots):
         cfg = self.config
         n = cfg.full_attention_interval - 1
-        scan = nn.scan(
-            _GdnScan, variable_axes={"params": 0},
-            split_rngs={"params": True}, length=n,
-            in_axes=(0, 0, nn.broadcast, nn.broadcast, nn.broadcast),
-        )
+        scan = _scan(_GdnScan, self, length=n, in_axes=(0,) + (nn.broadcast,) * 4)
         (x, cache), gdn_sizes = scan(cfg, name="gdn")(
-            carry, period * n + jnp.arange(n),
-            period * (n + 1) + jnp.arange(n), valid, experts, slots,
+            carry, jnp.arange(n), period, valid, experts, slots,
         )
-        x, cache, sizes = GatedAttentionLayer(cfg, name="attn")(
+        x, cache, sizes = GatedAttentionLayer(cfg, (cfg.n_period,), name="attn")(
             x, cache, period, period * (n + 1) + n, positions, valid, experts,
             decode=decode, pad_lens=pad_lens, slot_index=slot_index,
             page_table=page_table, start=start,
@@ -490,10 +530,8 @@ class Qwen3Next(nn.Module):
                 (cfg.n_layer, cfg.experts_held, f, c), cfg.dtype,
             ),
         )
-        scan = nn.scan(
-            _Period, variable_axes={"params": 0},
-            split_rngs={"params": True}, length=cfg.n_period,
-            in_axes=(0,) + (nn.broadcast,) * 9,
+        scan = _scan(
+            _Period, self, length=cfg.n_period, in_axes=(0,) + (nn.broadcast,) * 9
         )
         (x, cache), sizes = scan(cfg, name="periods")(
             (x, cache), jnp.arange(cfg.n_period), positions, valid, experts,
