@@ -391,7 +391,8 @@ def test_export_import_round_trips_a_page_set(rig):
 
 # ------------------------- the pool's shape, and what the chip makes of it
 CELLS = {"gpt2": "serve-medium-chat", "xing4": "serve-xing4-reason",
-         "sdar": "serve-sdar-blockgen", "qwen3_next": "serve-qwen3next-hybrid"}
+         "sdar": "serve-sdar-blockgen", "qwen3_next": "serve-qwen3next-hybrid",
+         "olmo_hybrid": "serve-olmohybrid-chat"}
 
 
 def _param_shapes(model):
@@ -556,6 +557,47 @@ def _buffers(text):
     return out
 
 
+def _stacked_reads(one_chip, family, stacks):
+    """The cell's decode block at its own depth, compiled for the described
+    chip at the narrowest rung: (the engine, the compiled block, the
+    buffers that hold a period's slab of a Gated DeltaNet leaf, the
+    buffers that hold one layer's leaf of 1 MB or more, with or without a
+    leading 1, made out of a stack or a period of one). ``stacks(params)``
+    names the stacked subtrees whose layers the loops read by index; the
+    Gated DeltaNet one (``(periods, 3, ...)``) first. A parameter, a
+    bitcast or a get-tuple-element is no buffer of its own."""
+    eng = _cell_engine(family, whole=True)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    compiled = eng._decode.lower(
+        described(eng.params), described(eng._cache),
+        *described(eng._decode_warm_args(*eng.decode_shapes[0])),
+    ).compile()
+    hlo = {"bfloat16": "bf16", "float32": "f32"}
+    gdn, *rest = (jax.tree_util.tree_leaves(t) for t in stacks(eng.params))
+    stacked = {(hlo[a.dtype.name], a.shape) for a in gdn + sum(rest, [])}
+    periods = {(hlo[a.dtype.name], a.shape[1:]) for a in gdn}
+    layers = set()
+    for a, lead in [(a, 2) for a in gdn] + [(a, 1) for a in sum(rest, [])]:
+        if math.prod(a.shape[lead:]) * a.dtype.itemsize >= 2**20:
+            layers |= {(hlo[a.dtype.name], a.shape[lead:]),
+                       (hlo[a.dtype.name], (1,) + a.shape[lead:])}
+    slabs, copies = [], []
+    for name, op, out, operands in _buffers(compiled.as_text()):
+        if op in ("parameter", "bitcast", "get-tuple-element"):
+            continue
+        if set(out) & periods:
+            slabs.append((name, op, out))
+        if set(out) & layers and set(operands) & (stacked | periods):
+            copies.append((name, op, out))
+    return eng, compiled, periods, layers, slabs, copies
+
+
 def test_the_chip_reads_each_gated_deltanet_layer_where_it_lies(one_chip):
     """``serve-qwen3next-hybrid``'s decode block at the cell's own depth,
     two whole periods (a loop of one trip may hide a slab), compiled for
@@ -570,42 +612,46 @@ def test_the_chip_reads_each_gated_deltanet_layer_where_it_lies(one_chip):
     period's inner loop, the chip made it a buffer of its own every step
     (``bf16[3,2048,12288]``, 151 MB) and ``memory_analysis()`` read
     314,399,744 bytes of temporaries; 82,761,728 since."""
-    eng = _cell_engine("qwen3_next", whole=True)
-    cfg = eng.model.config
-    assert cfg.n_period == 2
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            tree,
-        )
-
-    compiled = eng._decode.lower(
-        described(eng.params), described(eng._cache),
-        *described(eng._decode_warm_args(*eng.decode_shapes[0])),
-    ).compile()
-    gdn = jax.tree_util.tree_leaves(eng.params["periods"]["gdn"]["layer"])
-    hlo = {"bfloat16": "bf16", "float32": "f32"}
-    stacks = {(hlo[a.dtype.name], a.shape) for a in gdn}
-    periods = {(hlo[a.dtype.name], a.shape[1:]) for a in gdn}
+    eng, compiled, periods, layers, slabs, copies = _stacked_reads(
+        one_chip, "qwen3_next", lambda p: [p["periods"]["gdn"]["layer"]]
+    )
+    assert eng.model.config.n_period == 2
     assert ("bf16", (3, 2048, 12288)) in periods
-    layers = set()
-    for a in gdn:
-        if math.prod(a.shape[2:]) * a.dtype.itemsize >= 2**20:
-            layers |= {(hlo[a.dtype.name], a.shape[2:]),
-                       (hlo[a.dtype.name], (1,) + a.shape[2:])}
     assert ("bf16", (4096, 2048)) in layers
-    slabs, copies = [], []
-    for name, op, out, operands in _buffers(compiled.as_text()):
-        if op in ("parameter", "bitcast", "get-tuple-element"):
-            continue
-        if set(out) & periods:
-            slabs.append((name, op, out))
-        if set(out) & layers and set(operands) & (stacks | periods):
-            copies.append((name, op, out))
     assert not slabs, slabs
     assert not copies, copies
     assert compiled.memory_analysis().temp_size_in_bytes <= 314_399_744 - 150e6
+
+
+def test_the_chip_reads_each_olmo_hybrid_layer_where_it_lies(one_chip):
+    """The same guard on ``serve-olmohybrid-chat``'s decode block, the
+    dense stack's: four whole periods, compiled for the described chip at
+    the narrowest rung. No buffer of a period's Gated DeltaNet leaf
+    (``bf16[3,3840,17280]`` of ``in_qkvz``, 398 MB; ``bf16[3,3840,22016]``
+    of the feed-forward's ``gate_up``, 507 MB), and none of a layer's leaf
+    of 1 MB or more made out of either kind's stack (the attention
+    layers' ``bf16[3840,3840]`` and ``bf16[3840,22016]`` among them).
+    S is read and written by (slot, head): gathered by slot, a row of it
+    (30 x 96 x 192, 2.1 MB) was split by the compiler into 128- and
+    64-lane pieces, each of which sliced the whole leaf
+    (``mini-gather-slice`` ``f32[12,49,30,96,128]``) every layer and
+    step, and ``memory_analysis()`` read 924,531,200 bytes of temporaries
+    at this rung; 108,994,560 since. One period's Gated DeltaNet weights
+    as a buffer of their own would add 1.29 GB."""
+    eng, compiled, periods, layers, slabs, copies = _stacked_reads(
+        one_chip, "olmo_hybrid",
+        lambda p: [p["periods"]["gdn"]["layer"], p["periods"]["attn"]],
+    )
+    assert eng.model.config.n_period == 4
+    assert {("bf16", (3, 3840, 17280)), ("bf16", (3, 3840, 22016))} <= periods
+    assert {("bf16", (3840, 3840)), ("bf16", (3840, 22016)), ("bf16", (5760, 3840))} <= layers
+    assert not slabs, slabs
+    assert not copies, copies
+    heads = ",".join(map(str, eng._cache["state"].shape[2:4]))
+    split = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if "mini-gather" in line and heads + "," in line]
+    assert not split, split
+    assert compiled.memory_analysis().temp_size_in_bytes <= 108_994_560 + 0.2e9
 
 
 @pytest.mark.slow

@@ -2855,11 +2855,16 @@ class ServeEngine:
             # anyway so warmup is observationally a no-op. x*0 (not a
             # fresh zeros tree): the result stays committed exactly like
             # every later decode/insert output, so the program signatures
-            # warmed above are the ones the serving loop replays.
+            # warmed above are the ones the serving loop replays. In
+            # place, the cache donated: a new tree beside the old one
+            # would need the cache's bytes twice.
             with program("reset"):
-                self._cache = jax.tree_util.tree_map(
-                    lambda x: x * 0, self._cache
-                )
+                self._cache = jax.jit(
+                    lambda cache: jax.tree_util.tree_map(
+                        lambda x: x * 0, cache
+                    ),
+                    donate_argnums=0,
+                )(self._cache)
                 jax.block_until_ready(self._cache)
             stats = self.compile_stats()
             sp.set(**stats)

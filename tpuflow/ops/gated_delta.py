@@ -1,10 +1,17 @@
-"""The gated delta rule (Gated DeltaNet; Qwen3-Next's linear-attention
-layers), per head, with a state ``S`` of (key dim, value dim):
+"""The gated delta rule (Gated DeltaNet; the linear-attention layers of
+``models/hybrid.py``), per head, with a state ``S`` of (key dim, value
+dim), the two free to differ (Olmo-Hybrid's 96 × 192):
 
     S ← e^g · S;   δ = β (v − Sᵀk);   S ← S + k δᵀ;   o = Sᵀq
 
 for every token in order, ``g ≤ 0`` the log of the decay and ``β`` in (0,
-1) the write strength. Two forms of it, one result:
+2) the write strength: up to 1 a model whose transition ``I − β k kᵀ``
+keeps eigenvalues in [0, 1] (Qwen3-Next), up to 2 one that lets them reach
+−1 (fla's ``allow_neg_eigval``, Olmo-Hybrid). The chunked form's
+triangular solve then meets entries of ``A`` up to 2 in magnitude; on
+keys as the models make them it agrees with the recurrence there as it
+does below 1 (``tests/test_olmo_hybrid.py``). Two forms of it, one
+result:
 
 - ``chunked``: a prompt at once, ``chunk`` tokens at a time. Within a chunk
   every product is a dense one (the WY form of the delta rule: the chunk's
