@@ -143,27 +143,6 @@ def test_left_padding_leaves_the_wide_state_as_the_prompt_alone_makes_it(t, pads
         assert not np.asarray(got_o[r, :p]).any()
 
 
-def test_a_slots_state_over_2_mib_is_read_and_written_by_head_where_it_lies():
-    """At the cell's S of 30 x 96 x 192 (2.1 MB a slot, over
-    ``WHOLE_ROW_BYTES``) the rows are taken by (slot, head) from a view of
-    the same bytes: the gather equals indexing by slot, the scatter writes
-    those slots alone; Qwen3-Next's 32 x 128 x 128 (2 MiB) keeps the row a
-    slot."""
-    from tpuflow.models import hybrid
-
-    rng = np.random.default_rng(12)
-    leaf = jnp.asarray(rng.standard_normal((2, 4, 30, 96, 192)), jnp.float32)
-    slots = jnp.asarray([3, 0], jnp.int32)
-    flat, rows = hybrid._head_rows(leaf, slots)
-    assert flat.shape == (2, 120, 96, 192) and rows.shape == (60,)
-    np.testing.assert_array_equal(hybrid._state_rows(leaf, 1, slots), leaf[1, slots])
-    new = jnp.asarray(rng.standard_normal((2, 30, 96, 192)), jnp.float32)
-    put = hybrid._put_state_rows(leaf, new, 1, slots)
-    np.testing.assert_array_equal(put, leaf.at[1, slots].set(new))
-    qwen = jax.ShapeDtypeStruct((1, 3, 32, 128, 128), jnp.float32)
-    assert hybrid._head_rows(qwen, slots)[0] is qwen
-
-
 # ------------------------------------------------ (b) the module's forward
 def test_init_declares_the_tree_the_family_builds():
     """The shared stack declares the family's tree path for path, shape for

@@ -443,13 +443,26 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture
+def chip_kernels(monkeypatch):
+    """Programs lowered for the described chip as the chip runs them: off
+    the TPU a Gated DeltaNet layer's decode step would take the gather,
+    ``step`` and the scatter, and a kernel the interpreter."""
+    from tpuflow.ops import gated_delta
+
+    monkeypatch.setattr(gated_delta, "in_place", lambda: True, raising=False)
+    monkeypatch.setattr(gated_delta, "_interpret", lambda: False, raising=False)
+
+
 @pytest.mark.parametrize(
     "family,program",
     [("gpt2", "decode"), ("gpt2", "insert"), ("gpt2", "verify"),
      ("xing4", "decode"), ("xing4", "insert"),
      ("sdar", "decode"), ("sdar", "insert"),
-     ("qwen3_next", "decode"), ("qwen3_next", "insert")],
+     ("qwen3_next", "decode"), ("qwen3_next", "insert"),
+     ("olmo_hybrid", "decode")],
 )
+@pytest.mark.usefixtures("chip_kernels")
 def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     one_chip, family, program
 ):
@@ -462,7 +475,14 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     block's keys and values into the pool by index. The fourth holds state
     per slot beside the pool (``f32[3,65,32,128,128]``,
     ``f32[3,65,24576]`` for one period): those leaves too enter row-major
-    and are never copied."""
+    and are never copied, and so the fifth's (``f32[3,49,30,96,192]``,
+    ``f32[3,49,34560]``), whose S ends in the published head's 192 lanes,
+    which the chip's tiles pad to 256. A leaf of state small enough for
+    the chip's fast memory (the conv's, 19 MB here, 81 MB in
+    ``serve-olmohybrid-chat``) was staged through it whole around every
+    layer's update (``copy-done f32[3,65,24576]``), around the gather and
+    scatter of an XLA decode step and around a kernel that left the leaf's
+    memory to the compiler alike."""
     eng = _cell_engine(family, **({"speculative": 2} if program == "verify" else {}))
     pool = [leaf for _, leaf in eng._cache_leaf_items(eng._cache)]
     state = [eng._cache[k] for k in eng.slot_state]
@@ -496,7 +516,9 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
     text = compiled.as_text()
     entry = re.search(r"entry_computation_layout=\{(.*)\}\n", text).group(1)
     for leaf in pool + state:
-        assert leaf.shape[-1] % paged_pool.LANES == 0
+        assert leaf.shape[-1] % paged_pool.LANES == 0 or (family, leaf.shape[-1]) == (
+            "olmo_hybrid", 192
+        )
         dims = ",".join(map(str, leaf.shape))
         layouts = set(re.findall(r"\[" + dims + r"\]\{([\d,]*)", entry))
         major_to_minor = ",".join(str(i) for i in reversed(range(leaf.ndim)))
@@ -522,9 +544,9 @@ def test_the_chip_keeps_the_pool_page_major_and_copies_none_of_it(
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
-def _buffers(text):
-    """Every instruction of a compiled program that yields a buffer (those
-    of the computations that no fusion calls): (name, op, its arrays as
+def _instructions(text):
+    """Every instruction of a compiled program, fused computations' too:
+    (its computation, whether a fusion calls it, name, op, its arrays as
     (dtype, dims), its operands' arrays)."""
     comps, cur, fused = {}, None, set()
     for line in text.splitlines():
@@ -542,9 +564,7 @@ def _buffers(text):
         for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", s)
     ]
     out = []
-    for name, lines in comps.items():
-        if name in fused:
-            continue
+    for comp, lines in comps.items():
         held = {}
         for line in lines:
             m = re.match(r"(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)", line)
@@ -552,9 +572,34 @@ def _buffers(text):
                 continue
             held[m.group(1)] = arrays(m.group(2))
             operands = re.findall(r"%([\w.\-]+)", m.group(4))
-            out.append((m.group(1), m.group(3), held[m.group(1)],
+            out.append((comp, comp in fused, m.group(1), m.group(3), held[m.group(1)],
                         [a for o in operands for a in held.get(o, [])]))
     return out
+
+
+def _buffers(text):
+    """Every instruction of a compiled program that yields a buffer (those
+    of the computations that no fusion calls): (name, op, its arrays as
+    (dtype, dims), its operands' arrays)."""
+    return [(name, op, arrays, operands)
+            for _, fused, name, op, arrays, operands in _instructions(text) if not fused]
+
+
+def _decode_block(one_chip, family):
+    """The cell's engine at its own depth and its decode block compiled for
+    the described chip at the narrowest rung."""
+    eng = _cell_engine(family, whole=True)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    return eng, eng._decode.lower(
+        described(eng.params), described(eng._cache),
+        *described(eng._decode_warm_args(*eng.decode_shapes[0])),
+    ).compile()
 
 
 def _stacked_reads(one_chip, family, stacks):
@@ -566,18 +611,7 @@ def _stacked_reads(one_chip, family, stacks):
     names the stacked subtrees whose layers the loops read by index; the
     Gated DeltaNet one (``(periods, 3, ...)``) first. A parameter, a
     bitcast or a get-tuple-element is no buffer of its own."""
-    eng = _cell_engine(family, whole=True)
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            tree,
-        )
-
-    compiled = eng._decode.lower(
-        described(eng.params), described(eng._cache),
-        *described(eng._decode_warm_args(*eng.decode_shapes[0])),
-    ).compile()
+    eng, compiled = _decode_block(one_chip, family)
     hlo = {"bfloat16": "bf16", "float32": "f32"}
     gdn, *rest = (jax.tree_util.tree_leaves(t) for t in stacks(eng.params))
     stacked = {(hlo[a.dtype.name], a.shape) for a in gdn + sum(rest, [])}
@@ -598,6 +632,7 @@ def _stacked_reads(one_chip, family, stacks):
     return eng, compiled, periods, layers, slabs, copies
 
 
+@pytest.mark.usefixtures("chip_kernels")
 def test_the_chip_reads_each_gated_deltanet_layer_where_it_lies(one_chip):
     """``serve-qwen3next-hybrid``'s decode block at the cell's own depth,
     two whole periods (a loop of one trip may hide a slab), compiled for
@@ -623,6 +658,7 @@ def test_the_chip_reads_each_gated_deltanet_layer_where_it_lies(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes <= 314_399_744 - 150e6
 
 
+@pytest.mark.usefixtures("chip_kernels")
 def test_the_chip_reads_each_olmo_hybrid_layer_where_it_lies(one_chip):
     """The same guard on ``serve-olmohybrid-chat``'s decode block, the
     dense stack's: four whole periods, compiled for the described chip at
@@ -631,13 +667,14 @@ def test_the_chip_reads_each_olmo_hybrid_layer_where_it_lies(one_chip):
     of the feed-forward's ``gate_up``, 507 MB), and none of a layer's leaf
     of 1 MB or more made out of either kind's stack (the attention
     layers' ``bf16[3840,3840]`` and ``bf16[3840,22016]`` among them).
-    S is read and written by (slot, head): gathered by slot, a row of it
-    (30 x 96 x 192, 2.1 MB) was split by the compiler into 128- and
-    64-lane pieces, each of which sliced the whole leaf
-    (``mini-gather-slice`` ``f32[12,49,30,96,128]``) every layer and
-    step, and ``memory_analysis()`` read 924,531,200 bytes of temporaries
-    at this rung; 108,994,560 since. One period's Gated DeltaNet weights
-    as a buffer of their own would add 1.29 GB."""
+    No piece of S is gathered: gathered by slot, a row of it (30 x 96 x
+    192, 2.1 MB) was split by the compiler into 128- and 64-lane pieces,
+    each of which sliced the whole leaf (``mini-gather-slice``
+    ``f32[12,49,30,96,128]``) every layer and step, and
+    ``memory_analysis()`` read 924,531,200 bytes of temporaries at this
+    rung; 108,994,560 gathered by (slot, head), 74,200,576 with S advanced
+    where it lies. One period's Gated DeltaNet weights as a buffer of
+    their own would add 1.29 GB."""
     eng, compiled, periods, layers, slabs, copies = _stacked_reads(
         one_chip, "olmo_hybrid",
         lambda p: [p["periods"]["gdn"]["layer"], p["periods"]["attn"]],
@@ -652,6 +689,48 @@ def test_the_chip_reads_each_olmo_hybrid_layer_where_it_lies(one_chip):
              if "mini-gather" in line and heads + "," in line]
     assert not split, split
     assert compiled.memory_analysis().temp_size_in_bytes <= 108_994_560 + 0.2e9
+
+
+@pytest.mark.parametrize("family,temp_bytes", [
+    ("qwen3_next", 82_761_728), ("olmo_hybrid", 108_994_560),
+], ids=["qwen3_next", "olmo_hybrid"])
+@pytest.mark.usefixtures("chip_kernels")
+def test_the_chip_advances_each_gated_deltanet_state_where_it_lies(
+    one_chip, family, temp_bytes
+):
+    """Both hybrid cells' decode blocks at their own depth, compiled for
+    the described chip at the narrowest rung: for each leaf of state, S
+    (``f32[6,65,32,128,128]``, ``f32[12,49,30,96,192]``) and the conv's
+    (``f32[6,65,24576]``, ``f32[12,49,34560]``), one Mosaic call in the
+    body of the Gated DeltaNet layers' loop takes the leaf and gives it
+    back, so every layer's trip advances the rows' states where they lie;
+    no gather, scatter or copy in the program, fused or not, has a leaf's
+    shape among its arrays or its operands'. Where the rows were gathered,
+    stepped in XLA's fusions and scattered back, the scatter's output was
+    the leaf of S (``fusion.728`` / ``fusion.386`` of the traced runs), and
+    the conv's leaf crossed to the chip's fast memory and back whole
+    (``copy-done f32[12,49,34560]``). ``memory_analysis()``: 82,761,728
+    and 108,994,560 bytes of temporaries there (Olmo-Hybrid's S gathered
+    by (slot, head)); 79,641,088 and 73,845,760 here."""
+    eng, compiled = _decode_block(one_chip, family)
+    text = compiled.as_text()
+    ops = _instructions(text)
+    for key in ("state", "conv"):
+        leaf = ("f32", eng._cache[key].shape)
+        kernels = [(comp, name) for comp, _, name, op, arrays, _ in ops
+                   if op == "custom-call" and leaf in arrays]
+        assert len(kernels) == 1, (key, kernels)
+        assert [line for line in text.splitlines() if "tpu_custom_call" in line
+                and "custom-call(" in line and kernels[0][1] + " = " in line]
+        body = kernels[0][0]
+        loops = [name for _, _, name, op, arrays, _ in ops if op == "while" and leaf in arrays]
+        assert any(re.search(r"\bbody=%" + re.escape(body) + r"\b", line)
+                   for line in text.splitlines() if " while(" in line), (key, body, loops)
+        moved = [(comp, name, op) for comp, _, name, op, arrays, operands in ops
+                 if op in ("gather", "scatter", "copy", "copy-start")
+                 and leaf in arrays + operands]
+        assert not moved, (key, moved)
+    assert compiled.memory_analysis().temp_size_in_bytes <= temp_bytes
 
 
 @pytest.mark.slow

@@ -314,6 +314,35 @@ def test_rows_joining_leaving_and_reusing_slots_serve_the_references_tokens(para
         assert r.tokens == solo.tokens
 
 
+def test_the_state_advanced_in_place_serves_the_tokens_of_the_gathered_path(
+    params, engine, monkeypatch
+):
+    """The decode program with the kernels that advance S and the conv
+    state where they lie (``gated_delta.step_in_place``,
+    ``conv_step_in_place``: the TPU's path, here through Pallas's
+    interpreter) serves the tokens of the path that gathers, steps and
+    scatters the rows (the CPU's), over rows joining, leaving and reusing
+    slots, and the engine says which it runs."""
+    rng = np.random.default_rng(8)
+    prompts = _prompts(rng, (5, 12, 30, 3, 17, 9))
+    budgets = (9, 3, 14, 6, 11, 5)
+
+    def serve(eng):
+        reqs = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts[:4], budgets)]
+        eng.step()
+        reqs += [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts[4:], budgets[4:])]
+        eng.run_until_idle(max_iters=200)
+        return [r.tokens for r in reqs]
+
+    want = serve(engine)
+    assert engine.state_step == "xla" and engine.ledger.state_step_inplace == 0
+    monkeypatch.setattr(gated_delta, "in_place", lambda: True)
+    eng = _engine(params)
+    assert eng.state_step == "inplace" and eng.ledger.state_step_inplace == 1
+    assert serve(eng) == want
+    assert [len(t) for t in want] == list(budgets)
+
+
 def test_a_dead_row_never_touches_a_live_slots_state(params):
     """Three live rows run at the ladder's 4-row shape: the fourth row is
     padding. Slot 3, free, holds a sentinel in its state rows; after the
@@ -340,8 +369,10 @@ def test_a_dead_row_never_touches_a_live_slots_state(params):
 # ----------------------------- (d) what the engine counts, what it refuses
 def test_the_slot_state_is_declared_counted_and_sown(params, tmp_path):
     """The cache's state leaves hold max_slots + 1 rows; the gauge and the
-    ledger's summary say how many bytes; every `serve.decode` span carries
-    `state_rows`, the live rows a step moved on summed over the block."""
+    ledger's summary say how many bytes, and ``serve.init`` and a second
+    gauge how the decode program advances them; every `serve.decode` span
+    carries `state_rows`, the live rows a step moved on summed over the
+    block."""
     obs.configure(str(tmp_path))
     try:
         eng = _engine(params, max_slots=2, prefix_cache=False)
@@ -349,6 +380,7 @@ def test_the_slot_state_is_declared_counted_and_sown(params, tmp_path):
         want = sum(4 * np.prod(s) for s in cfg.state_shapes(3).values())
         assert eng._slot_axis == {"['conv']": 1, "['state']": 1}
         assert eng.ledger.snapshot()["slot_state_bytes"] == want
+        assert eng.ledger.snapshot()["state_step_inplace"] == 0
         rng = np.random.default_rng(7)
         reqs = [eng.submit(p, max_new_tokens=n) for p, n in zip(_prompts(rng, (4, 9)), (6, 3))]
         eng.run_until_idle(max_iters=50)
@@ -361,6 +393,11 @@ def test_the_slot_state_is_declared_counted_and_sown(params, tmp_path):
             events += [json.loads(line) for line in f if line.strip()]
     gauges = [e for e in events if e.get("name") == "serve.slot_state_bytes"]
     assert gauges and gauges[-1]["value"] == want
+    # Off the TPU the decode program gathers, steps and scatters the rows.
+    steps = [e for e in events if e.get("name") == "serve.state_step_inplace"]
+    assert steps and steps[-1]["value"] == 0
+    init = [e for e in events if e.get("kind") == "span" and e.get("name") == "serve.init"]
+    assert init and init[-1]["state_step"] == "xla"
     blocks = [e for e in events if e.get("kind") == "span" and e.get("name") == "serve.decode"]
     # Tokens after the prefill's first, one a live row a step.
     assert sum(e["state_rows"] for e in blocks) == (6 - 1) + (3 - 1)

@@ -816,6 +816,11 @@ class ServeEngine:
         # + 1, *state)`` in the engine's cache, the last row a trash row):
         # asked of the model, never guessed from shapes.
         self.slot_state = tuple(getattr(model, "slot_state", ()))
+        # How the decode program advances that state, as the model says
+        # (``state_step``: "inplace" or "xla"); None for a model without.
+        self.state_step = (
+            getattr(model, "state_step", lambda: None)() if self.slot_state else None
+        )
         # Per-request int8 (ISSUE 9): quantize ONCE at construction and
         # keep both numeric paths' params resident — requests pick a
         # path at submit, never a recompile. The quantized tree is a
@@ -1025,6 +1030,7 @@ class ServeEngine:
         with obs.span(
             "serve.init", slots=S, pages=self.n_pages,
             quant=self.quant_mode or "off",
+            **({"state_step": self.state_step} if self.state_step else {}),
         ):
             if self.quant_mode is not None:
                 from tpuflow.infer.quant import quant_decision, quantize_model
@@ -1131,7 +1137,9 @@ class ServeEngine:
         last a trash row, against the prefill row's one), the admission
         insert writes the prefill's final state into the slot's row, and
         the decode program reads and writes the rows of its live slots
-        by index (``serve.slot_state_bytes`` says what they hold)."""
+        by index (``serve.slot_state_bytes`` says what they hold,
+        ``serve.state_step_inplace`` whether it advances them where they
+        lie)."""
 
         def mk(params):
             _, variables = self._pmodel.apply(
@@ -1192,6 +1200,9 @@ class ServeEngine:
         if self.slot_state:
             self.ledger.slot_state_bytes = state_bytes
             obs.gauge("serve.slot_state_bytes", state_bytes)
+        if self.state_step is not None:
+            self.ledger.state_step_inplace = int(self.state_step == "inplace")
+            obs.gauge("serve.state_step_inplace", self.ledger.state_step_inplace)
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes
         )

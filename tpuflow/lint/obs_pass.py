@@ -135,6 +135,7 @@ REQUIRED_EMITTERS: tuple[tuple[str, str], ...] = (
     ("gauge", "serve.tokens_per_pass"),
     ("gauge", "serve.pool_pad_fraction"),
     ("gauge", "serve.slot_state_bytes"),
+    ("gauge", "serve.state_step_inplace"),
     ("gauge", "serve.masked_row_waste"),
     # Disaggregated prefill/decode + tiered KV (ISSUE 19): the ship /
     # import spans, the tier spill/hit/promote trail, and the per-tier

@@ -60,13 +60,11 @@ per head (``x · rsqrt(Σx² + 1e-6)``), q scaled by dk^-1/2. ``g = −exp(A_log
 · softplus(a + dt_bias)``, per value head, float32. Per head and token,
 with S (dk × dv, float32): ``S ← e^g S; δ = β (v − Sᵀk); S ← S + k δᵀ; o
 = Sᵀq`` (``ops/gated_delta.py``: the chunked form over a prompt, one step a
-token in decode). A call's rows read and write S by slot, or, where a
-slot's S is over 2 MiB, by (slot, head): the leaf (layers, slots, heads,
-dk, dv) seen as (layers, slots · heads, dk, dv), the same bytes, one row
-a head. By slot, a row of Olmo-Hybrid's S (30 × 96 × 192, 2.1 MB) is
-more than the chip's compiler gathers whole: it split each gather into
-lane pieces that each sliced the whole leaf, every layer's S for every
-slot, every layer and step (PERF.md §6, PR 45).
+token in decode). A serving engine's decode call advances each row's S
+and conv state where they lie in the engine's leaves, by slot, in one
+kernel each on the TPU (``gated_delta.step_in_place``,
+``conv_step_in_place``); off it, and for a paged call of several tokens,
+it gathers the rows' states by slot and scatters them back.
 ``o`` goes through a gated RMSNorm per head, ``rms(o) · w ⊙ silu(z)``,
 then ``W_out``. At a left pad the conv's input is nought, β =
 0 and g = 0: the state stays what it was, zero before a prompt's first
@@ -217,41 +215,23 @@ def rotate_partial(x, positions, cfg: HybridConfig):
     )
 
 
-# The most of one slot's S that a call gathers and scatters as one row:
-# Qwen3-Next's 32 x 128 x 128 float32 is exactly this, and gathered by
-# (slot, head) its decode read 14.5 ms a token where by slot it reads 9.3
-# (PERF.md §6, PR 45).
-WHOLE_ROW_BYTES = 2**21
-
-
-def _head_rows(leaf, slots):
-    """A leaf of S (layers, slots, heads, dk, dv) whose slot's row is over
-    ``WHOLE_ROW_BYTES`` as (layers, slots · heads, dk, dv), the same
-    bytes, and the rows of ``slots``' heads; any other leaf as it is, and
-    ``slots`` (module docstring)."""
-    if leaf.ndim < 5 or math.prod(leaf.shape[2:]) * leaf.dtype.itemsize <= WHOLE_ROW_BYTES:
-        return leaf, slots
-    n, rows, heads = leaf.shape[:3]
-    flat = leaf.reshape((n, rows * heads) + leaf.shape[3:])
-    return flat, (slots[:, None] * heads + jnp.arange(heads)).reshape(-1)
-
-
 def _state_rows(leaf, layer, slots):
     """A layer's state for the call's rows: by slot row from the engine's
-    leaf (layers, slots, ...), or the call's own rows (layers, B, ...)."""
+    leaf (layers, slots, ...), or the call's own rows (layers, B, ...).
+    On the TPU the states take this path only in a prefill (``slots``
+    None): a decode step there advances them in place (``_mixer``)."""
     if slots is None:
         return lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
-    flat, rows = _head_rows(leaf, slots)
-    return flat[layer, rows].reshape(slots.shape + leaf.shape[2:])
+    return leaf[layer, slots]
 
 
 def _put_state_rows(leaf, new, layer, slots):
-    """Write them back in place, by index."""
+    """Write them back in place, by index (where ``_state_rows`` read
+    them)."""
     new = new.astype(leaf.dtype)
     if slots is None:
         return lax.dynamic_update_index_in_dim(leaf, new, layer, 0)
-    flat, rows = _head_rows(leaf, slots)
-    return flat.at[layer, rows].set(new.reshape(rows.shape + flat.shape[2:])).reshape(leaf.shape)
+    return leaf.at[layer, slots].set(new)
 
 
 class _Block(nn.Module):
@@ -353,15 +333,23 @@ class GatedDeltaLayer(_Block):
             qkvz = dot(x, self._p("in_qkvz", (c, n_conv + hv * dv), at), dt)
             ba = dot(x, self._p("in_ba", (c, 2 * hv), at), dt, jnp.float32)
             mixed, z = qkvz[..., :n_conv], qkvz[..., n_conv:]
+        # A decode call on the TPU advances both states where they lie.
+        in_place = t == 1 and slots is not None and gated_delta.in_place()
         with jax.named_scope("gdn_conv"):
             mixed = jnp.where(valid[..., None], mixed.astype(jnp.float32), 0.0)
-            prev = _state_rows(conv_leaf, gdn, slots).reshape(b, width - 1, n_conv)
-            window = jnp.concatenate([prev, mixed], axis=1)  # (B, width - 1 + T, channels)
             w = self._p("conv", (width, n_conv), at).astype(jnp.float32)
-            out = sum(w[j] * window[:, j:j + t] for j in range(width))
-            conv_leaf = _put_state_rows(
-                conv_leaf, window[:, t:].reshape(b, -1), gdn, slots
-            )
+            if in_place:
+                out, conv_leaf = gated_delta.conv_step_in_place(
+                    conv_leaf, gdn, slots, mixed[:, 0], w
+                )
+                out = out[:, None]
+            else:
+                prev = _state_rows(conv_leaf, gdn, slots).reshape(b, width - 1, n_conv)
+                window = jnp.concatenate([prev, mixed], axis=1)  # (B, width - 1 + T, channels)
+                out = sum(w[j] * window[:, j:j + t] for j in range(width))
+                conv_leaf = _put_state_rows(
+                    conv_leaf, window[:, t:].reshape(b, -1), gdn, slots
+                )
             mixed = jax.nn.silu(out)
         with jax.named_scope("gdn_core"):
             q = l2_normalize(mixed[..., :hk * dk].reshape(b, t, hk, dk)) * dk ** -0.5
@@ -380,13 +368,19 @@ class GatedDeltaLayer(_Block):
                 ba[..., hv:] + dt_bias.astype(jnp.float32)
             )
             g = jnp.where(valid[..., None], g, 0.0)
-            s = _state_rows(state_leaf, gdn, slots)
-            if t == 1:
-                o, s = gated_delta.step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s)
+            if in_place:
+                o, state_leaf = gated_delta.step_in_place(
+                    state_leaf, gdn, slots, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+                )
                 o = o[:, None]
             else:
-                o, s = gated_delta.chunked(q, k, v, g, beta, s, cfg.gdn_chunk)
-            state_leaf = _put_state_rows(state_leaf, s, gdn, slots)
+                s = _state_rows(state_leaf, gdn, slots)
+                if t == 1:
+                    o, s = gated_delta.step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s)
+                    o = o[:, None]
+                else:
+                    o, s = gated_delta.chunked(q, k, v, g, beta, s, cfg.gdn_chunk)
+                state_leaf = _put_state_rows(state_leaf, s, gdn, slots)
         with jax.named_scope("gdn_out"):
             o = rms_norm(o, self._p("out_norm", (dv,), at, nn.initializers.ones),
                          cfg.rms_norm_eps)
@@ -526,6 +520,13 @@ class HybridLM(nn.Module):
     # the serving engine, ``ServeEngine._init_cache``): every other leaf is
     # a page pool, or an index.
     slot_state: ClassVar[tuple[str, ...]] = ("conv", "state")
+
+    @staticmethod
+    def state_step() -> str:
+        """How a decode call advances S and the conv state (the serving
+        engine records it): ``inplace``, the kernels on the engine's
+        leaves; ``xla``, the rows gathered, stepped and scattered back."""
+        return "inplace" if gated_delta.in_place() else "xla"
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, decode: bool = False,

@@ -221,7 +221,9 @@ CATALOG: dict[str, tuple[str, str]] = {
     "serve.init": (
         "span",
         "ServeEngine construction's device work: the int8 weights where "
-        "armed, the page pool and the decode ladder, the zeroed cache",
+        "armed, the page pool and the decode ladder, the zeroed cache "
+        "(attribute state_step, for a model with per-slot state: inplace "
+        "or xla, how the decode program advances it)",
     ),
     "serve.warmup": (
         "span",
@@ -471,6 +473,13 @@ CATALOG: dict[str, tuple[str, str]] = {
         "slot and the trash row (a model with recurrent state: each Gated "
         "DeltaNet layer's S and conv state, float32); set once when the "
         "engine allocates its cache",
+    ),
+    "serve.state_step_inplace": (
+        "gauge",
+        "1 where the decode program advances each row's recurrent state "
+        "where it lies in the engine's leaves, in the model's kernels (on "
+        "the TPU), 0 where it gathers the rows, steps them and scatters "
+        "them back; set once when the engine allocates its cache",
     ),
     "serve.masked_row_waste": (
         "gauge",

@@ -141,6 +141,10 @@ class ServeLedger:
         # recurrent state): bytes of every slot-state leaf, trash row
         # included; None for a model without.
         self.slot_state_bytes: int | None = None
+        # Whether the decode program advances that state where it lies (1,
+        # the model's kernel on the engine's leaf) or gathers, steps and
+        # scatters the rows (0); None for a model without.
+        self.state_step_inplace: int | None = None
         self.reset()
 
     def reset(self) -> None:
@@ -384,6 +388,7 @@ class ServeLedger:
             "pool_token_widths": dict(self.pool_token_widths),
             "pool_pad_fraction": self.pool_pad_fraction,
             "slot_state_bytes": self.slot_state_bytes,
+            "state_step_inplace": self.state_step_inplace,
             "model_steps": self._model_steps,
             "step_sum": dict(self.step_sum),
             "step_max": dict(self.step_max),
